@@ -63,7 +63,7 @@ DEFAULT_TABLES: Dict[str, object] = {
         "total_seconds", "round_seconds_total", "stage_seconds_total",
         "comm_seconds_total", "compile_seconds_total",
         "rounds_per_sec", "images_per_sec", "comm_overhead_frac",
-        "captured_utc", "last_error",
+        "captured_utc",
     ),
     "ENVELOPE_FIELDS": (
         "event", "schema", "run_id", "run_name", "span_id",

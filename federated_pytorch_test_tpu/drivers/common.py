@@ -31,8 +31,7 @@ def build_parser(defaults: FederatedConfig, prog: str) -> argparse.ArgumentParse
                     "(reference parity: see module docstring)")
     # converters for Optional[...] fields (default None carries no type)
     _optional_types = {"data_dir": str, "num_devices": int,
-                       "profile_dir": str, "obs_dir": str,
-                       "compile_cache_dir": str}
+                       "profile_dir": str, "obs_dir": str}
     # tri-state booleans: absent -> None (auto), --flag/--no-flag override
     _optional_bools = {"device_data", "donate"}
     for f in dataclasses.fields(FederatedConfig):
@@ -113,13 +112,6 @@ def build_parser(defaults: FederatedConfig, prog: str) -> argparse.ArgumentParse
                      "weights) or stratified (one id per contiguous "
                      "stratum); only meaningful with --population > 0 "
                      "(default: uniform)")
-        elif f.name == "compile_cache_dir":
-            p.add_argument(
-                arg, type=str, default=default, metavar="DIR",
-                help="persistent XLA compile-cache dir "
-                     "(utils/compile_cache.py); default: auto "
-                     "(FEDTPU_COMPILE_CACHE_DIR env, else tests/.jax_cache)"
-                     "; the literal 'none' disables the cache")
         elif default is None:
             conv = _optional_types.get(f.name)
             if conv is None:
@@ -168,15 +160,16 @@ def setup_runtime(cfg: FederatedConfig) -> None:
         enable_persistent_compile_cache,
     )
 
-    enable_persistent_compile_cache(getattr(cfg, "compile_cache_dir", None))
+    enable_persistent_compile_cache()
     apply_platform(cfg)
 
 
 def apply_platform(cfg: FederatedConfig) -> None:
     """Honor ``use_tpu`` (the reference's ``use_cuda`` gate,
     federated_multi.py:32): when False, run on the host CPU platform.
-    Must be called before the first JAX device query; if the backend is
-    already initialized on a non-CPU platform, warns instead of failing.
+    Must be called before the first JAX device query; a backend that is
+    already up on another platform is an error — the run would otherwise
+    carry on where the flag said not to.
 
     Also joins the multi-host runtime first when ``FEDTPU_DISTRIBUTED=1``
     (parallel/mesh.py:initialize_multihost).  Drivers reach this via
@@ -188,13 +181,23 @@ def apply_platform(cfg: FederatedConfig) -> None:
     if cfg.use_tpu:
         return
     import jax
-    try:
-        jax.config.update("jax_platforms", "cpu")
-    except RuntimeError as e:                     # backend already up
-        import warnings
-        warnings.warn(f"--no-use-tpu requested but the JAX backend is "
-                      f"already initialized ({e}); continuing on the "
-                      "existing platform")
+    jax.config.update("jax_platforms", "cpu")
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            "--no-use-tpu requested but the JAX backend is already "
+            f"initialized on {jax.default_backend()!r}; select the platform "
+            "before the first device query (or export JAX_PLATFORMS=cpu)")
+
+
+def device_banner() -> str:
+    """``platform=... device_kind=... device_count=...`` as JAX reports
+    the default backend — every driver prints it, so a run that landed on
+    the wrong platform says so in its first line."""
+    import jax
+
+    dev = jax.devices()[0]
+    return (f"platform={dev.platform} device_kind={dev.device_kind!r} "
+            f"device_count={len(jax.devices())}")
 
 
 # the single model registry: argparse choices and pick_model both derive
@@ -295,7 +298,7 @@ def run_classifier_driver(prog: str, defaults: FederatedConfig,
         mname = f"ResNet{trainer.model.qualifier}"
     print(f"{prog}: K={cfg.K} model={mname} "
           f"devices={trainer.D} clients/device={trainer.K_local} "
-          f"data={trainer.data.source}")
+          f"data={trainer.data.source} {device_banner()}")
     state = maybe_load(trainer, prog)
     if independent:
         state, history = trainer.run_independent(state)

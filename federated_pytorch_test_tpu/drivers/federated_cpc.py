@@ -62,14 +62,6 @@ def main(argv=None):
     args = build_parser().parse_args(argv)
     cfg = common.default_obs_dir(common.config_from_args(args))
     common.setup_runtime(cfg)
-    if cfg.use_tpu and args.Lc > 64:
-        import sys
-
-        print(
-            f"federated_cpc: WARNING — Lc={args.Lc} on the TPU backend can "
-            "trigger a pathological XLA compile of the jitted CPC round "
-            "(observed >20 min at Lc=256; README 'Known issues'); Lc<=64 "
-            "compiles in seconds", file=sys.stderr)
     data = CPCDataSource(args.file_list, args.sap_list,
                          batch_size=args.batch_size,
                          patch_size=args.patch_size, seed=cfg.seed)
@@ -80,7 +72,7 @@ def main(argv=None):
 
     trainer = make_trainer(cfg)
     print(f"federated_cpc: K={data.K} Lc={args.Lc} Rc={args.Rc} "
-          f"devices={trainer.D}")
+          f"devices={trainer.D} {common.device_banner()}")
     state = trainer.state0
     ckpt = common.checkpoint_path(cfg, "federated_cpc")
     if cfg.load_model and os.path.isdir(os.path.abspath(

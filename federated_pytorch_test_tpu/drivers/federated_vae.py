@@ -25,7 +25,8 @@ def main(argv=None):
         limit_per_client=args.n_train, limit_test=args.n_test)
     trainer = VAETrainer(AutoEncoderCNN(), cfg, data, FedAvg())
     trainer.obs_run_name = "federated_vae"
-    print(f"federated_vae: K={cfg.K} devices={trainer.D} data={data.source}")
+    print(f"federated_vae: K={cfg.K} devices={trainer.D} data={data.source} "
+          f"{common.device_banner()}")
     state = common.maybe_load(trainer, "federated_vae")
     supervised = cfg.max_restarts > 0
     # supervision is resume-from-checkpoint: a restart budget forces the

@@ -35,7 +35,8 @@ def main(argv=None):
     trainer = VAECLTrainer(model, cfg, data, FedAvg())
     trainer.obs_run_name = "federated_vae_cl"
     print(f"federated_vae_cl: K={cfg.K} Kc={args.Kc} Lc={args.Lc} "
-          f"devices={trainer.D} data={data.source}")
+          f"devices={trainer.D} data={data.source} "
+          f"{common.device_banner()}")
     state = common.maybe_load(trainer, "federated_vae_cl")
     ck = (common.checkpoint_path(cfg, "federated_vae_cl_midrun")
           if cfg.midrun_checkpoint else None)

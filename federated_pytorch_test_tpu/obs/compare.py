@@ -11,17 +11,14 @@ regression, so CI can gate on it.  Accepted inputs (auto-detected):
 - a bench.py artifact (``artifacts/bench_*.json``) — the headline
   metric named by its ``metric`` field plus the ``*_ips_chip`` section
   breakdowns and ``mfu`` (all higher-better).
-- a ``BENCH_rNN.json`` wrapper (``{n, cmd, rc, tail, parsed}``) — the
-  embedded ``parsed`` artifact is unwrapped.
 - ``BASELINE.json`` — its ``published`` dict; when that is empty (no
   published numbers yet) the comparison says so instead of inventing a
   verdict.
 
-Honesty about unmeasured data: an artifact with ``measured: false`` has
-value 0.0 by construction; comparing it would manufacture a fake
-regression.  If it embeds a ``last_measured`` reference the headline is
-PROMOTED from there and annotated; otherwise the artifact contributes
-no verdict and the report says "unmeasured".
+Honesty about unmeasured data: an artifact with ``measured: false``
+carries no comparable value; comparing it would manufacture a fake
+regression, so it contributes no verdict and the report says
+"unmeasured".  A number is never borrowed from another run.
 
 A candidate bench artifact may carry ``baseline_ref`` (bench.py emits
 it); when no ``--baseline`` flag is given and exactly one candidate is
@@ -241,9 +238,6 @@ def load_source(path: str) -> Dict[str, Any]:
         raise CompareError(f"{path}: {e}")
     if not isinstance(obj, dict):
         raise CompareError(f"{path}: expected a JSON object")
-    if isinstance(obj.get("parsed"), dict):       # BENCH_rNN.json wrapper
-        src["notes"].append(f"BENCH wrapper (iteration {obj.get('n')})")
-        obj = obj["parsed"]
     if "metric" in obj and "value" in obj:        # bench.py artifact
         src["kind"] = "bench"
         src["baseline_ref"] = obj.get("baseline_ref")
@@ -274,17 +268,8 @@ def load_source(path: str) -> Dict[str, Any]:
                     if v is not None:
                         src["metrics"][k] = v
         else:
-            last = obj.get("last_measured")
-            v = _num(last.get("value")) if isinstance(last, dict) else None
-            if v is not None:
-                src["metrics"][headline] = v
-                src["notes"].append(
-                    "measured=false; headline PROMOTED from "
-                    f"{last.get('path', '?')} ({last.get('captured_utc')})")
-            else:
-                src["notes"].append(
-                    "measured=false and no last_measured reference — "
-                    "no comparable metrics (unmeasured)")
+            src["notes"].append(
+                "measured=false — no comparable metrics (unmeasured)")
         return src
     if isinstance(obj.get("published"), dict):    # BASELINE.json
         src["kind"] = "baseline"
@@ -298,7 +283,7 @@ def load_source(path: str) -> Dict[str, Any]:
                 "nothing to compare against")
         return src
     raise CompareError(f"{path}: unrecognised artifact shape (not a run "
-                       "JSONL, bench artifact, BENCH wrapper, or baseline)")
+                       "JSONL, bench artifact, or baseline)")
 
 
 def compare(baseline: Dict[str, Any], candidates: List[Dict[str, Any]],

@@ -486,10 +486,10 @@ ADVISORY_FIELDS = (
     "total_seconds", "round_seconds_total", "stage_seconds_total",
     "comm_seconds_total", "compile_seconds_total",
     "rounds_per_sec", "images_per_sec", "comm_overhead_frac",
-    # bench artifact fields, declared here rather than silently
-    # exempted: the capture timestamp and the relay's last error text
-    # are operator-facing diagnostics, never replay-checked
-    "captured_utc", "last_error",
+    # bench artifact field, declared here rather than silently
+    # exempted: the capture timestamp is an operator-facing diagnostic,
+    # never replay-checked
+    "captured_utc",
 )
 
 #: run/record identity fields stamped by the recorder envelope — host

@@ -47,7 +47,12 @@ from jax.experimental import pallas as pl
 _LANES = 128                 # f32/int8 lane width
 _ROW_TILE = 32               # int8 sublane multiple (covers f32's 8)
 _GRAM_CHUNK = 512            # contraction slab per grid step
-_VMEM_BUDGET = 12 * 2**20    # headroom under the ~16 MB/core VMEM
+# budget for the per-program block estimates below.  The estimates count
+# each block once although the Pallas pipeline double-buffers them; the
+# headroom under the 16 MiB scoped-VMEM default absorbs that at the
+# shapes these kernels see (tiles of a few hundred KB) — chip_smoke.py
+# compiles them at driver shapes on every chip run
+_VMEM_BUDGET = 12 * 2**20
 
 # None = auto (TPU -> pallas, else XLA); "xla" | "pallas" | "pallas_interpret"
 _FORCE_IMPL = None
@@ -73,6 +78,20 @@ def _resolve_impl(fits: bool) -> str:
     if impl is None:
         return "pallas" if (jax.default_backend() == "tpu" and fits) else "xla"
     return impl
+
+
+def dispatch_plan(chunk: int, k: int) -> dict:
+    """What the three entry points run on the current backend for
+    ``[c, chunk]`` payload rows and a ``[k, n]`` client stack, and the
+    VMEM estimate that decided each (chip_smoke.py prints this)."""
+    est = {"quantize_chunks": _quantize_vmem_bytes(chunk),
+           "dequant_add": _dequant_vmem_bytes(chunk),
+           "gram_matrix": _gram_vmem_bytes(k)}
+    plan = {name: {"impl": _resolve_impl(b <= _VMEM_BUDGET), "vmem_bytes": b}
+            for name, b in est.items()}
+    plan.update(backend=jax.default_backend(), forced=_FORCE_IMPL,
+                vmem_budget=_VMEM_BUDGET)
+    return plan
 
 
 def _pad2(a, rows: int, cols: int):
@@ -109,12 +128,9 @@ def _quantize_kernel(qmax: int, cols: int, v_ref, q_ref, s_ref):
     del cols
 
 
-def _quantize_fits(rows: int, cols: int) -> bool:
+def _quantize_vmem_bytes(cols: int) -> int:
     # v tile f32 + q tile int8 + scale lanes, per program
-    per_program = 4 * _ROW_TILE * cols + _ROW_TILE * cols \
-        + 4 * _ROW_TILE * _LANES
-    del rows
-    return per_program <= _VMEM_BUDGET
+    return 4 * _ROW_TILE * cols + _ROW_TILE * cols + 4 * _ROW_TILE * _LANES
 
 
 def _quantize_pallas(vv, qmax: int, interpret: bool = False):
@@ -143,7 +159,7 @@ def quantize_chunks(vv, qmax: int):
     ``scale = max|row| / qmax`` and round-to-nearest
     ``q = clip(round(row / safe), ±qmax)`` int8 — the deterministic
     transport codec of ops/packed_reduce.py, fused."""
-    impl = _resolve_impl(_quantize_fits(*vv.shape))
+    impl = _resolve_impl(_quantize_vmem_bytes(vv.shape[1]) <= _VMEM_BUDGET)
     if impl == "xla":
         return _quantize_xla(vv, qmax)
     return _quantize_pallas(vv, qmax, interpret=impl == "pallas_interpret")
@@ -165,12 +181,10 @@ def _dequant_add_kernel(a_ref, q_ref, s_ref, o_ref):
     o_ref[...] = a_ref[...] + q_ref[...].astype(jnp.float32) * safe[:, None]
 
 
-def _dequant_fits(rows: int, cols: int) -> bool:
+def _dequant_vmem_bytes(cols: int) -> int:
     # acc + out f32, q int8, scale lanes, per program
-    per_program = 2 * 4 * _ROW_TILE * cols + _ROW_TILE * cols \
+    return 2 * 4 * _ROW_TILE * cols + _ROW_TILE * cols \
         + 4 * _ROW_TILE * _LANES
-    del rows
-    return per_program <= _VMEM_BUDGET
 
 
 def _dequant_add_pallas(acc, q, scale, interpret: bool = False):
@@ -200,7 +214,7 @@ def dequant_add(acc, q, scale):
     for the decoded buffer.  ``q`` is int8 rows (q4 payloads are
     nibble-unfolded by the caller; the fold is a pure byte shuffle XLA
     keeps inside the surrounding fusion either way)."""
-    impl = _resolve_impl(_dequant_fits(*acc.shape))
+    impl = _resolve_impl(_dequant_vmem_bytes(acc.shape[1]) <= _VMEM_BUDGET)
     if impl == "xla":
         return _dequant_add_xla(acc, q, scale)
     return _dequant_add_pallas(acc, q, scale,
@@ -236,9 +250,10 @@ def _gram_kernel(a_ref, g_ref):
         g_ref[...] += g
 
 
-def _gram_fits(k_pad: int) -> bool:
-    per_program = 4 * (k_pad * _GRAM_CHUNK + k_pad * k_pad)
-    return per_program <= _VMEM_BUDGET
+def _gram_vmem_bytes(k: int) -> int:
+    # [K_pad, CHUNK] slab + [K_pad, K_pad] accumulator block, per program
+    k_pad = pl.cdiv(k, _LANES) * _LANES
+    return 4 * (k_pad * _GRAM_CHUNK + k_pad * k_pad)
 
 
 def _gram_pallas(a, interpret: bool = False):
@@ -264,8 +279,7 @@ def gram_matrix(a):
     grid step.  Chunked accumulation re-associates the contraction:
     Pallas output is allclose to the XLA matmul, not bitwise
     (PARITY.md)."""
-    k = a.shape[0]
-    impl = _resolve_impl(_gram_fits(pl.cdiv(k, _LANES) * _LANES))
+    impl = _resolve_impl(_gram_vmem_bytes(a.shape[0]) <= _VMEM_BUDGET)
     if impl == "xla":
         return _gram_xla(a)
     return _gram_pallas(a, interpret=impl == "pallas_interpret")

@@ -52,7 +52,12 @@ from federated_pytorch_test_tpu.ops.infonce_core import (
 
 _TILE = 128                 # row tile = MXU edge
 _SUBLANE = 8                # float32 sublane multiple
-_VMEM_BUDGET = 12 * 2**20   # leave headroom under the ~16 MB/core VMEM
+# budget for the estimates below (_fwd_vmem_bytes/_bwd_vmem_bytes), under
+# the 16 MiB scoped-VMEM limit Mosaic enforces.  chip_smoke.py compiles
+# both kernels, vmapped over clients as the CPC engine calls them, at the
+# largest D each gate admits on every chip run — a gate that admits a
+# shape Mosaic refuses fails there, not in a training run
+_VMEM_BUDGET = 12 * 2**20
 
 # None = auto (TPU -> pallas, else XLA); "xla" | "pallas" | "pallas_interpret"
 _FORCE_IMPL = None
@@ -112,18 +117,49 @@ def _padded_dims(D: int, P: int) -> tuple:
     return pl.cdiv(D, _SUBLANE) * _SUBLANE, pl.cdiv(P, _TILE) * _TILE
 
 
+def _fwd_vmem_bytes(D_pad: int, P_pad: int) -> int:
+    """VMEM estimate for ``_log_p_kernel``: the Z tile [D, T] and Zhat
+    [D, P] blocks, each double-buffered by the Pallas pipeline, Zhat's
+    squares for the norms, and ~3 [T, P] score-sized temporaries.  At
+    [7680, 256] this gives 31.9 MB where Mosaic reported a 30.13 MB
+    scoped allocation (my chip run, PR 21)."""
+    return 4 * (2 * D_pad * _TILE + 3 * D_pad * P_pad + 3 * _TILE * P_pad)
+
+
+def _bwd_vmem_bytes(D_pad: int, P_pad: int) -> int:
+    """VMEM estimate for ``_grad_kernel``: Z tile + dZ tile [D, T] and
+    Zhat + dZhat [D, P], each double-buffered; the dZhat partial and
+    Zhat's squares [D, P], one [D, T] product, and ~5 [T, P] score-sized
+    temporaries (zz, s, G, Gn, G*zz).  The single-buffered count this
+    replaces admitted [4096, 128] — the CPC reference shape — which
+    Mosaic refuses once ``vmap`` over clients gives the grid a second
+    step to pipeline across (my chip run, PR 21)."""
+    return 4 * (5 * D_pad * _TILE + 6 * D_pad * P_pad + 5 * _TILE * P_pad)
+
+
 def _pallas_fits(D_pad: int, P_pad: int) -> bool:
-    per_program = 4 * (D_pad * (_TILE + P_pad) + _TILE * P_pad)
-    return per_program <= _VMEM_BUDGET
+    return _fwd_vmem_bytes(D_pad, P_pad) <= _VMEM_BUDGET
 
 
 def _pallas_bwd_fits(D_pad: int, P_pad: int) -> bool:
-    """VMEM estimate for ``_grad_kernel``: Z tile + dZ tile [D, T] each,
-    Zhat + dZhat accumulator + dZhat partial [D, P] each, and ~4 [T, P]
-    score-sized temporaries (zz, s, G, Gn)."""
-    per_program = 4 * (2 * D_pad * _TILE + 3 * D_pad * P_pad
-                       + 4 * _TILE * P_pad)
-    return per_program <= _VMEM_BUDGET
+    return _bwd_vmem_bytes(D_pad, P_pad) <= _VMEM_BUDGET
+
+
+def dispatch_plan(D: int, P: int) -> dict:
+    """What :func:`info_nce_fused` runs for a ``[D, P]`` patch matrix on
+    the current backend, and the numbers that decided it — forward and
+    backward resolve separately, so a shape can run the fused forward
+    with an XLA backward; callers that care (chip_smoke.py) print this
+    instead of assuming."""
+    D_pad, P_pad = _padded_dims(D, P)
+    fwd = _fwd_vmem_bytes(D_pad, P_pad)
+    bwd = _bwd_vmem_bytes(D_pad, P_pad)
+    return {"backend": jax.default_backend(), "forced": _FORCE_IMPL,
+            "padded": (D_pad, P_pad), "vmem_budget": _VMEM_BUDGET,
+            "forward": _resolve_impl(fwd <= _VMEM_BUDGET),
+            "forward_vmem_bytes": fwd,
+            "backward": _resolve_impl(bwd <= _VMEM_BUDGET),
+            "backward_vmem_bytes": bwd}
 
 
 def _log_p_pallas(Z: jnp.ndarray, Zhat: jnp.ndarray,

@@ -61,6 +61,13 @@ def _resolve_impl(n: int) -> str:
     return impl
 
 
+def dispatch_plan(n: int) -> dict:
+    """What :func:`top_k_abs_indices` runs for an ``[n]`` vector on the
+    current backend, and why (chip_smoke.py prints this)."""
+    return {"backend": jax.default_backend(), "forced": _FORCE_IMPL,
+            "impl": _resolve_impl(n), "chunk": _CHUNK}
+
+
 def _topk_abs_xla(vec, k: int):
     """The seed path: one global sort-based selection."""
     _, idx = lax.top_k(jnp.abs(vec), k)

@@ -13,7 +13,6 @@ the same code on a virtual 8-device CPU mesh (tests/conftest.py).
 
 from __future__ import annotations
 
-import functools
 import os
 import threading
 import time
@@ -21,29 +20,10 @@ from typing import Callable, Optional, Sequence
 
 import jax
 import numpy as np
+from jax import shard_map  # noqa: F401 — re-exported: the engines import it from here
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 CLIENT_AXIS = "clients"
-
-# --- shard_map version shim -------------------------------------------------
-# jax >= 0.6 exposes jax.shard_map(..., check_vma=); 0.4.x only has
-# jax.experimental.shard_map.shard_map(..., check_rep=).  Every engine/test
-# call site uses the modern keyword, so translate here instead of scattering
-# try/except over the codebase.
-try:
-    from jax import shard_map as _shard_map_impl  # type: ignore[attr-defined]
-    _REPLICATION_KW = "check_vma"
-except ImportError:                               # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-    _REPLICATION_KW = "check_rep"
-
-
-@functools.wraps(_shard_map_impl)
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs,
-                           **{_REPLICATION_KW: check_vma})
-
 
 def client_mesh(num_devices: Optional[int] = None,
                 devices: Optional[Sequence[jax.Device]] = None) -> Mesh:
@@ -174,7 +154,7 @@ def _process_count() -> int:
 
 class CollectiveTimeoutError(RuntimeError):
     """A multi-process collective or barrier exceeded its bounded wait —
-    the signature of a peer lost to preemption (or a wedged relay).
+    the signature of a peer lost to preemption.
     ``round_index`` (when known) lets the restart supervisor attribute
     the failure to a round without parsing the message."""
 

@@ -443,10 +443,3 @@ class FederatedConfig:
     # rebuilds the literal pre-probe programs (params bitwise
     # identical, tested).
     client_ledger: bool = True
-
-    # persistent XLA compile-cache directory (utils/compile_cache.py):
-    # None -> auto (FEDTPU_COMPILE_CACHE_DIR env, else tests/.jax_cache
-    # with an XDG fallback); the literal string "none" disables the
-    # persistent cache for this run (cost-ledger cache_hit attribution
-    # is then omitted).
-    compile_cache_dir: Optional[str] = None

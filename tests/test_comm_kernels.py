@@ -17,10 +17,6 @@ one ulp from the jitted kernel for reasons that have nothing to do with
 the kernel (PARITY.md).
 """
 
-import os
-import subprocess
-import sys
-import time
 import warnings
 
 import flax.linen as nn
@@ -72,9 +68,6 @@ from federated_pytorch_test_tpu.train import (
 pytestmark = pytest.mark.commkernels
 
 P = jax.sharding.PartitionSpec
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
 
 # ---------------------------------------------------------------------------
 # fused quantize / dequant-accumulate / gram kernels (interpret parity)
@@ -581,7 +574,7 @@ class TestEngineOverlapRound:
 
 
 # ---------------------------------------------------------------------------
-# schema v14 + relay wedge forensics
+# schema v14
 
 
 class TestSchemaV14:
@@ -608,30 +601,3 @@ class TestSchemaV14:
         assert _direction("smoke_robust_trim_chunked_peak_device_bytes") < 0
         assert _direction("smoke_robust_trim_dense_gather_bytes") < 0
         assert _direction("smoke_robust_trim_gather_savings_ratio") > 0
-
-
-class TestWedgeDiagnosis:
-    def test_diagnose_live_process_snapshot(self):
-        sys.path.insert(0, REPO)
-        import bench
-
-        p = subprocess.Popen([sys.executable, "-c",
-                              "import time; time.sleep(60)"])
-        try:
-            time.sleep(0.3)     # let it reach the sleep syscall
-            d = bench._diagnose_wedge(p.pid)
-        finally:
-            p.kill()
-            p.wait()
-        assert d["proc_state"].startswith("S"), d
-        assert int(d["threads"]) >= 1
-        # env snapshot only carries the accelerator-relevant prefixes
-        assert all(k.startswith(bench._RELAY_ENV_PREFIXES)
-                   for k in d.get("env", {}))
-
-    def test_diagnose_dead_pid_degrades_gracefully(self):
-        sys.path.insert(0, REPO)
-        import bench
-
-        d = bench._diagnose_wedge(2 ** 22 + 1)      # beyond pid_max default
-        assert isinstance(d, dict)                  # best-effort, no raise

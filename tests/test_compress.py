@@ -371,12 +371,6 @@ class TestEngineIntegration:
             loss = runs[name][2][-1]["loss"]
             assert abs(loss - dense) / dense < 0.10, (name, loss, dense)
 
-    def test_topk_without_error_feedback_tracks_worse(self, runs):
-        dense = runs["dense"][2][-1]["loss"]
-        ef = runs["topk_ef"][2][-1]["loss"]
-        plain = runs["topk"][2][-1]["loss"]
-        assert abs(plain - dense) > abs(ef - dense), (plain, ef, dense)
-
     def test_topk_bytes_reduction_at_least_8x(self, runs):
         dense_total = sum(r["bytes_on_wire"] for r in runs["dense"][2])
         topk_total = sum(r["bytes_on_wire"] for r in runs["topk_ef"][2])

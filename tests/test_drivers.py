@@ -75,7 +75,7 @@ class TestDriverCLI:
         state2, hist2 = main(common + ["--load-model"])
         assert len(hist2) == 1
 
-    def test_fedavg_driver_smoke(self, tmp_path, monkeypatch):
+    def test_fedavg_driver_smoke(self, tmp_path, monkeypatch, capsys):
         monkeypatch.chdir(tmp_path)
         from federated_pytorch_test_tpu.drivers.federated_multi import main
         state, hist = main([
@@ -83,6 +83,21 @@ class TestDriverCLI:
             "--n-test", "32", "--default-batch", "16", "--no-save-model",
             "--no-check-results"])
         assert all("dual_residual" in h for h in hist)
+        # the first line names the data source and the device JAX found
+        banner = capsys.readouterr().out.splitlines()[0]
+        assert "data=synthetic" in banner
+        assert (f"platform=cpu device_kind='cpu' "
+                f"device_count={len(jax.devices())}") in banner
+
+    def test_no_use_tpu_after_backend_init_is_an_error(self, monkeypatch):
+        """It used to warn and carry on on the other platform."""
+        from federated_pytorch_test_tpu.drivers import common
+        from federated_pytorch_test_tpu.train.config import FederatedConfig
+
+        common.apply_platform(FederatedConfig(use_tpu=False))   # cpu: fine
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        with pytest.raises(RuntimeError, match="already initialized on 'tpu'"):
+            common.apply_platform(FederatedConfig(use_tpu=False))
 
     def test_fedprox_driver_smoke(self, tmp_path, monkeypatch):
         """FedProx CLI end to end: proximal penalty runs and z is NEVER

@@ -395,9 +395,7 @@ if __name__ == "__main__":
         enable_persistent_compile_cache,
     )
 
-    enable_persistent_compile_cache(
-        os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                     ".jax_cache"))
+    enable_persistent_compile_cache()
     _data = FederatedCifar10(K=K, batch=16, limit_per_client=32,
                              limit_test=32)
     for _check in _names:

@@ -57,8 +57,8 @@ from federated_pytorch_test_tpu.train import (
     FedAvg,
     FederatedConfig,
 )
+from federated_pytorch_test_tpu.utils import compile_cache
 from federated_pytorch_test_tpu.utils.compile_cache import (
-    DISABLE,
     cache_stats,
     enable_persistent_compile_cache,
 )
@@ -540,27 +540,68 @@ class TestCompileCacheSatellite:
         s = cache_stats("/nonexistent/fedtpu/cache")
         assert s["entries"] == 0 and s["total_bytes"] == 0
 
-    def test_none_switch_disables(self, monkeypatch):
-        assert enable_persistent_compile_cache(DISABLE) == ""
-        assert enable_persistent_compile_cache("  NoNe ") == ""
-        # env spelling too
-        monkeypatch.setenv("FEDTPU_COMPILE_CACHE_DIR", "none")
-        assert enable_persistent_compile_cache() == ""
+    def test_env_set_means_no_directory_is_set_in_code(self, monkeypatch):
+        """JAX_COMPILATION_CACHE_DIR set: whoever launched the process
+        placed the cache (jax reads the variable itself at import); the
+        helper must not call jax.config.update("jax_compilation_cache_dir")."""
+        updates = []
+        real = jax.config.update
 
-    def test_env_and_arg_precedence(self, monkeypatch, tmp_path):
+        def spy(name, value):
+            updates.append(name)
+            real(name, value)
+
+        monkeypatch.setattr(jax.config, "update", spy)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/x")
+        before = jax.config.jax_compilation_cache_dir
+        assert enable_persistent_compile_cache() == "/x"
+        assert "jax_compilation_cache_dir" not in updates
+        assert jax.config.jax_compilation_cache_dir == before
+
+    def test_env_unset_means_the_fixed_in_checkout_path(self, monkeypatch):
         prev = jax.config.jax_compilation_cache_dir
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         try:
-            monkeypatch.setenv("FEDTPU_COMPILE_CACHE_DIR",
-                               str(tmp_path / "envdir"))
-            assert enable_persistent_compile_cache() == \
-                str(tmp_path / "envdir")
-            # explicit argument outranks the env var
-            assert enable_persistent_compile_cache(
-                str(tmp_path / "argdir")) == str(tmp_path / "argdir")
-            assert jax.config.jax_compilation_cache_dir == \
-                str(tmp_path / "argdir")
+            got = enable_persistent_compile_cache()
+            assert got == os.path.join(repo, "tests", ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
         finally:
             jax.config.update("jax_compilation_cache_dir", prev)
+
+    def test_compile_time_floor_follows_the_platform(self, monkeypatch):
+        """CPU-only: sub-second programs stay out.  Any other platform
+        list: every program is kept, so a second run adds no entries."""
+        prev = jax.config.jax_persistent_cache_min_compile_time_secs
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/x")
+        try:
+            assert jax.config.jax_platforms == "cpu"      # conftest
+            enable_persistent_compile_cache()
+            assert jax.config.jax_persistent_cache_min_compile_time_secs \
+                == 1.0
+            with monkeypatch.context() as m:
+                m.setattr(type(jax.config), "jax_platforms", "tpu,cpu",
+                          raising=False)
+                enable_persistent_compile_cache()
+                assert (jax.config
+                        .jax_persistent_cache_min_compile_time_secs) == 0.0
+        finally:
+            jax.config.update(
+                "jax_persistent_cache_min_compile_time_secs", prev)
+
+    def test_there_is_no_other_knob(self, monkeypatch):
+        """No argument, no FEDTPU_* variable, no config field, no flag."""
+        import inspect
+
+        from federated_pytorch_test_tpu.drivers.common import build_parser
+
+        assert not inspect.signature(
+            enable_persistent_compile_cache).parameters
+        assert "FEDTPU" not in inspect.getsource(compile_cache)
+        assert not hasattr(FederatedConfig(), "compile_cache_dir")
+        with pytest.raises(SystemExit):
+            build_parser(FederatedConfig(), "prog").parse_args(
+                ["--compile-cache-dir", "/x"])
 
 
 class TestCompareDirections:

@@ -538,17 +538,6 @@ class TestCompareCLI:
         assert obs_compare.main([near, "--baseline", base,
                                  "--threshold", "1"]) == 1
 
-    def test_repo_bench_wrapper_vs_its_own_promotion_source(self, capsys):
-        # BENCH_r05.json is measured:false with a last_measured pointer;
-        # compare must promote the headline and exit 0 against the very
-        # artifact it points at
-        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        wrapper = os.path.join(root, "BENCH_r05.json")
-        source = os.path.join(root, "artifacts", "bench_tpu_r05_early.json")
-        assert obs_compare.main([wrapper, "--baseline", source]) == 0
-        out = capsys.readouterr().out
-        assert "PROMOTED" in out
-
     def test_empty_baseline_json_is_honest(self, tmp_path, capsys):
         root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         run = _write_run(tmp_path, "a")
@@ -559,7 +548,9 @@ class TestCompareCLI:
     def test_unmeasured_artifact_contributes_no_verdict(self, tmp_path):
         p = os.path.join(str(tmp_path), "unmeasured.json")
         with open(p, "w") as f:
-            json.dump({"metric": "m", "value": 0.0, "measured": False}, f)
+            # a pointer at another run's number must not be borrowed
+            json.dump({"metric": "m", "value": 0.0, "measured": False,
+                       "last_measured": {"path": "x.json", "value": 9.0}}, f)
         src = obs_compare.load_source(p)
         assert src["metrics"] == {} and "unmeasured" in src["notes"][0]
 

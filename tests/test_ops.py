@@ -148,6 +148,43 @@ class TestInfoNCEPallas:
         np.testing.assert_allclose(np.asarray(got_gzh), np.asarray(want_gzh),
                                    rtol=1e-4, atol=1e-6)
 
+    def test_compiled_comm_kernels_on_tpu(self):
+        """The three comm kernels COMPILED by Mosaic vs their XLA chains,
+        at the shape the fused collective produces for the largest
+        ResNet18 block ([N/256, 256], N = 4,720,640) and a [K, n] Gram
+        slab.  Interpret mode is bitwise (tests/test_comm_kernels.py);
+        the hardware contract is allclose (PARITY.md)."""
+        if jax.default_backend() != "tpu":
+            pytest.skip("real TPU backend required (FEDTPU_TEST_TPU=1)")
+        from federated_pytorch_test_tpu.ops.comm_kernels import (
+            dequant_add,
+            force_comm_kernels_impl,
+            gram_matrix,
+            quantize_chunks,
+        )
+
+        vv = _rand((4_720_640 // 256, 256), 30)
+        acc = _rand(vv.shape, 31)
+        stack = _rand((8, 1 << 20), 32)
+        got = {}
+        for impl in ("xla", "pallas"):
+            with force_comm_kernels_impl(impl):
+                q, s = jax.jit(lambda v: quantize_chunks(v, 127))(vv)
+                got[impl] = (q, s,
+                             jax.jit(lambda a, q, s: dequant_add(a, q, s))(
+                                 acc, q, s),
+                             jax.jit(lambda a: gram_matrix(a))(stack))
+        (qx, sx, ax, gx), (qp, sp, ap, gp) = got["xla"], got["pallas"]
+        np.testing.assert_allclose(np.asarray(sp), np.asarray(sx), rtol=1e-6)
+        dq = np.abs(np.asarray(qp, np.int32) - np.asarray(qx, np.int32))
+        assert dq.max() <= 1 and dq.mean() < 1e-3
+        np.testing.assert_allclose(np.asarray(ap), np.asarray(ax),
+                                   rtol=1e-5, atol=1e-5)
+        # conftest pins float32 matmul precision, so both Gram paths
+        # carry f32 products; the slab accumulation re-associates
+        np.testing.assert_allclose(np.asarray(gp), np.asarray(gx),
+                                   rtol=1e-4, atol=1e-4 * (1 << 20))
+
     def test_zero_norm_column_finite_and_consistent(self):
         """A dead (all-zero) patch column must give the same finite loss
         and finite gradients on every dispatch path (safe_norms guard)."""
