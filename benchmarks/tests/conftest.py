@@ -1,0 +1,10 @@
+"""The benchmark's tests run on the CPU with four virtual devices (the
+four-chip cell's mesh), set before anything imports JAX."""
+
+import os
+
+os.environ["JAX_PLATFORMS"] = "cpu"
+flags = os.environ.get("XLA_FLAGS", "")
+if "xla_force_host_platform_device_count" not in flags:
+    os.environ["XLA_FLAGS"] = (
+        flags + " --xla_force_host_platform_device_count=4").strip()
