@@ -13,7 +13,9 @@ that assembly at two points:
   dispatch timer.  Under jax's async dispatch the timed window covers
   trace + compile but not device execution, so when the trace counter
   moved across a dispatch the elapsed wall-seconds *are* the compile
-  wall-seconds (plus O(100us) of dispatch overhead).
+  wall-seconds (plus O(100us) of dispatch overhead).  The same elapsed
+  seconds of EVERY call add up to the window's ``dispatch_seconds``
+  (schema v15): what the host spends enqueueing the round's programs.
 
 Per compile event the ledger records wall-seconds, the site's cumulative
 trace count (1 == cold), AOT cost-model numbers, and a persistent-
@@ -118,6 +120,9 @@ class RoundCosts(NamedTuple):
     flops: float  # executed cost-model FLOPs (sum over dispatches)
     bytes_accessed: float  # executed cost-model HLO bytes
     peak_bytes: int  # max per-program peak_device_bytes dispatched
+    # host seconds inside the window's instrumented jitted calls (the
+    # timer's own t1 - t0: enqueue, plus trace + compile when it compiles)
+    dispatch_seconds: float = 0.0
 
 
 def round_cost_fields(costs: RoundCosts, t_start: float,
@@ -144,6 +149,10 @@ def round_cost_fields(costs: RoundCosts, t_start: float,
         out["hlo_bytes_accessed"] = float(costs.bytes_accessed)
     if costs.peak_bytes > 0:
         out["peak_device_bytes"] = int(costs.peak_bytes)
+    if costs.dispatch_seconds > 0:
+        # schema v15: every instrumented call drained with this window,
+        # the ones of the block switch before the round included
+        out["dispatch_seconds"] = float(costs.dispatch_seconds)
     return out
 
 
@@ -190,6 +199,7 @@ class CostLedger:
         self._exec_flops = 0.0
         self._exec_bytes = 0.0
         self._exec_peak = 0
+        self._dispatch_s = 0.0
         self._cache_dir: Optional[str] = cache_dir
         self._cache_dir_resolved = cache_dir is not None
         self._cache_entries: Optional[int] = None
@@ -217,14 +227,15 @@ class CostLedger:
         @functools.wraps(jfn)
         def timed(*args: Any, **kwargs: Any) -> Any:
             n0 = marks.get(site, 0)
-            t0 = time.perf_counter()
-            out = jfn(*args, **kwargs)
             # Async dispatch: no block_until_ready on purpose — the
-            # window must cover trace+compile, NOT device execution.
+            # window must cover trace+compile (and, summed into
+            # dispatch_seconds, the enqueue), NOT device execution.
+            t0 = time.perf_counter()  # graftlint: disable=JG104
+            out = jfn(*args, **kwargs)
             t1 = time.perf_counter()  # graftlint: disable=JG104
             if marks.get(site, 0) != n0:
                 self._on_compile(site, t0, t1, jfn, args, kwargs)
-            self._on_dispatch(site)
+            self._on_dispatch(site, t1 - t0)
             return out
 
         timed.__wrapped_jit__ = jfn  # AOT access for tests/tools
@@ -248,7 +259,8 @@ class CostLedger:
         self._events.append(ev)
         self.all_events.append(ev)
 
-    def _on_dispatch(self, site: str) -> None:
+    def _on_dispatch(self, site: str, seconds: float) -> None:
+        self._dispatch_s += seconds
         costs = self._site_costs.get(site)
         if not costs:
             return
@@ -263,11 +275,13 @@ class CostLedger:
         out = RoundCosts(events=tuple(self._events),
                          flops=self._exec_flops,
                          bytes_accessed=self._exec_bytes,
-                         peak_bytes=self._exec_peak)
+                         peak_bytes=self._exec_peak,
+                         dispatch_seconds=self._dispatch_s)
         self._events = []
         self._exec_flops = 0.0
         self._exec_bytes = 0.0
         self._exec_peak = 0
+        self._dispatch_s = 0.0
         return out
 
     # ------------------------------------------------------ aggregates

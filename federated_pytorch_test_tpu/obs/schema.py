@@ -194,8 +194,28 @@ from typing import Any, Dict
 # a block (the pre-dispatch is gated to same-block successors) and
 # whenever the lookahead cache was already spent.  Overlap-off streams
 # carry no such field and stay byte-identical to v13.
-# v1..v13 records remain valid: validate_record accepts ver <= SCHEMA_VERSION.
-SCHEMA_VERSION = 14
+# v15 (additive): the host timeline outside the round window — three
+# advisory round fields, all host perf_counter differences.
+# `block_switch_seconds`: on the first round of each block visit only,
+# the length of that visit's `block_switch` span (top of the block loop
+# body to the round's t_start: building the block's fns, sizing it,
+# staging z/y/rho/x0/yhat0, the optimizer and compressor state).
+# `gap_seconds`: on every round but the run's first, host seconds from
+# the previous round's t_end to this round's t_start; it holds the
+# previous round's tail (cost-ledger drain, checkpoint, obs emission,
+# log, on_round) and, where the block changed, the switch.
+# `dispatch_seconds`: host seconds inside the instrumented jitted calls
+# drained with this round (obs/costs.py: the timer's own t1 - t0, so a
+# compile is inside it on the round that compiles); absent when
+# cost_ledger is off.  The first two are written whether the recorder
+# is on or off.  With it on, the same stamps also become spans
+# (cat="phase", parented to the RUN span because they lie outside every
+# round window): `block_switch` with children `build_fns`, `block_size`,
+# `block_vars`, `init_opt`, and one `round_tail` per round (the `ckpt`
+# span becomes its child).  No new record kind and no new span field;
+# streams of engines that do not stamp them stay byte-identical to v14.
+# v1..v14 records remain valid: validate_record accepts ver <= SCHEMA_VERSION.
+SCHEMA_VERSION = 15
 
 EVENTS = ("run_header", "round", "summary", "span", "alert", "compile",
           "control", "client", "campaign", "serve")
@@ -277,6 +297,10 @@ FIELDS: Dict[str, Any] = {
     "overlap_seconds": (("round",), _NUM),
     # whole-round overlap (schema v14; --overlap-round)
     "overlap_dispatch_seconds": (("round",), _NUM),
+    # host timeline outside the round window (schema v15)
+    "block_switch_seconds": (("round",), _NUM),
+    "gap_seconds": (("round",), _NUM),
+    "dispatch_seconds": (("round",), _NUM),
     # fault / guard counters
     "guard_trips":  (("round",), _NUM),
     "guard_norm_mean": (("round",), _NUM),
@@ -479,6 +503,8 @@ ADVISORY_FIELDS = (
     "comm_seconds", "sync_seconds", "compute_seconds", "epoch_seconds",
     "ckpt_write_seconds", "overlap_seconds", "overlap_dispatch_seconds",
     "compile_seconds", "t_start", "t_end",
+    # host timeline outside the round window (v15)
+    "block_switch_seconds", "gap_seconds", "dispatch_seconds",
     # serving-plane latency/throughput telemetry (v13)
     "serve_p50_ms", "serve_p99_ms", "serve_qps", "swap_gap_seconds",
     "serve_accuracy", "drift_score", "forced_refresh",
@@ -571,6 +597,9 @@ VERSION_LADDER = (
                       "forced_refresh")},
     {"version": 14, "added_kinds": (),
      "added_fields": ("overlap_dispatch_seconds",)},
+    {"version": 15, "added_kinds": (),
+     "added_fields": ("block_switch_seconds", "gap_seconds",
+                      "dispatch_seconds")},
 )
 
 

@@ -9,9 +9,15 @@ Schema v5 gives the run stream a span hierarchy::
         │                     where jit compiles landed inside the round;
         │                     out-of-window events parent to the RUN span)
         └── ...
-    └── ckpt           (parented to the RUN span: the mid-run save runs
-                        after round_seconds is measured, so hanging it
-                        off the round would break laminar nesting)
+    └── block_switch   (schema v15; ahead of a block visit's first round)
+        └── build_fns / block_size / block_vars / init_opt
+    └── round_tail     (schema v15; behind every round, to on_round's
+        │               return; both hang off the RUN span because they
+        │               lie outside every round window)
+        └── ckpt       (the mid-run save runs after round_seconds is
+                        measured, so hanging it off the round would
+                        break laminar nesting; under the RUN span where
+                        an engine stamps no tail)
 
 ``python -m federated_pytorch_test_tpu.obs.trace run.jsonl -o trace.json``
 converts that into Chrome trace-event / Perfetto JSON (load in

@@ -1742,9 +1742,13 @@ class BlockwiseFederatedTrainer(RoundKernel):
             # trainer is done either way, so release them (close is the
             # documented terminal state — _stage_epoch stops prefetching).
             # The obs stream gets its summary event too, flagged aborted
-            # (idempotent: a no-op if the run closed it normally)
+            # (idempotent: a no-op if the run closed it normally), after
+            # the tail of the round that raised (it ends here: on_round
+            # is where a caller stops a run) and the other pending marks
+            self._close_round_tail()
             self.close()
             if self.obs_recorder is not None:
+                self._flush_outer_spans(self.obs_recorder)
                 self.obs_recorder.close(status="aborted")
             raise
 
@@ -1850,10 +1854,16 @@ class BlockwiseFederatedTrainer(RoundKernel):
             # recorded (applied=False) and nothing is raised
             obs.control.can_restart = checkpoint_path is not None
         obs_images = cfg.Nepoch * self._obs_epoch_images()
+        # the end stamp of the previous round, for gap_seconds (schema v15)
+        t_prev_end = None
         for nloop in range(cfg.Nloop):
             for ci in range(self.L):
                 if resume_at is not None and (nloop, ci) < resume_at[:2]:
                     continue
+                # block switch: its start, then the end of each of
+                # rounds.BLOCK_SWITCH_PARTS (plain clock reads, no sync:
+                # what these calls enqueue runs behind them)
+                switch = [time.perf_counter()]
                 if obs.control is not None:
                     # block-scope interventions (compressor swap) land
                     # HERE, before the round fns/scratch/comp-state for
@@ -1867,6 +1877,7 @@ class BlockwiseFederatedTrainer(RoundKernel):
                 # until the NEXT comm call donates them
                 train_epoch_ahead = self._fn_cache.get(
                     ("ahead", ci), train_epoch)
+                switch.append(time.perf_counter())
                 N = self.block_size(ci)
                 # donated sparse accumulator (top-k only): zeroed [K, N]
                 # buffer the comm step scatters into and hands back
@@ -1874,6 +1885,7 @@ class BlockwiseFederatedTrainer(RoundKernel):
                 # the block.  Not checkpointed — it is zeros between
                 # rounds by construction.
                 scratch = self._init_sparse_scratch(N)
+                switch.append(time.perf_counter())
                 nadmm_start = 0
                 if (resume_at is not None and (nloop, ci) == resume_at[:2]
                         and resume_at[3]):
@@ -1881,6 +1893,7 @@ class BlockwiseFederatedTrainer(RoundKernel):
                     z, y, rho, x0, yhat0 = r_blockvars
                     nadmm_start = resume_at[2]
                     resume_at = None
+                    switch.append(time.perf_counter())
                 else:
                     resume_at = None
                     # fresh per-block state (federated_multi.py:148-159);
@@ -1900,12 +1913,14 @@ class BlockwiseFederatedTrainer(RoundKernel):
                     else:
                         yhat0 = stage_global(
                             np.zeros((cfg.K, 1), np.float32), csh)
+                    switch.append(time.perf_counter())
                     state = ClientState(state.params, state.batch_stats,
                                         init_opt(state.params),
                                         self._init_comp_state(ci))
                     # fresh block => fresh guard scale, void in-flight
                     # async updates (RoundKernel)
                     self._reset_block_ledgers()
+                switch.append(time.perf_counter())
 
                 for nadmm in range(nadmm_start, cfg.Nadmm):
                     # one XProf step per comm round, keyed on the
@@ -1914,6 +1929,15 @@ class BlockwiseFederatedTrainer(RoundKernel):
                     with round_trace(len(history),
                                      enabled=cfg.profile_dir is not None):
                         t_round = time.perf_counter()
+                        switch_s = None
+                        if switch is not None:
+                            # the block visit's first round: the switch
+                            # ends where the round's window opens
+                            switch_s = t_round - switch[0]
+                            if obs.enabled:
+                                self._mark_block_switch(switch, t_round,
+                                                        len(history))
+                            switch = None
                         # the campaign tick FIRST: it derives this
                         # round's fault spec (and may raise the
                         # deterministic preempt_at event) before any
@@ -2174,14 +2198,28 @@ class BlockwiseFederatedTrainer(RoundKernel):
                         if obs.enabled:
                             phase_marks.append(
                                 ("sync", "phase", t_sync, t_sync + sync_s))
+                        rho_host = float(rho)   # a fetch: inside the window
+                        t_round_end = time.perf_counter()
                         rec = dict(nloop=nloop, block=ci, nadmm=nadmm, N=N,
-                                   loss=loss_sum, rho=float(rho),
-                                   round_seconds=time.perf_counter() - t_round,
+                                   loss=loss_sum, rho=rho_host,
+                                   round_seconds=t_round_end - t_round,
                                    stage_seconds=stage_s,
                                    train_seconds=train_s,
                                    comm_seconds=comm_s,
                                    sync_seconds=sync_s,
                                    **fcounts, **diag)
+                        # the host seconds no segment above counts (schema
+                        # v15): everything from here to on_round's return
+                        # is this round's tail and lands in the NEXT
+                        # round's gap_seconds, with the switch where the
+                        # block changes
+                        if switch_s is not None:
+                            rec["block_switch_seconds"] = switch_s
+                        if t_prev_end is not None:
+                            rec["gap_seconds"] = t_round - t_prev_end
+                        t_prev_end = t_round_end
+                        if obs.enabled:
+                            self._open_round_tail(len(history), t_round_end)
                         if self._overlap:
                             # host staging seconds hidden behind the comm
                             # dispatch (schema v7) — 0.0 on fused rounds
@@ -2289,6 +2327,8 @@ class BlockwiseFederatedTrainer(RoundKernel):
                         log(msg)
                         if on_round is not None:
                             on_round(state, rec)
+                        self._close_round_tail()
+        self._flush_outer_spans(obs)
         obs.close()
         # write barrier on run exit: every queued async checkpoint must be
         # durable before the caller sees the run as finished (a failed

@@ -48,6 +48,8 @@ Host-class contract (the engine plugin surface the mixin reads):
 from __future__ import annotations
 
 import os
+import time
+import uuid
 from typing import Optional
 
 import jax
@@ -60,6 +62,11 @@ from federated_pytorch_test_tpu.parallel.mesh import (
     stage_global,
 )
 from federated_pytorch_test_tpu.train.faults import FaultSpec
+
+
+#: the parts of a ``block_switch`` span, in the order the engine runs
+#: (and stamps) them
+BLOCK_SWITCH_PARTS = ("build_fns", "block_size", "block_vars", "init_opt")
 
 
 class RoundKernel:
@@ -135,6 +142,11 @@ class RoundKernel:
         # _emit_client_record folds them into one `client` record —
         # advisory telemetry only, never read by the math
         self._client_round: dict = {}
+        # host timeline outside the round windows (schema v15): spans
+        # already bounded and waiting for the next emission, and the
+        # tail of the round that is still running its bookkeeping
+        self._outer_marks: list = []
+        self._tail_open: Optional[tuple] = None
         # buffered-async staleness ledger (cfg.async_rounds): per-client
         # scheduled arrival round (-1 = nothing in flight) and dispatch
         # round of the in-flight update, plus the cumulative admission-
@@ -1034,6 +1046,52 @@ class RoundKernel:
         self.obs_recorder = rec
         return rec
 
+    # -- the host timeline outside the round windows (schema v15) -------
+    # A round record spans [t_round, t_round + round_seconds].  What the
+    # host does outside of that is stamped too, so that the whole run is
+    # under a span of the program's own: `block_switch` (with the parts
+    # the engine stamped inside it) ahead of a block visit's first round
+    # and `round_tail` behind every round.  The marks are perf_counter
+    # stamps the engine took anyway; they are kept only while the
+    # recorder is on, and a mark is EMITTED with the next round's spans
+    # (or at the run's end), because emitting is itself part of a tail.
+    def _mark_block_switch(self, stamps, t_end: float,
+                           round_index: int) -> None:
+        """``stamps``: the switch's start, then the end of each part in
+        ``BLOCK_SWITCH_PARTS`` order; ``t_end``: the first round's
+        ``t_round``."""
+        sid = uuid.uuid4().hex[:12]
+        self._outer_marks.append(
+            ("block_switch", stamps[0], t_end, round_index, sid, None))
+        for name, t0, t1 in zip(BLOCK_SWITCH_PARTS, stamps, stamps[1:]):
+            self._outer_marks.append(
+                (name, t0, t1, round_index, uuid.uuid4().hex[:12], sid))
+
+    def _open_round_tail(self, round_index: int, t0: float) -> None:
+        """The round's window just closed at ``t0``.  The id the tail's
+        span will carry is fixed now, for the ``ckpt`` child that is
+        emitted before the tail ends."""
+        self._tail_open = (round_index, t0, uuid.uuid4().hex[:12])
+
+    def _close_round_tail(self) -> None:
+        """The round's bookkeeping is over (``on_round`` returned, or the
+        run is being aborted from inside it).  No-op without an open
+        tail."""
+        if self._tail_open is not None:
+            round_index, t0, sid = self._tail_open
+            self._tail_open = None
+            self._outer_marks.append(
+                ("round_tail", t0, time.perf_counter(), round_index, sid,
+                 None))
+
+    def _flush_outer_spans(self, obs) -> None:
+        """Emit the pending marks as spans under the RUN span (they lie
+        outside every round window, like ``ckpt`` always did)."""
+        marks, self._outer_marks = self._outer_marks, []
+        for name, t0, t1, round_index, sid, parent in marks:
+            obs.span(name, t0, t1, cat="phase", round_index=round_index,
+                     span_id=sid, parent_span=parent)
+
     def _emit_client_record(self, obs, round_index: int, N: int,
                             loss_host, cl_nrm, cl_dist) -> None:
         """Fold this round's per-client host arrays — the activity/guard
@@ -1072,9 +1130,12 @@ class RoundKernel:
 
         ``phase_marks`` is ``[(name, cat, t0, t1), ...]`` span bounds
         the engine collected from timestamps it already took; the ckpt
-        span (after ``round_seconds`` is measured) and late-drained
-        compile events hang off the RUN span to keep nesting laminar
-        (obs/trace.py)."""
+        span (after ``round_seconds`` is measured) hangs off the round's
+        open ``round_tail`` span (the RUN span where an engine stamps no
+        tail) and late-drained compile events off the
+        RUN span, to keep nesting laminar (obs/trace.py).  The spans
+        stamped outside the round windows since the last emission (the
+        previous round's tail, this round's block switch) ride along."""
         from federated_pytorch_test_tpu.obs import device_memory_stats
 
         if not (obs.enabled or obs.health is not None
@@ -1103,15 +1164,18 @@ class RoundKernel:
             self._serve_tick(obs, round_index, state, log=log)
         if obs.enabled:
             rspan = (rrec or {}).get("span_id")
+            self._flush_outer_spans(obs)
             for nm, cat, s0, s1 in phase_marks:
                 obs.span(nm, s0, s1, cat=cat, round_index=round_index,
                          parent_span=rspan)
             if t_ckpt is not None:
                 # the mid-run save runs AFTER round_seconds is measured,
-                # so its span hangs off the RUN span
+                # so its span lies in the round's tail, not in the round
                 obs.span("ckpt", t_ckpt,
                          t_ckpt + rec["ckpt_write_seconds"],
-                         cat="ckpt", round_index=round_index)
+                         cat="ckpt", round_index=round_index,
+                         parent_span=(self._tail_open[2]
+                                      if self._tail_open else None))
             t_hi = t_round + rec["round_seconds"] + 1e-9
             for cev in ledger_events:
                 # in-window compiles nest inside the round span; late-

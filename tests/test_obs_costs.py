@@ -11,6 +11,7 @@ reconciliation math against hand-computed numbers.
 
 import json
 import os
+import time
 
 import jax
 import jax.numpy as jnp
@@ -215,6 +216,19 @@ class TestLedgerUnit:
                           "peak_device_bytes": 4096}
         assert isinstance(fields["peak_device_bytes"], int)
 
+    def test_round_cost_fields_dispatch_seconds(self):
+        # schema v15: written when the window held a dispatch, omitted
+        # (not zeroed) when it held none or the field was never set
+        costs = RoundCosts(events=(), flops=0.0, bytes_accessed=0.0,
+                           peak_bytes=0, dispatch_seconds=0.0125)
+        assert round_cost_fields(costs, t_start=0.0, seconds=1.0) == {
+            "dispatch_seconds": pytest.approx(0.0125)}
+        bare = RoundCosts(events=(), flops=0.0, bytes_accessed=0.0,
+                          peak_bytes=0)
+        assert bare.dispatch_seconds == 0.0
+        assert round_cost_fields(bare, t_start=0.0, seconds=1.0) == {}
+        validate_record(round_record(dispatch_seconds=0.0125))
+
     def test_event_record_omits_absent_fields(self):
         ev = CompileEvent(site="s", seconds=0.1, t_start=0.0, t_end=0.1,
                           trace_count=1, cache_hit=None, costs={})
@@ -308,6 +322,36 @@ class TestLedgerJit:
         if "flops" in led.all_events[0].costs:
             assert rc3.flops == pytest.approx(
                 2 * led.all_events[0].costs["flops"])
+
+    @pytest.mark.parametrize("aot_mode", ["off", "lowered"])
+    def test_dispatch_seconds_accumulates_and_resets(self, aot_mode):
+        """Every instrumented call's own timer adds up, compiling or not,
+        with or without a cost model; ``drain`` hands it out and resets."""
+        led = CostLedger(aot_mode=aot_mode, cache_dir="")
+        f = _instrumented(led, "a", lambda x: x * 3.0)
+        g = _instrumented(led, "b", lambda x: x - 3.0)
+        assert led.drain().dispatch_seconds == 0.0
+        x = jnp.ones((16,))
+        f(x)                                    # compiles: inside the sum
+        cold = led.drain()
+        assert len(cold.events) == 1
+        assert cold.dispatch_seconds >= cold.events[0].seconds > 0
+        assert led.drain().dispatch_seconds == 0.0       # reset
+        f(x)
+        one = led.drain().dispatch_seconds
+        assert 0 < one < cold.dispatch_seconds           # warm: no compile
+        g(x)
+        led.drain()
+        t0 = time.perf_counter()
+        for _ in range(5):
+            f(x)
+            g(x)
+        wall = time.perf_counter() - t0
+        many = led.drain()
+        assert many.events == ()
+        assert 0 < many.dispatch_seconds <= wall         # both sites, summed
+        fields = round_cost_fields(many, t_start=t0, seconds=wall)
+        assert fields["dispatch_seconds"] == many.dispatch_seconds
 
     def test_off_mode_records_timing_only(self):
         led = CostLedger(aot_mode="off", cache_dir="")
