@@ -148,6 +148,10 @@ def instrument_jit(fn: Callable, name: str, *, sanitize: bool,
     """
     if sanitize:
         fn = checkify_callable(fn)
+        if "out_shardings" in jit_kwargs:
+            # the checkified fn returns (error, outputs): the error
+            # payload's placement is left to jax
+            jit_kwargs["out_shardings"] = (None, jit_kwargs["out_shardings"])
     if sentinel is not None:
         fn = sentinel.wrap(fn, name)
     if ledger is not None:

@@ -214,8 +214,17 @@ from typing import Any, Dict
 # `block_vars`, `init_opt`, and one `round_tail` per round (the `ckpt`
 # span becomes its child).  No new record kind and no new span field;
 # streams of engines that do not stamp them stay byte-identical to v14.
-# v1..v14 records remain valid: validate_record accepts ver <= SCHEMA_VERSION.
-SCHEMA_VERSION = 15
+# v16 (additive): one advisory round field beside `block_switch_seconds`
+# (same rounds: the first of each block visit, recorder on or off).
+# `block_switch_h2d_bytes`: bytes that block switch staged from host
+# memory.  The per-block z/y/rho/x0/yhat0 (and top-k's scratch) are made
+# by a device program, so this is 0 except for a stateful compressor's
+# fresh rows (`_init_comp_state`: q8's PRNG rows, error feedback's
+# residual), and 0 on a resumed segment's first round (the restore
+# staged its arrays ahead of the switch).  Advisory: a resumed segment
+# stamps it on a round where the uninterrupted run has no switch.
+# v1..v15 records remain valid: validate_record accepts ver <= SCHEMA_VERSION.
+SCHEMA_VERSION = 16
 
 EVENTS = ("run_header", "round", "summary", "span", "alert", "compile",
           "control", "client", "campaign", "serve")
@@ -301,6 +310,8 @@ FIELDS: Dict[str, Any] = {
     "block_switch_seconds": (("round",), _NUM),
     "gap_seconds": (("round",), _NUM),
     "dispatch_seconds": (("round",), _NUM),
+    # host bytes staged at a block switch (schema v16)
+    "block_switch_h2d_bytes": (("round",), _NUM),
     # fault / guard counters
     "guard_trips":  (("round",), _NUM),
     "guard_norm_mean": (("round",), _NUM),
@@ -505,6 +516,8 @@ ADVISORY_FIELDS = (
     "compile_seconds", "t_start", "t_end",
     # host timeline outside the round window (v15)
     "block_switch_seconds", "gap_seconds", "dispatch_seconds",
+    # host bytes staged at a block switch (v16)
+    "block_switch_h2d_bytes",
     # serving-plane latency/throughput telemetry (v13)
     "serve_p50_ms", "serve_p99_ms", "serve_qps", "swap_gap_seconds",
     "serve_accuracy", "drift_score", "forced_refresh",
@@ -600,6 +613,8 @@ VERSION_LADDER = (
     {"version": 15, "added_kinds": (),
      "added_fields": ("block_switch_seconds", "gap_seconds",
                       "dispatch_seconds")},
+    {"version": 16, "added_kinds": (),
+     "added_fields": ("block_switch_h2d_bytes",)},
 )
 
 

@@ -329,6 +329,15 @@ def stage_global(x, sharding: NamedSharding):
     data/cifar10.py), and ``jax.make_array_from_callback`` materialises
     only this process's addressable shards — each host feeds its own
     slice of the client axis, nothing is sent over DCN at staging time.
+
+    Callers left: what really starts on the host.  Epoch data, keys and
+    the per-round masks (``_stage_epoch``, ``train/rounds.py``), the
+    client norm stats (``cnorm``) and test set, checkpoint restore and
+    the common init (through ``stage_tree_global``), a stateful
+    compressor's fresh rows, and the small zeros of the independent and
+    CPC loops.  The blockwise round loop's block switch is no longer
+    one: its z / y / rho / x0 / yhat0 and top-k's scratch are made on
+    the device (``BlockwiseFederatedTrainer._fresh_fn``).
     """
     if _process_count() == 1:
         return jax.device_put(x, sharding)

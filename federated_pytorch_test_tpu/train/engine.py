@@ -254,6 +254,7 @@ class BlockwiseFederatedTrainer(RoundKernel):
         self.batch_stats0 = stage_tree_global(stack(batch_stats), csh)
 
         self._fn_cache: Dict[Any, Any] = {}
+        self._block_sizes: Dict[Any, int] = {}     # block_size(), by paths
         # retrace sentinel: counts jit traces of the instrumented step
         # functions (analysis/sanitize.py); None when off so the step
         # builders wrap nothing and the jitted chain is literally the
@@ -399,8 +400,18 @@ class BlockwiseFederatedTrainer(RoundKernel):
         return blocklib.build_mask(jax.tree.map(lambda _: 0, self.params0), paths)
 
     def block_size(self, ci: Optional[int]) -> int:
-        one = jax.tree.map(lambda x: x[0], self.params0)
-        return codec.masked_size(one, self.order, self.mask_for_block(ci))
+        """Flat size ``N`` of sweep unit ``ci`` (``None``: the whole net),
+        read from the leaves' shapes alone (no device op) and kept per
+        set of active paths: callers may re-point ``block_ids`` after
+        construction, so the key is what ``sweep_paths`` returns now."""
+        key = None if ci is None else tuple(self.sweep_paths(ci))
+        if key not in self._block_sizes:
+            one = jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype),
+                self.params0)
+            self._block_sizes[key] = codec.masked_size(
+                one, self.order, self.mask_for_block(ci))
+        return self._block_sizes[key]
 
     def optimizer_for_block(self, ci: Optional[int]) -> str:
         """'adam' | 'lbfgs' — the VAE-CL driver switches per block
@@ -1469,6 +1480,46 @@ class BlockwiseFederatedTrainer(RoundKernel):
         return stage_tree_global(jax.tree.unflatten(treedef, out),
                                  client_sharding(self.mesh))
 
+    def _fresh_fn(self, name: str, make, shardings):
+        """``jit(make)`` of a program WITHOUT operands: every call hands
+        back fresh device buffers (the comm step donates them), filled
+        where they live instead of copied from host memory.
+        ``out_shardings`` are the ``NamedSharding`` objects
+        ``stage_global`` committed the host arrays to, letter for letter
+        (on a one-device mesh a ``shard_map`` output would come back as
+        ``P()`` whatever its spec), so the round fns see the signatures
+        they always saw and hit the same compiled entries.  Cached by
+        ``name`` beside the block's other fns and instrumented like
+        them, so the cost ledger and the retrace sentinel see it."""
+        key = ("fresh", name)
+        if key not in self._fn_cache:
+            self._fn_cache[key] = self._instrument_jit(
+                make, name, out_shardings=shardings)
+        return self._fn_cache[key]
+
+    def _fresh_block_vars(self, N: int):
+        """Fresh per-block ``(z, y, rho, x0)`` — plus ``yhat0`` where
+        ``bb_update`` is off (under BB it is the gather of the params at
+        block start) — made by one device program per ``(N, ydim, x0
+        width)`` (federated_multi.py:148-159): zeros and ``admm_rho0``,
+        float32, z/rho replicated and the [K, .] stacks client-sharded."""
+        cfg = self.cfg
+        ydim = N if self.algo.needs_dual else 1
+        x0w = N if cfg.bb_update else 1
+        rsh, csh = replicated_sharding(self.mesh), client_sharding(self.mesh)
+
+        def make():
+            zeros = lambda *shape: jnp.zeros(shape, jnp.float32)
+            out = (zeros(N), zeros(cfg.K, ydim),
+                   jnp.asarray(cfg.admm_rho0, jnp.float32),
+                   zeros(cfg.K, x0w))
+            return out if cfg.bb_update else out + (zeros(cfg.K, 1),)
+
+        shardings = (rsh, csh, rsh, csh)
+        return self._fresh_fn(
+            f"block_vars[N={N},y={ydim},x0={x0w}]", make,
+            shardings if cfg.bb_update else shardings + (csh,))()
+
     def _init_sparse_scratch(self, N: int):
         """Zeroed [K, N] accumulator the sparse top-k comm step scatters
         into and hands back re-zeroed — the donated operand that lets XLA
@@ -1477,8 +1528,11 @@ class BlockwiseFederatedTrainer(RoundKernel):
         on every non-sparse path so default signatures are untouched."""
         if not getattr(self.compressor, "sparse", False):
             return None
-        return stage_global(np.zeros((self.cfg.K, N), np.float32),
-                            client_sharding(self.mesh))
+        K = self.cfg.K
+        return self._fresh_fn(
+            f"sparse_scratch[N={N}]",
+            lambda: jnp.zeros((K, N), jnp.float32),
+            client_sharding(self.mesh))()
 
     def round_bytes_on_wire(self, N: int, n_active: int) -> int:
         """Uplink bytes this comm round: every participant ships one
@@ -1773,7 +1827,6 @@ class BlockwiseFederatedTrainer(RoundKernel):
         state = state or self.init_state()
         history: List[Dict[str, Any]] = []
         csh = client_sharding(self.mesh)
-        rsh = replicated_sharding(self.mesh)
 
         from federated_pytorch_test_tpu.utils.checkpoint import (
             CheckpointCorruptError,
@@ -1887,6 +1940,9 @@ class BlockwiseFederatedTrainer(RoundKernel):
                 scratch = self._init_sparse_scratch(N)
                 switch.append(time.perf_counter())
                 nadmm_start = 0
+                # bytes this switch stages from host memory (schema v16):
+                # a stateful compressor's fresh rows, nothing else
+                switch_h2d = 0
                 if (resume_at is not None and (nloop, ci) == resume_at[:2]
                         and resume_at[3]):
                     # resume inside this block: restored z/y/rho/BB/opt state
@@ -1896,27 +1952,21 @@ class BlockwiseFederatedTrainer(RoundKernel):
                     switch.append(time.perf_counter())
                 else:
                     resume_at = None
-                    # fresh per-block state (federated_multi.py:148-159);
-                    # stage_global so multi-host stages local shards only
-                    z = stage_global(np.zeros((N,), np.float32), rsh)
-                    ydim = N if algo.needs_dual else 1
-                    y = stage_global(
-                        np.zeros((cfg.K, ydim), np.float32), csh)
-                    rho = stage_global(
-                        np.asarray(cfg.admm_rho0, np.float32), rsh)
-                    x0 = stage_global(
-                        np.zeros((cfg.K, N if cfg.bb_update else 1),
-                                 np.float32), csh)
-                    # yhat0 init = params at block start (consensus_multi.py:184)
+                    # fresh per-block state (federated_multi.py:148-159),
+                    # made on the device: no array leaves host memory
                     if cfg.bb_update:
+                        # yhat0 init = params at block start
+                        # (consensus_multi.py:184)
+                        z, y, rho, x0 = self._fresh_block_vars(N)
                         yhat0 = self._build_gather(ci)(state.params)
                     else:
-                        yhat0 = stage_global(
-                            np.zeros((cfg.K, 1), np.float32), csh)
+                        z, y, rho, x0, yhat0 = self._fresh_block_vars(N)
                     switch.append(time.perf_counter())
+                    comp = self._init_comp_state(ci)
+                    switch_h2d = sum(int(x.nbytes)
+                                     for x in jax.tree.leaves(comp))
                     state = ClientState(state.params, state.batch_stats,
-                                        init_opt(state.params),
-                                        self._init_comp_state(ci))
+                                        init_opt(state.params), comp)
                     # fresh block => fresh guard scale, void in-flight
                     # async updates (RoundKernel)
                     self._reset_block_ledgers()
@@ -2215,6 +2265,7 @@ class BlockwiseFederatedTrainer(RoundKernel):
                         # block changes
                         if switch_s is not None:
                             rec["block_switch_seconds"] = switch_s
+                            rec["block_switch_h2d_bytes"] = switch_h2d
                         if t_prev_end is not None:
                             rec["gap_seconds"] = t_round - t_prev_end
                         t_prev_end = t_round_end

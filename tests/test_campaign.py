@@ -115,7 +115,10 @@ def det_view(rec):
     # differ between a resumed process and an uninterrupted one
     return {k: v for k, v in rec.items()
             if isinstance(v, (int, float)) and not k.endswith("_seconds")
-            and k not in ("cache_hit", "peak_device_bytes")}
+            and k not in ("cache_hit", "peak_device_bytes",
+                          # a resumed segment's first round is a block
+                          # visit's first round: it stamps a switch
+                          "block_switch_h2d_bytes")}
 
 
 # ----------------------------------------------------------------------

@@ -445,7 +445,10 @@ def _strip(rec):
     return {k: v for k, v in rec.items()
             if isinstance(v, (int, float)) and not k.endswith("_seconds")
             and k not in ("cache_hit", "peak_device_bytes", "flops_round",
-                          "hlo_bytes_accessed")}
+                          "hlo_bytes_accessed",
+                          # a resumed segment's first round is a block
+                          # visit's first round: it stamps a switch
+                          "block_switch_h2d_bytes")}
 
 
 class TestEngineRobustChunked:

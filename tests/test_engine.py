@@ -567,3 +567,253 @@ class TestMultihostHelpers:
         x = np.arange(4 * 5, dtype=np.float32).reshape(4, 5)
         staged = meshmod.stage_global(x, sh)
         np.testing.assert_array_equal(meshmod.fetch(staged), x)
+
+
+# ----------------------------------------------------------------------
+# the block switch: per-block state made by a device program, block_size
+# from shapes
+# ----------------------------------------------------------------------
+def _stage_switch_from_host(t):
+    """Put the block switch of PR 26's parent back on trainer ``t``: z, y,
+    rho, x0, yhat0 and top-k's scratch as host arrays through
+    ``stage_global`` (a copy of the old staging, kept here as the pin)."""
+    from federated_pytorch_test_tpu.parallel.mesh import (
+        client_sharding, replicated_sharding, stage_global,
+    )
+    cfg = t.cfg
+    rsh, csh = replicated_sharding(t.mesh), client_sharding(t.mesh)
+
+    def block_vars(N):
+        z = stage_global(np.zeros((N,), np.float32), rsh)
+        ydim = N if t.algo.needs_dual else 1
+        y = stage_global(np.zeros((cfg.K, ydim), np.float32), csh)
+        rho = stage_global(np.asarray(cfg.admm_rho0, np.float32), rsh)
+        x0 = stage_global(
+            np.zeros((cfg.K, N if cfg.bb_update else 1), np.float32), csh)
+        if cfg.bb_update:
+            return z, y, rho, x0
+        return z, y, rho, x0, stage_global(
+            np.zeros((cfg.K, 1), np.float32), csh)
+
+    def scratch(N):
+        if not getattr(t.compressor, "sparse", False):
+            return None
+        return stage_global(np.zeros((cfg.K, N), np.float32), csh)
+
+    t._fresh_block_vars = block_vars
+    t._init_sparse_scratch = scratch
+
+
+def _old_block_size(t, ci):
+    """``block_size`` as it was: client 0 sliced out of every leaf."""
+    one = jax.tree.map(lambda x: x[0], t.params0)
+    return codec.masked_size(one, t.order, t.mask_for_block(ci))
+
+
+SWITCH_CASES = {
+    "admm": (AdmmConsensus, {}),
+    "fedavg": (FedAvg, {}),
+    "admm-bb": (AdmmConsensus, {"bb_update": True, "Nadmm": 3}),
+    "admm-topk": (AdmmConsensus, {"compress": "topk"}),
+    "fedavg-q8": (FedAvg, {"compress": "q8"}),
+}
+
+
+class TestBlockSwitchOnDevice:
+    def _run(self, data, algo, host_staged, **kw):
+        from federated_pytorch_test_tpu.obs.schema import ADVISORY_FIELDS
+        from federated_pytorch_test_tpu.parallel.mesh import fetch
+
+        t = BlockwiseFederatedTrainer(
+            Net(), small_cfg(retrace_sentinel=True, **kw), data, algo())
+        if host_staged:
+            _stage_switch_from_host(t)
+        seen, emit = [], t._emit_round_obs
+
+        def spy(*a, **k):
+            # z, y after each round's exchange (the loop keeps them
+            # internal); fetched now, the next round donates them
+            seen.append([np.asarray(fetch(v)) for v in k["blockvars"]])
+            return emit(*a, **k)
+
+        t._emit_round_obs = spy
+        state, hist = t.run(log=lambda m: None)
+        # the cost model's per-round totals count the switch's program too
+        skip = ADVISORY_FIELDS + ("cache_hit", "flops_round",
+                                  "hlo_bytes_accessed", "peak_device_bytes")
+        core = [{k: v for k, v in r.items() if k not in skip}
+                for r in hist]
+        return t, jax.device_get(state.params), core, seen
+
+    @pytest.mark.parametrize("D", [1, 4])
+    @pytest.mark.parametrize("case", sorted(SWITCH_CASES))
+    def test_bitwise_the_host_staged_switch(self, data, case, D):
+        algo, kw = SWITCH_CASES[case]
+        kw = dict(kw, Nloop=2, num_devices=D)
+        t_new, p_new, h_new, v_new = self._run(data, algo, False, **kw)
+        t_old, p_old, h_old, v_old = self._run(data, algo, True, **kw)
+        assert h_new == h_old and len(h_new) == 2 * 2 * kw.get("Nadmm", 2)
+        jax.tree.map(np.testing.assert_array_equal, p_new, p_old)
+        for new, old in zip(v_new, v_old):          # z, y, rho, x0, yhat0
+            for a, b in zip(new, old):
+                assert a.dtype == b.dtype and a.shape == b.shape
+                np.testing.assert_array_equal(a, b)
+        # the device-made arrays are committed to the shardings the
+        # host-staged ones had: the round fns saw the same signatures,
+        # so their jit caches hold as many entries either way
+        entries = []
+        for t in (t_new, t_old):
+            train_epoch, comm_fns, _ = t._fn_cache[("blk", 0)]
+            entries.append([f.__wrapped_jit__._cache_size()
+                            for f in (train_epoch, *comm_fns.values())])
+        assert entries[0] == entries[1]
+
+    @pytest.mark.parametrize("D", [1, 4])
+    @pytest.mark.parametrize("case", ["admm", "fedavg", "admm-bb"])
+    def test_made_under_the_staged_shardings(self, data, case, D):
+        from federated_pytorch_test_tpu.parallel.mesh import (
+            client_sharding, replicated_sharding,
+        )
+        algo, kw = SWITCH_CASES[case]
+        t = BlockwiseFederatedTrainer(
+            Net(), small_cfg(num_devices=D, **kw), data, algo())
+        rsh, csh = replicated_sharding(t.mesh), client_sharding(t.mesh)
+        N = t.block_size(1)
+        made = t._fresh_block_vars(N)
+        assert len(made) == (4 if t.cfg.bb_update else 5)
+        want = [((N,), rsh), ((K, N if t.algo.needs_dual else 1), csh),
+                ((), rsh), ((K, N if t.cfg.bb_update else 1), csh),
+                ((K, 1), csh)]
+        for a, (shape, sh) in zip(made, want):
+            assert a.shape == shape and a.dtype == jnp.float32
+            assert a.sharding == sh and a.committed
+            assert a.sharding.spec == sh.spec    # not only equivalent
+        assert np.asarray(made[2]) == np.float32(t.cfg.admm_rho0)
+        assert not any(np.asarray(a).any() for a in made[:2] + made[3:])
+        # fresh buffers on every call: the comm step donates them
+        again = t._fresh_block_vars(N)
+        shards = [s for a in (*made, *again) for s in a.addressable_shards]
+        assert len({s.data.unsafe_buffer_pointer() for s in shards}) \
+            == len(shards)
+        t.close()
+
+    @pytest.mark.parametrize("case", sorted(SWITCH_CASES))
+    def test_second_sweep_compiles_and_retraces_nothing(self, data, case):
+        algo, kw = SWITCH_CASES[case]
+        t = BlockwiseFederatedTrainer(
+            Net(), small_cfg(Nloop=3, retrace_sentinel=True, **kw), data,
+            algo())
+        events = []
+
+        def on_round(state, rec):
+            events.append(len(t._ledger.all_events))
+
+        _, hist = t.run(log=lambda m: None, on_round=on_round)
+        per_sweep = len(hist) // 3
+        assert all(r["jit_retraces"] == 0 for r in hist)
+        assert all("compile_seconds" not in r for r in hist[per_sweep:])
+        assert events[per_sweep - 1] == events[-1]      # ledger flat
+        assert t._sentinel.retraces == 0
+        fresh = [k for k in t._fn_cache if k[0] == "fresh"]
+        sparse = getattr(t.compressor, "sparse", False)
+        assert len(fresh) == t.L * (2 if sparse else 1)
+
+    def test_no_block_state_leaves_host_memory(self, data, monkeypatch):
+        """Nothing of a block's size goes through ``stage_global`` at a
+        switch: ADMM, BB and plain top-k stage no [K, N] / [N] array."""
+        from federated_pytorch_test_tpu.train import engine as engine_mod
+
+        for case in ("admm", "admm-bb", "admm-topk"):
+            algo, kw = SWITCH_CASES[case]
+            t = BlockwiseFederatedTrainer(Net(), small_cfg(**kw), data,
+                                          algo())
+            sizes = {t.block_size(ci) for ci in range(t.L)}
+            staged, real = [], engine_mod.stage_global
+
+            def counted(x, sharding):
+                staged.append(np.shape(x))
+                return real(x, sharding)
+
+            monkeypatch.setattr(engine_mod, "stage_global", counted)
+            t.run(log=lambda m: None)
+            monkeypatch.setattr(engine_mod, "stage_global", real)
+            assert staged                               # epoch data, keys
+            assert not [s for s in staged if s and s[-1] in sizes]
+
+
+def _skeleton(cls, model, *sample):
+    """A trainer of class ``cls`` with just what ``block_size`` reads
+    (order, block partition, sweep, the [K]-stacked ``params0``), its
+    leaves zero-copy numpy views of the model's real shapes: building a
+    ResNet18 trainer through ``__init__`` costs 20 s of op-by-op init."""
+    params, _ = jax.eval_shape(
+        lambda: model.init_variables(jax.random.PRNGKey(0), *sample))
+    t = object.__new__(cls)
+    t.order = model.param_order()
+    t.block_ids = model.train_order_block_ids()
+    t.L = len(t.block_ids)
+    t.params0 = jax.tree.map(
+        lambda v: np.broadcast_to(np.zeros((), v.dtype), (2,) + v.shape),
+        params)
+    t._block_sizes = {}
+    return t
+
+
+class TestBlockSizeFromShapes:
+    def _check(self, t):
+        want = [_old_block_size(t, ci) for ci in range(t.L)]
+        whole = _old_block_size(t, None)
+        got = [t.block_size(ci) for ci in range(t.L)]
+        assert got == want and t.block_size(None) == whole
+        assert all(type(n) is int and n > 0 for n in got)
+        # kept per set of paths: asked again, nothing is recomputed
+        t.mask_for_block = lambda ci: pytest.fail("block_size() not kept")
+        assert [t.block_size(ci) for ci in range(t.L)] == got
+        del t.mask_for_block
+        return got
+
+    def test_all_ten_resnet18_blocks(self):
+        from federated_pytorch_test_tpu.models import ResNet18
+
+        t = _skeleton(BlockwiseFederatedTrainer, ResNet18(),
+                      jnp.zeros((1, 32, 32, 3)))
+        got = self._check(t)
+        assert len(got) == 10 and got[0] == 1856 and got[8] == 4720640
+        assert sum(got) == t.block_size(None) == 11173962
+        # the benchmark re-points block_ids after construction
+        t.block_ids = [t.block_ids[b] for b in (8, 0)]
+        t.L = 2
+        assert [t.block_size(0), t.block_size(1)] == [4720640, 1856]
+        assert t.block_size(0) == _old_block_size(t, 0)
+
+    def test_reads_shapes_only(self, data):
+        """No program is dispatched: params0 is never indexed."""
+        t = BlockwiseFederatedTrainer(Net(), small_cfg(), data, FedAvg())
+
+        class Shapes:
+            def __init__(self, x):
+                self.shape, self.dtype = x.shape, x.dtype
+
+        t.params0 = jax.tree.map(Shapes, t.params0)
+        assert [t.block_size(0), t.block_size(1)] == [304, 2570]
+        assert t.block_size(None) == 2874
+        t.close()
+
+    @pytest.mark.parametrize("which", ["vae-layers", "vae-cl"])
+    def test_vae_trainers(self, which):
+        from federated_pytorch_test_tpu.models.vae import AutoEncoderCNN
+        from federated_pytorch_test_tpu.models.vae_cl import AutoEncoderCNNCL
+        from federated_pytorch_test_tpu.train.vae_engine import (
+            VAECLTrainer, VAETrainer,
+        )
+        sample = (jnp.zeros((1, 32, 32, 3)), jax.random.PRNGKey(0))
+        if which == "vae-layers":
+            t = _skeleton(VAETrainer, AutoEncoderCNN(), *sample)
+            assert t.sweep == "layers"
+        else:
+            t = _skeleton(VAECLTrainer, AutoEncoderCNNCL(), *sample)
+            assert t.sweep == "blocks"
+        got = self._check(t)
+        assert len(got) == t.L
+        if which == "vae-layers":       # every layer once: sizes add up
+            assert sum(got) == t.block_size(None)

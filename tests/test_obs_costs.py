@@ -417,7 +417,10 @@ class TestEngineIntegration:
         assert len(compiles) == len(t._ledger.all_events) > 0
         for c in compiles:
             validate_record(c)
-            assert c["site"].startswith(("train_epoch[", "comm["))
+            # every instrumented site: the round fns and the block
+            # switch's device program (its compile lies outside a round)
+            assert c["site"].startswith(("train_epoch[", "comm[",
+                                         "block_vars["))
             assert c["compile_seconds"] > 0
 
     def test_summary_totals_match_events(self, cost_run):

@@ -1,4 +1,4 @@
-"""The host timeline outside the round windows (obs schema v15).
+"""The host timeline outside the round windows (obs schema v15, v16).
 
 A round record spans ``[t_round, t_round + round_seconds]``.  The engine
 also stamps what the host does outside of it: ``block_switch`` (with its
@@ -6,12 +6,16 @@ parts ``build_fns`` / ``block_size`` / ``block_vars`` / ``init_opt``)
 ahead of each block visit's first round and ``round_tail`` behind every
 round, so that rounds, switches and tails tile the whole run, and three
 round fields carry the same seconds whether the recorder is on or off:
-``block_switch_seconds``, ``gap_seconds``, ``dispatch_seconds``.
+``block_switch_seconds``, ``gap_seconds``, ``dispatch_seconds``.  Beside
+the first (schema v16): ``block_switch_h2d_bytes``, the bytes that switch
+staged from host memory; the per-block state is made on the device, so
+only a stateful compressor's fresh rows are left to count.
 """
 
 from __future__ import annotations
 
 import flax.linen as nn
+import jax
 import pytest
 
 from federated_pytorch_test_tpu.data.cifar10 import FederatedCifar10
@@ -171,11 +175,13 @@ def test_round_fields(streams, algo, fused):
     for i, rec in enumerate(hist):
         first_of_block = rec["nadmm"] == 0
         assert ("block_switch_seconds" in rec) == first_of_block
+        assert ("block_switch_h2d_bytes" in rec) == first_of_block
         assert ("gap_seconds" in rec) == (i > 0)
         before = rec.get("gap_seconds", rec.get("block_switch_seconds"))
         if first_of_block:
             assert rec["block_switch_seconds"] > 0
             assert before >= rec["block_switch_seconds"]
+            assert rec["block_switch_h2d_bytes"] == 0    # all on the device
         # the instrumented calls drained with a round ran in its window
         # or in the gap ahead of it (init_opt, at a block switch)
         assert 0 < rec["dispatch_seconds"] <= rec["round_seconds"] + before
@@ -218,6 +224,7 @@ def test_obs_off_makes_no_span_and_keeps_the_fields(data, algo, fused,
     assert len(hist) == BLOCKS * ROUNDS
     for i, rec in enumerate(hist):
         assert ("block_switch_seconds" in rec) == (rec["nadmm"] == 0)
+        assert ("block_switch_h2d_bytes" in rec) == (rec["nadmm"] == 0)
         assert ("gap_seconds" in rec) == (i > 0)
         assert rec["dispatch_seconds"] > 0
 
@@ -288,9 +295,49 @@ def test_ckpt_span_is_a_child_of_its_rounds_tail(data, tmp_path):
     obs_trace.validate_chrome_trace(obs_trace.to_chrome_trace(records))
 
 
-def test_resume_inside_a_block_stamps_a_switch_too(data, tmp_path):
+H2D_CASES = [
+    # (id, algorithm, config) -> what the switch still stages from the host
+    pytest.param("admm", {}, False, id="admm"),
+    pytest.param("fedavg", {}, False, id="fedavg"),
+    pytest.param("admm", {"bb_update": True}, False, id="admm-bb"),
+    pytest.param("admm", {"compress": "topk"}, False, id="topk-plain"),
+    pytest.param("fedavg", {"compress": "topk", "error_feedback": True},
+                 True, id="topk-error-feedback"),
+    pytest.param("admm", {"compress": "q8"}, True, id="q8"),
+]
+
+
+@pytest.mark.parametrize("algo,kw,stateful", H2D_CASES)
+def test_h2d_bytes_counts_what_the_switch_stages(data, algo, kw, stateful):
+    """Under 64 bytes wherever the block's state is zeros and rho0 (made
+    on the device); a compressor with state of its own still stages its
+    fresh rows from the host, and the field counts exactly those."""
+    t, hist = run(data, algo, False, obs_sinks="none", **kw)
+    firsts = [r for r in hist if "block_switch_seconds" in r]
+    assert len(firsts) == BLOCKS
+    assert all(("block_switch_h2d_bytes" in r)
+               == ("block_switch_seconds" in r) for r in hist)
+    for ci, rec in enumerate(firsts):
+        comp = t._init_comp_state(ci)
+        rows = sum(x.nbytes for x in jax.tree.leaves(comp))
+        assert rec["block_switch_h2d_bytes"] == rows
+        assert type(rec["block_switch_h2d_bytes"]) is int
+        if stateful:
+            assert rows > 0 and comp is not None
+        else:
+            assert rows < 64 and comp is None
+    if kw.get("compress") == "q8":      # one PRNG key (2 x uint32) a client
+        assert {r["block_switch_h2d_bytes"] for r in firsts} == {8 * K}
+    if kw.get("error_feedback"):        # the residual is [K, N] float32
+        assert [r["block_switch_h2d_bytes"] >= 4 * K * r["N"]
+                for r in firsts] == [True] * BLOCKS
+
+
+@pytest.mark.parametrize("algo", sorted(ALGOS))
+def test_resume_inside_a_block_stamps_a_switch_too(data, tmp_path, algo):
     """A resumed segment's first round is a block visit's first round: it
-    carries the switch (the restore branch is its ``block_vars``)."""
+    carries the switch (the restore branch is its ``block_vars``), which
+    staged nothing itself, and goes on from the restored z / y / rho."""
     ck = str(tmp_path / "ck")
     seen = []
 
@@ -302,13 +349,16 @@ def test_resume_inside_a_block_stamps_a_switch_too(data, tmp_path):
         if len(seen) == 1:
             raise Stop
 
-    t = BlockwiseFederatedTrainer(TinyNet(), small_cfg(), data, FedAvg())
+    t = BlockwiseFederatedTrainer(TinyNet(), small_cfg(), data,
+                                  ALGOS[algo]())
     with pytest.raises(Stop):
         t.run(log=lambda m: None, on_round=on_round, checkpoint_path=ck)
-    t2 = BlockwiseFederatedTrainer(TinyNet(), small_cfg(), data, FedAvg())
+    t2 = BlockwiseFederatedTrainer(TinyNet(), small_cfg(), data,
+                                   ALGOS[algo]())
     _, hist = t2.run(log=lambda m: None, checkpoint_path=ck, resume=True)
     resumed = hist[1]
     assert resumed["nadmm"] == 1 and "block_switch_seconds" in resumed
+    assert resumed["block_switch_h2d_bytes"] == 0
     assert "gap_seconds" not in resumed          # the segment's first round
     mem = t2.obs_recorder.memory
     sw = spans_of(mem, "block_switch")[0]
@@ -317,6 +367,13 @@ def test_resume_inside_a_block_stamps_a_switch_too(data, tmp_path):
          and r.get("parent_span") == sw["span_id"]),
         key=lambda r: r["t_start"])]
     assert tuple(parts) == BLOCK_SWITCH_PARTS
+    # r_blockvars came back: the trajectory is the uninterrupted run's
+    _, whole = run(data, algo, False)
+    for key in ("loss", "rho", "dual_residual", "primal_residual"):
+        assert [r.get(key) for r in hist] == [r.get(key) for r in whole]
+    # only the fresh switches made block state: block 0's came from disk
+    made = [k for k in t2._fn_cache if k[0] == "fresh"]
+    assert len(made) == BLOCKS - 1
 
 
 def test_schema_v15_declares_the_fields():
@@ -336,3 +393,18 @@ def test_schema_v15_declares_the_fields():
                          "engine": "classifier", "round_index": 0,
                          "round_seconds": 0.5, "loss": 1.0,
                          "gap_seconds": "soon"})
+
+
+def test_schema_v16_declares_h2d_bytes():
+    assert SCHEMA_VERSION >= 16
+    rung = next(r for r in VERSION_LADDER if r["version"] == 16)
+    assert rung["added_fields"] == ("block_switch_h2d_bytes",)
+    assert rung["added_kinds"] == ()
+    assert "block_switch_h2d_bytes" in ADVISORY_FIELDS
+    base = {"event": "round", "schema": 16, "run_id": "r",
+            "engine": "classifier", "round_index": 0,
+            "round_seconds": 0.5, "loss": 1.0,
+            "block_switch_seconds": 0.03}
+    validate_record(dict(base, block_switch_h2d_bytes=0))
+    with pytest.raises(ValueError):
+        validate_record(dict(base, block_switch_h2d_bytes="none"))

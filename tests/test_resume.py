@@ -49,7 +49,10 @@ def strip(rec):
     # run compiled nothing in (obs/costs.py)
     return {k: v for k, v in rec.items()
             if isinstance(v, (int, float)) and not k.endswith("_seconds")
-            and k not in ("cache_hit", "peak_device_bytes")}
+            and k not in ("cache_hit", "peak_device_bytes",
+                          # a resumed segment's first round is a block
+                          # visit's first round: it stamps a switch
+                          "block_switch_h2d_bytes")}
 
 
 class TestMidrunResume:
