@@ -16,3 +16,4 @@ from federated_pytorch_test_tpu.data.lofar import (  # noqa: F401
     RoundPrefetcher,
     get_data_minibatch,
 )
+from federated_pytorch_test_tpu.data.tokens import FederatedTokens  # noqa: F401

@@ -14,6 +14,7 @@ from federated_pytorch_test_tpu.models.cpc import (  # noqa: F401
     EncoderCNN,
     PredictorCNN,
 )
+from federated_pytorch_test_tpu.models.qwen3_next import Qwen3Next  # noqa: F401
 
 MODEL_REGISTRY = {
     "net": Net,
@@ -26,6 +27,7 @@ MODEL_REGISTRY = {
     "cpc_encoder": EncoderCNN,
     "cpc_contextgen": ContextgenCNN,
     "cpc_predictor": PredictorCNN,
+    "qwen3_next": Qwen3Next,
 }
 
 

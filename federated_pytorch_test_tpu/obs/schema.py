@@ -223,8 +223,21 @@ from typing import Any, Dict
 # residual), and 0 on a resumed segment's first round (the restore
 # staged its arrays ahead of the switch).  Advisory: a resumed segment
 # stamps it on a round where the uninterrupted run has no switch.
-# v1..v15 records remain valid: validate_record accepts ver <= SCHEMA_VERSION.
-SCHEMA_VERSION = 16
+# v17 (additive): five round fields of the language-model trainer
+# (train/lm_engine.py: `LMTrainer.round_fields`), all pure functions of
+# (seed, config, round coordinates) and so core, not advisory.
+# `tokens`: tokens the round's local steps consumed, over the clients.
+# `block_kind`: what the active block is, `embed` / `gdn` / `attn` /
+# `moe` / `head` (models/qwen3_next.py: `block_kinds`).
+# `moe_pairs_local`: token-expert pairs of the round that hit an expert
+# this chip holds, summed over layers, steps and clients.
+# `moe_load_max_over_mean`: the most loaded held expert's pairs over the
+# held experts' mean, worst layer, averaged over the round's steps.
+# `moe_dropped`: pairs that found no row in the sorted pair buffer
+# (ops/moe.py); 0, or the benchmark's `correct` fails.  Streams of the
+# other engines carry none of them and stay byte-identical to v16.
+# v1..v16 records remain valid: validate_record accepts ver <= SCHEMA_VERSION.
+SCHEMA_VERSION = 17
 
 EVENTS = ("run_header", "round", "summary", "span", "alert", "compile",
           "control", "client", "campaign", "serve")
@@ -312,6 +325,12 @@ FIELDS: Dict[str, Any] = {
     "dispatch_seconds": (("round",), _NUM),
     # host bytes staged at a block switch (schema v16)
     "block_switch_h2d_bytes": (("round",), _NUM),
+    # the language-model trainer's round fields (schema v17)
+    "tokens":       (("round",), _INT),
+    "block_kind":   (("round",), _STR),
+    "moe_pairs_local": (("round",), _INT),
+    "moe_load_max_over_mean": (("round",), _NUM),
+    "moe_dropped":  (("round",), _INT),
     # fault / guard counters
     "guard_trips":  (("round",), _NUM),
     "guard_norm_mean": (("round",), _NUM),
@@ -615,6 +634,9 @@ VERSION_LADDER = (
                       "dispatch_seconds")},
     {"version": 16, "added_kinds": (),
      "added_fields": ("block_switch_h2d_bytes",)},
+    {"version": 17, "added_kinds": (),
+     "added_fields": ("tokens", "block_kind", "moe_pairs_local",
+                      "moe_load_max_over_mean", "moe_dropped")},
 )
 
 

@@ -1,0 +1,141 @@
+"""Expert layer of a chip that holds a share of the experts.
+
+The chip routes over ALL ``n_experts`` (the router keeps its published
+width), keeps each token's top-k weights as they are, and computes only
+the terms of the experts ``[first, first + held)`` it holds.  What the
+absent experts would add is left out; on one chip there is no exchange.
+
+:func:`route_local` sorts the token-expert pairs that hit a held expert
+by expert into a buffer of ``rows`` rows; :func:`grouped_matmul` is the
+matrix product of each expert's rows with that expert's weights
+(``jax.lax.ragged_dot``, which the TPU compiler lowers to a grouped
+Mosaic kernel whose work follows the group sizes).  A pair that finds no
+row is counted in ``dropped`` and never silently lost: the caller sizes
+``rows`` for its traffic and the benchmark's ``correct`` fails on a
+non-zero count.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.custom_batching import sequential_vmap
+
+
+class Routing(NamedTuple):
+    """``rows`` sorted pair slots of one token batch ``[T, H]``."""
+
+    token: jnp.ndarray        # [rows] int32: the pair's token
+    weight: jnp.ndarray       # [rows] f32: its routing weight (0: empty row)
+    group_sizes: jnp.ndarray  # [held] int32: rows of each held expert
+    pairs_local: jnp.ndarray  # () int32: pairs that hit a held expert
+    dropped: jnp.ndarray      # () int32: of those, pairs with no row
+    load_max_over_mean: jnp.ndarray   # () f32 over the held experts
+
+
+def router_weights(logits: jnp.ndarray, top_k: int, renormalise: bool):
+    """``(weights [T, k] f32, experts [T, k] int32)``: softmax over all
+    experts in float32, top-k, weights renormalised to sum 1."""
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    w, e = lax.top_k(probs, top_k)
+    if renormalise:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return w, e.astype(jnp.int32)
+
+
+def route_local(weights, experts, first: int, held: int, rows: int) -> Routing:
+    """Sort the pairs of ``experts [T, k]`` that hit ``[first, first +
+    held)`` by expert into ``rows`` slots (stable: by token within an
+    expert)."""
+    T, k = experts.shape
+    flat = experts.reshape(-1) - first
+    here = (flat >= 0) & (flat < held)
+    local = jnp.where(here, flat, held)                # held: "not here"
+    order = jnp.argsort(local, stable=True)[:rows]
+    counts = jnp.bincount(local, length=held + 1)[:held].astype(jnp.int32)
+    pairs = jnp.sum(counts)
+    # rows that exist: the first ``rows`` of the sorted local pairs
+    ends = jnp.minimum(jnp.cumsum(counts), order.shape[0])
+    group_sizes = jnp.diff(ends, prepend=0).astype(jnp.int32)
+    filled = jnp.arange(order.shape[0]) < pairs
+    w = jnp.where(filled, weights.reshape(-1)[order], 0.0)
+    mean = jnp.maximum(pairs.astype(jnp.float32) / held, 1e-9)
+    return Routing(
+        token=(order // k).astype(jnp.int32), weight=w,
+        group_sizes=group_sizes, pairs_local=pairs.astype(jnp.int32),
+        dropped=jnp.maximum(pairs - order.shape[0], 0).astype(jnp.int32),
+        load_max_over_mean=jnp.max(counts).astype(jnp.float32) / mean)
+
+
+def operand(x, dtype):
+    """``x`` as an operand of a product in ``dtype``.  A one-byte float
+    ``dtype`` (float8) rounds to it and multiplies in bfloat16, since
+    the v5e has no float8 unit: the benchmark's precision probe, which
+    has to come out as not correct (``benchmarks/engines/lm.py``)."""
+    if jnp.dtype(dtype).itemsize == 1:
+        return x.astype(dtype).astype(jnp.bfloat16)
+    return x.astype(dtype)
+
+
+def _grouped_rows(rows: int, group_sizes):
+    """``[rows, 1]`` mask of the rows that belong to a group."""
+    return (jnp.arange(rows) < jnp.sum(group_sizes))[:, None]
+
+
+def _ragged(x, w, group_sizes):
+    # the TPU's grouped kernel writes the rows of its groups and no
+    # other: rows past the last group hold whatever the buffer held
+    # (seen on the v5e: a gradient 1e9 times too large), so they are
+    # zeroed here, as the CPU's lowering leaves them
+    y = lax.ragged_dot(x, w, group_sizes, preferred_element_type=jnp.float32)
+    return jnp.where(_grouped_rows(x.shape[0], group_sizes), y, 0.0)
+
+
+def _ragged_vjp(x, w, group_sizes, dy):
+    """``(dx [rows, k], dw [groups, k, n])`` in float32: ``dy`` against
+    each row's expert, and each expert's rows against their ``dy``."""
+    dx = _ragged(dy, jnp.swapaxes(w, 1, 2), group_sizes)
+    dw = lax.ragged_dot_general(
+        x, dy, group_sizes,
+        lax.RaggedDotDimensionNumbers(
+            dot_dimension_numbers=(((0,), (0,)), ((), ())),
+            lhs_ragged_dimensions=[0], rhs_group_dimensions=[]),
+        preferred_element_type=jnp.float32)
+    return dx, dw
+
+
+# The TPU compiler takes a ragged product without batch dimensions only,
+# and the engine vmaps its clients: under vmap each product runs client
+# after client (a loop of K), which is what K clients' experts are.
+_ragged_seq = sequential_vmap(_ragged)
+_ragged_vjp_seq = sequential_vmap(_ragged_vjp)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def grouped_matmul(x, w, group_sizes, dtype=jnp.bfloat16):
+    """``y[r] = x[r] @ w[g(r)]`` for the rows ``r`` of group ``g`` (rows
+    ``[sum(group_sizes[:g]), sum(group_sizes[:g + 1]))``); rows past the
+    last group read 0.  ``x [rows, k]`` and ``w [groups, k, n]`` are
+    float32 and multiplied in ``dtype``; the result and both cotangents
+    are float32 (an expert's weight gradient is summed in float32)."""
+    return _ragged_seq(operand(x, dtype), operand(w, dtype), group_sizes)
+
+
+def _gm_fwd(x, w, group_sizes, dtype):
+    xc = operand(x, dtype)
+    return (_ragged_seq(xc, operand(w, dtype), group_sizes),
+            (xc, w, group_sizes))
+
+
+def _gm_bwd(dtype, res, dy):
+    xc, w, group_sizes = res
+    dx, dw = _ragged_vjp_seq(xc, operand(w, dtype), group_sizes,
+                             operand(dy, dtype))
+    return dx, dw, None
+
+
+grouped_matmul.defvjp(_gm_fwd, _gm_bwd)

@@ -19,3 +19,4 @@ from federated_pytorch_test_tpu.train.vae_engine import (  # noqa: F401
     VAECLTrainer,
     VAETrainer,
 )
+from federated_pytorch_test_tpu.train.lm_engine import LMTrainer  # noqa: F401

@@ -68,6 +68,7 @@ from federated_pytorch_test_tpu.utils import blocks as blocklib
 from federated_pytorch_test_tpu.utils import codec
 from federated_pytorch_test_tpu.utils.initializers import init_weights
 from federated_pytorch_test_tpu.utils.profiling import profile_ctx, round_trace
+from federated_pytorch_test_tpu.utils.tree import get_by_path, set_by_path
 
 
 class ClientState(NamedTuple):
@@ -111,8 +112,17 @@ class BlockwiseFederatedTrainer(RoundKernel):
     obs_engine: str = "classifier"
 
     def sample_init_args(self):
-        """Args after rng for ``model.init`` (overridden by rng-taking models)."""
-        return (jnp.zeros((1, 32, 32, 3), jnp.float32),)
+        """Args after rng for ``model.init``: one prepared sample of the
+        data's own shape (rng-taking models override and add their key;
+        a token trainer returns int32 ids)."""
+        return (jnp.zeros((1,) + self._sample_shape, jnp.float32),)
+
+    def prepare_batch(self, xb_raw, norm):
+        """A minibatch as staged (uint8 images) -> what ``model_loss``
+        is given; ``norm`` is the client's row of ``data.norm_stats``.
+        The image trainers normalise on the device; a token trainer
+        passes its int32 ids through."""
+        return _normalize_u8(xb_raw, norm)
 
     def __init__(
         self,
@@ -232,6 +242,10 @@ class BlockwiseFederatedTrainer(RoundKernel):
         # (reference seeds torch.manual_seed(0) before init of EVERY client,
         # federated_multi.py:124-128)
         rng = jax.random.PRNGKey(cfg.init_seed)
+        # one sample's shape, from the pipeline's own test batches
+        # ([tsteps, B, ...]): nothing here assumes a 32x32x3 image
+        test_raw = data.test_batches_raw()
+        self._sample_shape = tuple(test_raw[0].shape[2:])
         params, batch_stats = model.init_variables(rng, *self.sample_init_args())
         if cfg.init_model:
             # SEED COMPAT (graftcheck JG103): init_weights used to rebuild
@@ -305,7 +319,7 @@ class BlockwiseFederatedTrainer(RoundKernel):
         # (stage_global = device_put single-process; local-shards-only on
         # multi-host, parallel/mesh.py)
         rsh = replicated_sharding(mesh)
-        xt_u8, yt, wt = data.test_batches_raw()
+        xt_u8, yt, wt = test_raw
         self.test_x = stage_global(xt_u8, rsh)       # [tsteps, B, 32,32,3] u8
         self.test_y = stage_global(yt, rsh)          # [tsteps, B] i32
         self.test_w = stage_global(wt, rsh)          # [tsteps, B] f32
@@ -431,6 +445,20 @@ class BlockwiseFederatedTrainer(RoundKernel):
             return (self.cfg.lambda1, self.cfg.lambda2)
         return (0.0, 0.0)
 
+    def wrap_client_grad(self, grad_fn):
+        """``grad_fn`` is one client's loss and active-block gradient for
+        one minibatch; the epoch program vmaps it over the device's
+        clients.  A trainer whose clients' activations do not fit side
+        by side returns it wrapped so that it runs client after client
+        (``jax.custom_batching.sequential_vmap``).  Here: as it is."""
+        return grad_fn
+
+    def round_fields(self, state: ClientState, ci: int) -> Dict[str, Any]:
+        """Further fields of this round's record, read after the round's
+        one host sync (a token trainer: tokens, block kind, routing
+        counts).  None here."""
+        return {}
+
     def model_loss(self, p, bs, xb, yb, wb, rng):
         """Per-batch core loss -> (scalar, new_batch_stats).
 
@@ -492,7 +520,21 @@ class BlockwiseFederatedTrainer(RoundKernel):
         cfg, algo = self.cfg, self.algo
         order = self.order
         mask = self.mask_for_block(ci)
-        mask_grads = functools.partial(blocklib.mask_tree, mask=mask)
+        # the active leaves, by path: gradients and Adam's moments exist
+        # for these alone.  A frozen leaf's moments were identically zero
+        # and its update 0, so every active leaf's trajectory is what it
+        # was when the optimizer spanned the model; what is gone is a
+        # gradient, two moments and the backward residuals per frozen leaf
+        active_paths = (tuple(order) if ci is None
+                        else tuple(self.sweep_paths(ci)))
+        take_active = lambda p: {path: get_by_path(p, path)
+                                 for path in active_paths}
+
+        def put_active(p, act):
+            for path in active_paths:
+                p = set_by_path(p, path, act[path])
+            return p
+
         lam1, lam2 = self.reg_for_block(ci)
         reg_on = lam1 != 0.0 or lam2 != 0.0
         opt_name = self.optimizer_for_block(ci)
@@ -513,7 +555,12 @@ class BlockwiseFederatedTrainer(RoundKernel):
                 loss = loss + l1_l2(xflat, lam1, lam2)
             return loss, new_bs
 
-        grad_fn = jax.value_and_grad(batch_loss, has_aux=True)
+        def active_loss(act, p, *rest):
+            return batch_loss(put_active(p, act), *rest)
+
+        grad_fn = self.wrap_client_grad(
+            jax.value_and_grad(active_loss, has_aux=True))
+        prepare_batch = self.prepare_batch
         if use_lbfgs and has_bn:
             raise ValueError(
                 "lbfgs local optimizer requires a BatchNorm-free model "
@@ -525,12 +572,13 @@ class BlockwiseFederatedTrainer(RoundKernel):
 
         def adam_step(carry, batch):
             p, bs, os = carry
-            xb_u8, yb, wb, rng, z, y, rho, norm = batch
-            xb = _normalize_u8(xb_u8, norm)
-            (loss, new_bs), g = grad_fn(p, bs, xb, yb, wb, rng, z, y, rho)
-            g = mask_grads(g)
-            updates, os = tx.update(g, os, p)
-            p = optax.apply_updates(p, updates)
+            xb_raw, yb, wb, rng, z, y, rho, norm = batch
+            xb = prepare_batch(xb_raw, norm)
+            act = take_active(p)
+            (loss, new_bs), g = grad_fn(act, p, bs, xb, yb, wb, rng, z, y,
+                                        rho)
+            updates, os = tx.update(g, os, act)
+            p = put_active(p, optax.apply_updates(act, updates))
             return (p, new_bs, os), loss
 
         def lbfgs_step(carry, batch):
@@ -539,8 +587,8 @@ class BlockwiseFederatedTrainer(RoundKernel):
             # here the closure is a pure flat-vector objective on the active
             # block and step() runs bounded line searches inside jit
             p, bs, os = carry
-            xb_u8, yb, wb, rng, z, y, rho, norm = batch
-            xb = _normalize_u8(xb_u8, norm)
+            xb_raw, yb, wb, rng, z, y, rho, norm = batch
+            xb = prepare_batch(xb_raw, norm)
 
             def flat_loss(v):
                 pv = codec.put_trainable_values(p, order, mask, v)
@@ -867,7 +915,7 @@ class BlockwiseFederatedTrainer(RoundKernel):
                     lambda p: lbfgs.init(
                         codec.get_trainable_values(p, order, mask))
                 )(params)
-            return jax.vmap(tx.init)(params)
+            return jax.vmap(lambda p: tx.init(take_active(p)))(params)
         # no donation: callers keep ``params`` (the state that carries it
         # is re-assembled around the fresh opt state) — see JG106 note
         init_opt = jax.jit(  # graftlint: disable=JG106
@@ -939,7 +987,7 @@ class BlockwiseFederatedTrainer(RoundKernel):
                 perm = jnp.concatenate([perm, perm[: nB - n]])
             idx = perm[:nB]
             return (x[idx].reshape(steps, B, *x.shape[1:]),
-                    y[idx].reshape(steps, B))
+                    y[idx].reshape(steps, B, *y.shape[1:]))
 
         def fused_shard(state: ClientState, z, y, rho, x0, yhat0, active,
                         comm_active, corrupt, gbound, seeds, norm, xs, ys,
@@ -1037,12 +1085,12 @@ class BlockwiseFederatedTrainer(RoundKernel):
         key = ("eval",)
         if key in self._fn_cache:
             return self._fn_cache[key]
-        metric = self.eval_batch_metric
+        metric, prepare_batch = self.eval_batch_metric, self.prepare_batch
 
         def per_client(p, bs, norm, xt_u8, yt, wt):
             def step(acc, batch):
                 xb_u8, yb, wb = batch
-                return acc + metric(p, bs, _normalize_u8(xb_u8, norm), yb,
+                return acc + metric(p, bs, prepare_batch(xb_u8, norm), yb,
                                     wb), None
             acc, _ = lax.scan(step, jnp.float32(0), (xt_u8, yt, wt))
             return acc
@@ -1112,11 +1160,7 @@ class BlockwiseFederatedTrainer(RoundKernel):
 
         def forward(weights, xb_u8):
             p, bs = weights
-            xb = _normalize_u8(xb_u8, norm)
-            if self.has_bn:
-                return self.model.apply(
-                    {"params": p, "batch_stats": bs}, xb, train=False)
-            return self.model.apply({"params": p}, xb, train=False)
+            return self._apply_eval(p, bs, self.prepare_batch(xb_u8, norm))
 
         head_key = ("vae" if self.obs_engine.startswith("vae")
                     else "cpc" if self.obs_engine == "cpc"
@@ -1209,7 +1253,7 @@ class BlockwiseFederatedTrainer(RoundKernel):
                     perm = jnp.concatenate([perm, perm[: nB - n]])
                 idx = perm[:nB]
                 return (x[idx].reshape(steps, B, *x.shape[1:]),
-                        y[idx].reshape(steps, B))
+                        y[idx].reshape(steps, B, *y.shape[1:]))
             return jax.vmap(one)(keys, xs, ys)
 
         self._dev_gather = jax.jit(gather, out_shardings=(csh, csh))
@@ -2244,6 +2288,9 @@ class BlockwiseFederatedTrainer(RoundKernel):
                             # the probes ride the same single round sync
                             cl_nrm = np.asarray(fetch(cl_nrm))
                             cl_dist = np.asarray(fetch(cl_dist))
+                        # the workload's own round fields (a fetch of
+                        # a few scalars for a trainer that has any)
+                        workload_fields = self.round_fields(state, ci)
                         sync_s = time.perf_counter() - t_sync
                         if obs.enabled:
                             phase_marks.append(
@@ -2257,7 +2304,7 @@ class BlockwiseFederatedTrainer(RoundKernel):
                                    train_seconds=train_s,
                                    comm_seconds=comm_s,
                                    sync_seconds=sync_s,
-                                   **fcounts, **diag)
+                                   **fcounts, **diag, **workload_fields)
                         # the host seconds no segment above counts (schema
                         # v15): everything from here to on_round's return
                         # is this round's tail and lands in the NEXT

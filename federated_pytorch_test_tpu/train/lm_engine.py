@@ -1,0 +1,132 @@
+"""Language-model trainer: a small subclass of the blockwise engine.
+
+``LMTrainer`` overrides the workload hooks only (as ``VAETrainer``
+does), so staging, the epoch scan, the vmap over clients, the exchange,
+the block switch and the recorder are the engine's own.  A batch is
+``[B, T]`` int32 ids with their next ids as labels; a sample is one
+sequence.  The model's routing counts ride in the engine's per-client
+non-parameter state (``ClientState.batch_stats``), summed over the steps,
+and ``round_fields`` turns them into the round record's ``tokens``,
+``block_kind``, ``moe_pairs_local``, ``moe_load_max_over_mean`` and
+``moe_dropped``.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Dict
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.custom_batching import sequential_vmap
+
+from federated_pytorch_test_tpu.models.qwen3_next import weighted_mean
+from federated_pytorch_test_tpu.parallel.mesh import (
+    client_sharding,
+    fetch,
+    stage_tree_global,
+)
+from federated_pytorch_test_tpu.train.engine import (
+    BlockwiseFederatedTrainer,
+    ClientState,
+)
+
+#: the counters kept per client, all sums over local steps
+_COUNTERS = ("steps", "moe_pairs_local", "moe_dropped", "moe_load_sum")
+
+
+class LMTrainer(BlockwiseFederatedTrainer):
+    """Federated next-token training of a ``BlockModule`` whose
+    ``__call__(ids)`` returns ``(logits, aux)`` (``models/qwen3_next.py``).
+    No L1/L2 term on any block; evaluation is the mean test loss."""
+
+    obs_engine = "lm"
+
+    def __init__(self, model, cfg, data, algorithm, **kw):
+        self._counters_seen: Dict[str, np.ndarray] = {}
+        super().__init__(model, cfg, data, algorithm, **kw)
+        # the per-client counters ARE the engine's batch_stats here (the
+        # model has none of its own); has_bn stays False so nothing takes
+        # them for batch-norm statistics
+        zeros = {k: np.zeros((cfg.K,), np.float32 if k == "moe_load_sum"
+                             else np.int32) for k in _COUNTERS}
+        self.batch_stats0 = stage_tree_global(zeros,
+                                              client_sharding(self.mesh))
+
+    def wrap_client_grad(self, grad_fn):
+        # a step of one client is 8,192 tokens through matrices thousands
+        # wide: nothing is gained by batching K of them, and K clients'
+        # activations side by side do not fit the chip
+        return sequential_vmap(grad_fn)
+
+    def init_state(self) -> ClientState:
+        """The staged init itself, handed over: the weights are held K
+        times already, and a second staged copy of them (the base class
+        keeps ``params0`` and copies it) does not fit.  ``params0`` keeps
+        the shapes, which is all the masks and ``block_size`` read."""
+        if any(isinstance(a, jax.ShapeDtypeStruct)
+               for a in jax.tree.leaves(self.params0)):
+            raise RuntimeError(
+                "LMTrainer.init_state() hands its staged weights over and "
+                "can be called once; build a new trainer for a new run")
+        self._counters_seen = {}
+        state = ClientState(self.params0,
+                            jax.tree.map(jnp.copy, self.batch_stats0), None)
+        self.params0 = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), self.params0)
+        return state
+
+    def sample_init_args(self):
+        # no parameter's shape depends on the sequence length, and flax
+        # runs the init forward op by op: eight ids, not a whole sequence
+        return (jnp.zeros((1, 8), jnp.int32),)
+
+    def prepare_batch(self, xb_raw, norm):
+        return xb_raw
+
+    def reg_for_block(self, ci):
+        return (0.0, 0.0)
+
+    def model_loss(self, p, bs, xb, yb, wb, rng):
+        per_seq, aux = self.model.apply({"params": p}, xb, yb)
+        new = {"steps": bs["steps"] + 1,
+               "moe_pairs_local": bs["moe_pairs_local"]
+               + aux["moe_pairs_local"],
+               "moe_dropped": bs["moe_dropped"] + aux["moe_dropped"],
+               "moe_load_sum": bs["moe_load_sum"]
+               + aux["moe_load_max_over_mean"]}
+        return weighted_mean(per_seq, wb), new
+
+    def eval_batch_metric(self, p, bs, xb, yb, wb):
+        per_seq, _ = self.model.apply({"params": p}, xb, yb)
+        return weighted_mean(per_seq, wb) * jnp.sum(wb)
+
+    def eval_finalize(self, totals: np.ndarray, n_samples: int) -> np.ndarray:
+        return totals / n_samples          # mean test loss per sequence
+
+    def _restore_midrun(self, path):
+        out = super()._restore_midrun(path)
+        self._counters_seen = {
+            k: np.asarray(fetch(out[0].batch_stats[k])) for k in _COUNTERS}
+        return out
+
+    def round_fields(self, state: ClientState, ci: int) -> Dict[str, Any]:
+        """This round's share of the cumulative counters (clients
+        summed; the load ratio averaged over the round's steps)."""
+        now = {k: np.asarray(fetch(state.batch_stats[k])) for k in _COUNTERS}
+        d = {k: float(np.sum(now[k] - self._counters_seen.get(k, 0)))
+             for k in _COUNTERS}
+        self._counters_seen = now
+        steps = max(d["steps"], 1.0)
+        return {"tokens": int(d["steps"]) * self.data.batch
+                * self.data.tokens_per_sample,
+                "block_kind": self.model.block_kinds()[self._block_index(ci)],
+                "moe_pairs_local": int(d["moe_pairs_local"]),
+                "moe_dropped": int(d["moe_dropped"]),
+                "moe_load_max_over_mean": d["moe_load_sum"] / steps}
+
+    def _block_index(self, ci: int) -> int:
+        """Index into the model's own block list of sweep unit ``ci``
+        (callers may re-point ``block_ids`` at a subset)."""
+        return self.model.train_order_block_ids().index(
+            list(self.block_ids[ci]))

@@ -45,7 +45,7 @@ class VAETrainer(BlockwiseFederatedTrainer):
     obs_engine = "vae"
 
     def sample_init_args(self):
-        return (jnp.zeros((1, 32, 32, 3), jnp.float32), jax.random.PRNGKey(0))
+        return super().sample_init_args() + (jax.random.PRNGKey(0),)
 
     def reg_for_block(self, ci):
         return (0.0, 0.0)
@@ -84,7 +84,7 @@ class VAECLTrainer(BlockwiseFederatedTrainer):
     obs_engine = "vae_cl"
 
     def sample_init_args(self):
-        return (jnp.zeros((1, 32, 32, 3), jnp.float32), jax.random.PRNGKey(0))
+        return super().sample_init_args() + (jax.random.PRNGKey(0),)
 
     def optimizer_for_block(self, ci):
         if ci == 2:                      # latent space block

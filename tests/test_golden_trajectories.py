@@ -64,7 +64,15 @@ def _digest(history, state):
     for leaf in jax.tree_util.tree_leaves(
             state._asdict() if hasattr(state, "_asdict") else state):
         h.update(np.ascontiguousarray(jax.device_get(leaf)).tobytes())
-    return {"history": hist, "params_sha256": h.hexdigest()}
+    # the weights alone (PR 27: the optimizer tree shrank to the active
+    # leaves, which changes the hash above and must not change this one)
+    w = hashlib.sha256()
+    if hasattr(state, "params"):
+        for leaf in jax.tree_util.tree_leaves((state.params,
+                                               state.batch_stats)):
+            w.update(np.ascontiguousarray(jax.device_get(leaf)).tobytes())
+    return {"history": hist, "params_sha256": h.hexdigest(),
+            "weights_sha256": w.hexdigest()}
 
 
 def _check(name, digest):
@@ -79,6 +87,9 @@ def _check(name, digest):
     want = json.loads(path.read_text())
     assert digest["params_sha256"] == want["params_sha256"], \
         f"{name}: final parameter bytes diverged from the golden"
+    if "weights_sha256" in want:
+        assert digest["weights_sha256"] == want["weights_sha256"], \
+            f"{name}: final weights diverged from the golden"
     assert len(digest["history"]) == len(want["history"]), \
         (name, len(digest["history"]), len(want["history"]))
     for i, (got, exp) in enumerate(zip(digest["history"],
