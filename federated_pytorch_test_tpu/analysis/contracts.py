@@ -59,7 +59,7 @@ DEFAULT_TABLES: Dict[str, object] = {
         "epoch_seconds", "ckpt_write_seconds", "overlap_seconds",
         "overlap_dispatch_seconds", "compile_seconds", "t_start", "t_end",
         "block_switch_seconds", "gap_seconds", "dispatch_seconds",
-        "block_switch_h2d_bytes",
+        "block_switch_h2d_bytes", "gdn_scan_impl",
         "serve_p50_ms", "serve_p99_ms", "serve_qps", "swap_gap_seconds",
         "serve_accuracy", "drift_score", "forced_refresh",
         "total_seconds", "round_seconds_total", "stage_seconds_total",

@@ -47,7 +47,10 @@ import jax.numpy as jnp
 
 from federated_pytorch_test_tpu.models.base import BlockModule
 from federated_pytorch_test_tpu.ops import moe as moelib
-from federated_pytorch_test_tpu.ops.gated_delta import gated_delta_chunked
+from federated_pytorch_test_tpu.ops.gated_delta import (
+    gated_delta_chunked,
+    plan as gdn_scan_plan,
+)
 
 _F32 = jnp.float32
 _op = moelib.operand
@@ -165,6 +168,15 @@ class Qwen3Next(BlockModule):
         for k in self.layer_kinds():
             kinds += [k, "moe"]
         return kinds + ["head"]
+
+    def gdn_scan_impl(self, tokens: int) -> str:
+        """What runs the delta rule's chunk recurrence for a sequence of
+        ``tokens`` in a Gated DeltaNet layer here ("pallas" |
+        "pallas_interpret" | "xla": ``ops/gated_delta.py:plan``)."""
+        return gdn_scan_plan(
+            self.linear_num_value_heads, -(-tokens // self.chunk),
+            self.chunk, self.linear_key_head_dim,
+            self.linear_value_head_dim, self.dtype)["impl"]
 
     def _spec(self, name: str):
         H, s = self.hidden_size, _normal(self.init_scale)

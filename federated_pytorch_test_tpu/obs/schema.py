@@ -236,8 +236,17 @@ from typing import Any, Dict
 # `moe_dropped`: pairs that found no row in the sorted pair buffer
 # (ops/moe.py); 0, or the benchmark's `correct` fails.  Streams of the
 # other engines carry none of them and stay byte-identical to v16.
-# v1..v16 records remain valid: validate_record accepts ver <= SCHEMA_VERSION.
-SCHEMA_VERSION = 17
+# v18 (additive): one more round field of the language-model trainer.
+# `gdn_scan_impl`: what ran the delta rule's chunk recurrence in the
+# round's Gated DeltaNet layers, `pallas` (the kernel pair of
+# ops/gated_delta.py, S in VMEM), `pallas_interpret` (the same in
+# interpret mode: tests) or `xla` (`lax.scan`).  Decided per call from
+# backend, dtype and shapes
+# (ops/gated_delta.py: `plan`), so it says in every round whether the
+# kernels engaged.  Advisory: it names the machine's path, not the
+# trajectory (the same config reads `xla` on a CPU).
+# v1..v17 records remain valid: validate_record accepts ver <= SCHEMA_VERSION.
+SCHEMA_VERSION = 18
 
 EVENTS = ("run_header", "round", "summary", "span", "alert", "compile",
           "control", "client", "campaign", "serve")
@@ -331,6 +340,8 @@ FIELDS: Dict[str, Any] = {
     "moe_pairs_local": (("round",), _INT),
     "moe_load_max_over_mean": (("round",), _NUM),
     "moe_dropped":  (("round",), _INT),
+    # which implementation ran the delta rule's recurrence (schema v18)
+    "gdn_scan_impl": (("round",), _STR),
     # fault / guard counters
     "guard_trips":  (("round",), _NUM),
     "guard_norm_mean": (("round",), _NUM),
@@ -537,6 +548,8 @@ ADVISORY_FIELDS = (
     "block_switch_seconds", "gap_seconds", "dispatch_seconds",
     # host bytes staged at a block switch (v16)
     "block_switch_h2d_bytes",
+    # which implementation this backend took for the recurrence (v18)
+    "gdn_scan_impl",
     # serving-plane latency/throughput telemetry (v13)
     "serve_p50_ms", "serve_p99_ms", "serve_qps", "swap_gap_seconds",
     "serve_accuracy", "drift_score", "forced_refresh",
@@ -637,6 +650,7 @@ VERSION_LADDER = (
     {"version": 17, "added_kinds": (),
      "added_fields": ("tokens", "block_kind", "moe_pairs_local",
                       "moe_load_max_over_mean", "moe_dropped")},
+    {"version": 18, "added_kinds": (), "added_fields": ("gdn_scan_impl",)},
 )
 
 

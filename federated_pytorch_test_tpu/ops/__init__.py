@@ -6,6 +6,12 @@ so every op runs identically on CPU/interpret mode.  Currently:
   * :func:`info_nce_fused` — fused InfoNCE (CPC contrastive loss): Gram
     matmul + normalisation + online log-softmax + diagonal gather in one
     VMEM-resident kernel.
+  * ``comm_kernels`` — the packed collective's ``quantize_chunks``,
+    ``dequant_add`` and ``gram_matrix``; ``topk_select`` — two-stage top-k.
+  * ``gated_delta.gated_delta_chunked`` — the chunked gated delta rule;
+    its recurrence over the chunks is a forward and a backward kernel
+    under one ``custom_vjp`` that keep the state ``S`` in VMEM
+    (``gated_delta.force_gdn_scan_impl`` for tests).
 """
 
 from federated_pytorch_test_tpu.ops.infonce import (  # noqa: F401

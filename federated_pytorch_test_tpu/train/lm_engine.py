@@ -8,7 +8,8 @@ sequence.  The model's routing counts ride in the engine's per-client
 non-parameter state (``ClientState.batch_stats``), summed over the steps,
 and ``round_fields`` turns them into the round record's ``tokens``,
 ``block_kind``, ``moe_pairs_local``, ``moe_load_max_over_mean`` and
-``moe_dropped``.
+``moe_dropped``, and adds ``gdn_scan_impl``: which implementation of the
+delta rule's recurrence the model's shapes take on this backend.
 """
 
 from __future__ import annotations
@@ -123,7 +124,9 @@ class LMTrainer(BlockwiseFederatedTrainer):
                 "block_kind": self.model.block_kinds()[self._block_index(ci)],
                 "moe_pairs_local": int(d["moe_pairs_local"]),
                 "moe_dropped": int(d["moe_dropped"]),
-                "moe_load_max_over_mean": d["moe_load_sum"] / steps}
+                "moe_load_max_over_mean": d["moe_load_sum"] / steps,
+                "gdn_scan_impl": self.model.gdn_scan_impl(
+                    self.data.tokens_per_sample)}
 
     def _block_index(self, ci: int) -> int:
         """Index into the model's own block list of sweep unit ``ci``

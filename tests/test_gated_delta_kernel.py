@@ -1,0 +1,336 @@
+"""The delta rule's chunk recurrence as a Pallas kernel pair
+(``ops/gated_delta.py``), on the CPU in interpret mode: against the
+token-by-token recurrence and against the ``lax.scan`` path, the backward
+kernel line by line against ``jax.vjp`` of the XLA step, the rule that
+picks the path, the round field that reports it, and a compile of both
+kernels at the published widths for a described v5e.
+"""
+
+import functools
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
+
+from federated_pytorch_test_tpu.data.tokens import FederatedTokens  # noqa: E402
+from federated_pytorch_test_tpu.models import get_model  # noqa: E402
+from federated_pytorch_test_tpu.obs.schema import (  # noqa: E402
+    ADVISORY_FIELDS,
+    SCHEMA_VERSION,
+    VERSION_LADDER,
+    validate_record,
+)
+from federated_pytorch_test_tpu.ops import gated_delta as gd  # noqa: E402
+from federated_pytorch_test_tpu.train import (  # noqa: E402
+    FedAvg,
+    FederatedConfig,
+)
+from federated_pytorch_test_tpu.train.lm_engine import LMTrainer  # noqa: E402
+
+F32, BF16 = jnp.float32, jnp.bfloat16
+CHUNK, D = 64, 128          # the published chunk and head width
+INPUTS = ("q", "k", "v", "g", "beta")
+#: agreement with the float32 recurrence that each operand dtype allows
+TOL = {F32: 1e-5, BF16: 2e-2}
+GRAD_TOL = {F32: 1e-4, BF16: 3e-2}
+
+
+def rel(a, b):
+    a, b = jnp.asarray(a, F32), jnp.asarray(b, F32)
+    return float(jnp.max(jnp.abs(a - b)) / (jnp.max(jnp.abs(b)) + 1e-30))
+
+
+def delta_inputs(length, H=2, dk=D, dv=D, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)
+    q = unit(jax.random.normal(ks[0], (H, length, dk))) / np.sqrt(dk)
+    k = unit(jax.random.normal(ks[1], (H, length, dk)))
+    v = jax.random.normal(ks[2], (H, length, dv))
+    g = -3.0 * jax.random.uniform(ks[3], (H, length))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (H, length)))
+    return q, k, v, g, beta
+
+
+def chunked(dtype, *args, chunk=CHUNK):
+    return gd.gated_delta_chunked(*args, chunk=chunk, dtype=dtype)
+
+
+def kernels(dtype, *args, **kw):
+    with gd.force_gdn_scan_impl("pallas_interpret"):
+        return chunked(dtype, *args, **kw)
+
+
+def calls_a_kernel(f, *args):
+    return "pallas_call" in str(jax.make_jaxpr(f)(*args))
+
+
+# ----------------------------------------------------------------------
+# forward and gradient, whole function
+# ----------------------------------------------------------------------
+@pytest.mark.parametrize("dtype", [F32, BF16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("length", [64, 128, 100, 7])
+def test_kernel_path_matches_the_recurrence_and_the_scan(length, dtype):
+    args = delta_inputs(length)
+    assert calls_a_kernel(functools.partial(kernels, dtype), *args)
+    assert not calls_a_kernel(functools.partial(chunked, dtype), *args)
+    got = kernels(dtype, *args)
+    want = jax.vmap(gd.gated_delta_stepwise)(*args)
+    assert got.shape == want.shape and got.dtype == F32
+    assert rel(got, want) < TOL[dtype]
+    # the same arithmetic as the scan: what differs is the order of sums
+    assert rel(got, chunked(dtype, *args)) < (1e-6 if dtype == F32 else 1e-2)
+
+
+@pytest.fixture(scope="module")
+def gradients():
+    """Gradients of all five inputs at a length that needs padding:
+    the recurrence's, and per dtype the kernel path's and the scan's."""
+    args = delta_inputs(100)
+    loss = lambda f: lambda *a: jnp.sum(f(*a) ** 2)
+    grad = lambda f: jax.grad(loss(f), argnums=(0, 1, 2, 3, 4))(*args)
+    out = {"want": grad(jax.vmap(gd.gated_delta_stepwise))}
+    for dtype in (F32, BF16):
+        out[dtype] = (grad(functools.partial(kernels, dtype)),
+                      grad(functools.partial(chunked, dtype)))
+    return out
+
+
+@pytest.mark.parametrize("dtype", [F32, BF16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("i", range(5), ids=INPUTS)
+def test_kernel_path_gradient_matches_the_recurrence(gradients, i, dtype):
+    got, scan = gradients[dtype]
+    want = gradients["want"][i]
+    assert got[i].shape == want.shape
+    assert rel(got[i], want) < GRAD_TOL[dtype]
+    # and no further from it than the scan's own gradient, rounding aside
+    assert rel(got[i], want) < 2.0 * rel(scan[i], want) + 1e-5
+
+
+def test_forward_without_a_gradient_writes_no_states():
+    """Where no gradient is asked the primal kernel runs: one output."""
+    args = delta_inputs(128)
+    fwd = str(jax.make_jaxpr(functools.partial(kernels, BF16))(*args))
+    both = str(jax.make_jaxpr(jax.grad(
+        lambda *a: jnp.sum(kernels(BF16, *a))))(*args))
+    assert fwd.count("pallas_call") == 1 and both.count("pallas_call") == 2
+    states = "f32[2,2,128,128]"       # S at each chunk's start
+    assert states not in fwd and states in both
+
+
+# ----------------------------------------------------------------------
+# the backward kernel, line by line
+# ----------------------------------------------------------------------
+STEP_GRADS = ("du", "dw", "dqk", "dq_in", "dk_out", "dg_last")
+
+
+@pytest.fixture(scope="module")
+def step_vjp():
+    """Two chunks, so that the second's ``dS`` reaches the first and the
+    first's ``S`` is not zero at the second: the kernel pair's cotangents
+    and ``jax.vjp`` of two applications of the XLA step, per dtype."""
+    H, N, C = 2, 2, 16
+    ks = jax.random.split(jax.random.PRNGKey(7), 7)
+    n = lambda key, *s: jax.random.normal(key, s)
+    ops = (n(ks[0], H, N, C, D), 0.3 * n(ks[1], H, N, C, D),
+           jnp.tril(0.3 * n(ks[2], H, N, C, C)), 0.3 * n(ks[3], H, N, C, D),
+           0.3 * n(ks[4], H, N, C, D),
+           jnp.exp(-jax.random.uniform(ks[5], (H, N))))
+    do = n(ks[6], H, N, C, D)
+    out = {}
+    for dtype in (F32, BF16):
+        def xla(*ops):
+            S, outs = jnp.zeros((H, D, D), F32), []
+            for c in range(N):
+                S, o = gd._step(dtype, S, tuple(a[:, c] for a in ops))
+                outs.append(o)
+            return jnp.stack(outs, 1)
+
+        def pallas(u, w, qk, q_in, k_out, gl):
+            c = lambda a: a.astype(dtype)
+            return gd._recurrence(H, True, u, c(w), c(qk), c(q_in), c(k_out),
+                                  jnp.broadcast_to(gl[..., None, None],
+                                                   (H, N, 1, D)))
+
+        o_x, vjp_x = jax.vjp(xla, *ops)
+        o_p, vjp_p = jax.vjp(pallas, *ops)
+        out[dtype] = (o_p, o_x, vjp_p(do), vjp_x(do))
+    return out
+
+
+@pytest.mark.parametrize("dtype", [F32, BF16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("i", range(6), ids=STEP_GRADS)
+def test_backward_kernel_matches_the_vjp_of_the_xla_step(step_vjp, i, dtype):
+    o_p, o_x, got, want = step_vjp[dtype]
+    assert rel(o_p, o_x) < (1e-6 if dtype == F32 else 1e-2)
+    assert got[i].shape == want[i].shape and got[i].dtype == want[i].dtype
+    assert rel(got[i], want[i]) < (2e-6 if dtype == F32 else 2e-2)
+
+
+# ----------------------------------------------------------------------
+# which path runs
+# ----------------------------------------------------------------------
+@pytest.mark.parametrize("case,dk,dv,chunk,dtype,why", [
+    ("one_byte_dtype", D, D, CHUNK, jnp.float8_e4m3fn, "float8_e4m3fn"),
+    ("key_width_64", 64, D, CHUNK, BF16, "multiples of 128"),
+    ("value_width_64", D, 64, CHUNK, BF16, "multiples of 128"),
+    ("chunk_12", D, D, 12, BF16, "multiple of 8"),
+    ("over_the_budget", 1024, 1024, 512, F32, "exceed"),
+])
+def test_what_the_kernels_do_not_take_falls_back_to_the_scan(
+        case, dk, dv, chunk, dtype, why):
+    with gd.force_gdn_scan_impl("pallas_interpret"):
+        p = gd.plan(2, 2, chunk, dk, dv, dtype)
+    assert p["impl"] == "xla" and p["heads"] == 0 and why in p["why"]
+    if dk * dv <= D * D:
+        args = delta_inputs(2 * chunk, dk=dk, dv=dv)
+        f = functools.partial(kernels, dtype, chunk=chunk)
+        assert not calls_a_kernel(f, *args)
+        # the one-byte probe is still a different result, not an error
+        want = jax.vmap(gd.gated_delta_stepwise)(*args)
+        err = rel(f(*args), want)
+        assert err > 0.02 if case == "one_byte_dtype" else err < 2e-2
+
+
+def test_without_a_tpu_the_scan_runs():
+    assert jax.default_backend() == "cpu"
+    p = gd.plan(32, 64, CHUNK, D, D, BF16)
+    assert p["impl"] == "xla" and p["why"] == "no TPU"
+    assert not calls_a_kernel(functools.partial(chunked, BF16),
+                              *delta_inputs(CHUNK))
+
+
+@pytest.mark.parametrize("H,heads", [(32, 8), (16, 8), (12, 6), (6, 6),
+                                     (7, 7), (11, 1)])
+def test_plan_takes_the_most_heads_that_divide_and_fit(H, heads):
+    with gd.force_gdn_scan_impl("pallas"):
+        p = gd.plan(H, 64, CHUNK, D, D, BF16)
+    assert p["impl"] == "pallas" and p["heads"] == heads
+    assert 0 < p["vmem_bytes"] <= p["vmem_budget"] <= 16 * 2**20
+    # the estimate grows with what a step holds
+    assert p["vmem_bytes"] >= gd._grad_vmem_bytes(1, CHUNK, D, D, 2)
+    with gd.force_gdn_scan_impl("pallas"):
+        wide = gd.plan(H, 64, CHUNK, D, D, F32)
+    assert wide["vmem_bytes"] > p["vmem_bytes"] or wide["heads"] < heads
+
+
+# ----------------------------------------------------------------------
+# the round field
+# ----------------------------------------------------------------------
+def lm_trainer():
+    """Two layers (GDN, attention) at tiny widths but for the GDN heads,
+    which are as wide as the kernels ask; the GDN block is active."""
+    model = get_model(
+        "qwen3_next", hidden_size=32, num_attention_heads=2,
+        num_key_value_heads=1, head_dim=16, linear_num_key_heads=1,
+        linear_num_value_heads=2, linear_key_head_dim=D,
+        linear_value_head_dim=D, num_experts=8, num_experts_per_tok=2,
+        moe_intermediate_size=16, shared_expert_intermediate_size=16,
+        layers=2, full_attention_interval=2, experts_held=4, vocab_rows=64,
+        chunk=16, attn_block=16, pair_rows_factor=8.0, dtype=F32)
+    data = FederatedTokens(K=2, batch=2, samples_per_client=2, seq_len=24,
+                           vocab=64, seed=3, head=16)
+    cfg = FederatedConfig(K=2, Nloop=1, Nepoch=1, Nadmm=2, default_batch=2,
+                          check_results=False, lr=1e-3, num_devices=1,
+                          save_model=False)
+    t = LMTrainer(model, cfg, data, FedAvg())
+    t.block_ids, t.L = [t.block_ids[1]], 1
+    return t
+
+
+@pytest.fixture(scope="module")
+def rounds():
+    out = {}
+    for impl in ("xla", "pallas_interpret"):
+        t = lm_trainer()
+        with gd.force_gdn_scan_impl(impl):
+            state, hist = t.run(log=lambda m: None)
+        t.close()
+        out[impl] = (jax.tree.map(np.asarray, state.params), hist)
+    return out
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
+def test_every_round_says_which_implementation_ran(rounds, impl):
+    _, hist = rounds[impl]
+    assert len(hist) == 2
+    assert [r["gdn_scan_impl"] for r in hist] == [impl] * 2
+    assert all(r["block_kind"] == "gdn" for r in hist)
+
+
+def test_rounds_through_the_kernels_train_what_the_scan_trains(rounds):
+    """The whole path: ``custom_vjp`` under ``jax.checkpoint``, the map
+    over sequences and the engine's client-by-client gradient."""
+    (p_x, h_x), (p_k, h_k) = rounds["xla"], rounds["pallas_interpret"]
+    for a, b in zip(h_x, h_k):
+        assert a["loss"] == pytest.approx(b["loss"], rel=1e-5)
+    moved = 0.0
+    for a, b in zip(jax.tree.leaves(p_x), jax.tree.leaves(p_k)):
+        # Adam's first steps are lr * sign(g): compare to a tenth of lr
+        assert np.max(np.abs(a - b)) < 1e-4
+        moved = max(moved, float(np.max(np.abs(a[0] - a[1]))))
+    assert moved == 0.0             # FedAvg left the clients equal
+
+
+def test_schema_v18_declares_gdn_scan_impl():
+    assert SCHEMA_VERSION >= 18
+    rung = next(r for r in VERSION_LADDER if r["version"] == 18)
+    assert rung["added_fields"] == ("gdn_scan_impl",)
+    assert rung["added_kinds"] == ()
+    assert "gdn_scan_impl" in ADVISORY_FIELDS
+    base = {"event": "round", "schema": 18, "run_id": "r", "engine": "lm",
+            "round_index": 0, "round_seconds": 0.5, "loss": 1.0}
+    validate_record(dict(base, gdn_scan_impl="pallas"))
+    with pytest.raises(ValueError):
+        validate_record(dict(base, gdn_scan_impl=1))
+
+
+# ----------------------------------------------------------------------
+# compile for the chip, without the chip
+# ----------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("what", ["forward", "forward_and_backward"])
+def test_kernels_compile_for_a_v5e_at_the_published_widths(one_chip, what):
+    """Mosaic takes both kernels at 32 heads of 128, 64 chunks of 64 and
+    the heads per step that ``plan`` picks; interpret mode cannot tell."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    H, N = 32, 64
+    with gd.force_gdn_scan_impl("pallas"):
+        heads = gd.plan(H, N, CHUNK, D, D, BF16)["heads"]
+    sh = lambda dt, *s: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    ops = (sh(F32, H, N, CHUNK, D), sh(BF16, H, N, CHUNK, D),
+           sh(BF16, H, N, CHUNK, CHUNK), sh(BF16, H, N, CHUNK, D),
+           sh(BF16, H, N, CHUNK, D), sh(F32, H, N, 1, D))
+    f = functools.partial(gd._recurrence, heads, False)
+    if what != "forward":
+        f = jax.grad(lambda *a, f=f: jnp.sum(f(*a)), argnums=(0, 1, 2, 3, 4,
+                                                              5))
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without the chip
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(f).lower(*ops).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    assert text.count("tpu_custom_call") == (1 if what == "forward" else 2)
