@@ -460,9 +460,6 @@ def _measure(out: dict) -> None:
     out["compile_cache"] = cache_stats()
     ledger = trainer._ledger
     if ledger is not None:
-        rate = ledger.cache_hit_rate()
-        if rate is not None:
-            out["cache_hit_rate"] = round(rate, 4)
         totals = ledger.totals()
         out["compile_events"] = totals["compile_events"]
         out["compile_seconds"] = round(totals["compile_seconds"], 3)

@@ -14,11 +14,11 @@ statically, on the same whole-program summaries the flow rules use:
   timing/diagnostic telemetry) and ``ENVELOPE_FIELDS`` (run identity)
   are exempt — the whole point is that the exemption is *declared*,
   not inferred.
-* **JG118** — the schema contract itself: the ``VERSION_LADDER`` in
-  obs/schema.py must be strictly additive, every record kind needs a
-  non-empty ``REQUIRED`` core, every emitted kind needs a ``check_*``
-  checker registered in control/replay.py's ``REPLAY_CHECKERS`` (or an
-  explicit exemption), and every registered checker must still exist.
+* **JG118** — the schema contract itself: every record kind of
+  obs/schema.py needs a non-empty ``REQUIRED`` core, every emitted kind
+  needs a ``check_*`` checker registered in control/replay.py's
+  ``REPLAY_CHECKERS`` (or an explicit exemption), and every registered
+  checker must still exist.
 * **JG119** — iteration over an unordered collection (set, dict view,
   ``os.listdir``/glob) feeding a recorded field, or a float ``sum()``
   straight over one, without ``sorted()``.
@@ -36,68 +36,41 @@ Like every graftcheck pass this one is purely syntactic: the contract
 tables are read from the *source* of obs/schema.py and
 control/replay.py via ``ast.literal_eval`` (summary ``tables``), never
 by importing them.  When the declaring modules are not part of the lint
-run (single-fixture invocations), ``DEFAULT_TABLES`` — cross-checked
-against the live modules by ``lint --selftest`` — stands in, and the
+run (single-fixture invocations, ``--changed`` slices) the same
+extraction reads them from the shipped files (``TABLE_SOURCES``), and the
 declaration-site checks are skipped.
 """
 
 from __future__ import annotations
 
+import ast
+from pathlib import Path
 from typing import Dict, Iterator, List, Optional, Sequence, Set, Tuple
 
 from .core import Finding, ModuleContext, ProgramRule, Rule, Severity
-from .flow import _label, _mk_finding, _program_of, Program
+from .flow import (_label, _mk_finding, _program_of, extract_tables,
+                   Program)
 
-#: fallback contract tables for lint runs that do not include
-#: obs/schema.py / control/replay.py (fixture runs, --changed slices).
-#: ``lint --selftest`` asserts these mirror the declaring modules, so
-#: they cannot drift silently.
-DEFAULT_TABLES: Dict[str, object] = {
-    "ADVISORY_FIELDS": (
-        "time_unix", "round_seconds", "stage_seconds", "train_seconds",
-        "comm_seconds", "sync_seconds", "compute_seconds",
-        "epoch_seconds", "ckpt_write_seconds", "overlap_seconds",
-        "overlap_dispatch_seconds", "compile_seconds", "t_start", "t_end",
-        "block_switch_seconds", "gap_seconds", "dispatch_seconds",
-        "block_switch_h2d_bytes", "gdn_scan_impl",
-        "serve_p50_ms", "serve_p99_ms", "serve_qps", "swap_gap_seconds",
-        "serve_accuracy", "drift_score", "forced_refresh",
-        "total_seconds", "round_seconds_total", "stage_seconds_total",
-        "comm_seconds_total", "compile_seconds_total",
-        "rounds_per_sec", "images_per_sec", "comm_overhead_frac",
-        "captured_utc",
-    ),
-    "ENVELOPE_FIELDS": (
-        "event", "schema", "run_id", "run_name", "span_id",
-        "parent_span", "engine", "algorithm", "host", "pid", "git_rev",
-        "devices", "local_devices", "platform", "jax_version",
-        "jaxlib_version", "resumed", "rounds_prior", "config",
-        "mesh_shape",
-    ),
-    "DIAGNOSTIC_KINDS": ("sink_degraded",),
-    "RESERVED_META_NAMESPACES": (
-        ("pop_", ("population.registry",)),
-        ("geom_", ("utils.checkpoint",)),
-        ("members", ("utils.checkpoint",)),
-    ),
-    "EVENTS": ("run_header", "round", "summary", "span", "alert",
-               "compile", "control", "client", "campaign", "serve"),
-    "REPLAY_CHECKERS": {
-        "control": ("check_policy_records", "check_supervisor_records",
-                    "check_reshape_records"),
-        "client": ("check_cohort_records",),
-        "campaign": ("check_campaign_records",),
-        "serve": ("check_serve_records",),
-    },
-    "REPLAY_EXEMPT_KINDS": ("run_header", "round", "summary", "span",
-                            "alert", "compile"),
-}
+#: the shipped files that own the contract tables, for lint runs that do
+#: not include them
+TABLE_SOURCES: Tuple[Path, ...] = tuple(
+    Path(__file__).resolve().parent.parent / rel
+    for rel in ("obs/schema.py", "control/replay.py"))
+
+
+def shipped_tables() -> Dict[str, object]:
+    """The contract tables as ``TABLE_SOURCES`` declare them."""
+    out: Dict[str, object] = {}
+    for path in TABLE_SOURCES:
+        tables = extract_tables(ast.parse(path.read_text(), str(path)))
+        out.update((name, val[0]) for name, val in tables.items())
+    return out
+
 
 #: which module declares each table — a declaration from the canonical
 #: owner wins over any other (fixture) declaration in the same run
 _TABLE_OWNERS = {
     "ADVISORY_FIELDS": "obs.schema", "ENVELOPE_FIELDS": "obs.schema",
-    "VERSION_LADDER": "obs.schema", "SCHEMA_VERSION": "obs.schema",
     "EVENTS": "obs.schema", "REQUIRED": "obs.schema",
     "DIAGNOSTIC_KINDS": "obs.schema",
     "RESERVED_META_NAMESPACES": "obs.schema",
@@ -149,6 +122,7 @@ class _Model:
     def __init__(self, prog: Program, live: Dict[str, ModuleContext]):
         self.prog = prog
         self.live = live
+        self._shipped: Optional[Dict[str, object]] = None
 
         # -------- contract tables: every declaration, with provenance
         self.declared: Dict[str, List[Tuple[object, str, int, str]]] = {}
@@ -344,8 +318,8 @@ class _Model:
 
     def table(self, name: str):
         """The consumed value of one contract table: the canonical
-        owner's declaration if present, else any declaration, else the
-        DEFAULT_TABLES mirror."""
+        owner's declaration if present, else any declaration, else what
+        the shipped owner declares."""
         decls = self.declared.get(name, ())
         owner = _TABLE_OWNERS.get(name)
         for val, _path, _line, modname in decls:
@@ -354,7 +328,9 @@ class _Model:
                 return val
         if decls:
             return decls[0][0]
-        return DEFAULT_TABLES.get(name)
+        if self._shipped is None:
+            self._shipped = shipped_tables()
+        return self._shipped.get(name)
 
     def exempt_fields(self) -> Set[str]:
         adv = self.table("ADVISORY_FIELDS") or ()
@@ -460,93 +436,43 @@ class EntropyIntoRecord(ProgramRule):
 
 # ================================================================ JG118
 
-_LADDER_KEYS = {"version", "added_kinds", "added_fields"}
-
-
 class SchemaContract(ProgramRule):
-    """The additive-schema + replay-coverage contract.
+    """The schema's required cores + replay coverage.
 
     Declaration-site checks (only when the declaring module is in the
-    lint run): the ``VERSION_LADDER`` must be strictly increasing,
-    carry no ``removed_fields``/``removed_kinds`` rungs, top out at
-    ``SCHEMA_VERSION``, introduce every ``EVENTS`` kind exactly once,
-    and every kind needs a non-empty ``REQUIRED`` core.  Every checker
-    named in ``REPLAY_CHECKERS`` must exist in the declaring module.
-    Emit-site check (always): a record kind emitted anywhere must be
-    replay-checked, replay-exempt, or a declared diagnostic."""
+    lint run): every ``EVENTS`` kind needs a non-empty ``REQUIRED`` core,
+    and every checker named in ``REPLAY_CHECKERS`` must exist in the
+    declaring module.  Emit-site check (always): a record kind emitted
+    anywhere must be replay-checked, replay-exempt, or a declared
+    diagnostic."""
 
     id = "JG118"
     severity = Severity.ERROR
 
     def check_program(self, modules, extra_summaries, state):
         model = _model_of(modules, extra_summaries, state)
-        yield from self._check_ladders(model)
+        yield from self._check_required(model)
         yield from self._check_checkers(model)
         yield from self._check_emits(model)
 
-    # ------------------------------------------------- ladder shape
+    # ------------------------------------------------ required cores
 
-    def _sibling(self, model: _Model, path: str, name: str):
-        for val, p, _line, _mod in model.declared.get(name, ()):
-            if p == path:
-                return val
-        return None
-
-    def _check_ladders(self, model: _Model) -> Iterator[Finding]:
-        for val, path, line, _mod in model.declared.get(
-                "VERSION_LADDER", ()):
-            if path not in model.live:
+    def _check_required(self, model: _Model) -> Iterator[Finding]:
+        events_at = {path: val for val, path, _line, _mod
+                     in model.declared.get("EVENTS", ())}
+        for required, path, line, _mod in model.declared.get(
+                "REQUIRED", ()):
+            events = events_at.get(path)
+            if path not in model.live or not isinstance(required, dict) \
+                    or not isinstance(events, (list, tuple)):
                 continue
-
-            def bad(msg: str, ln: int = line) -> Finding:
-                return _mk_finding(self, model.live, path, ln, 0,
-                                   "schema contract violated: " + msg,
-                                   ())
-
-            if not isinstance(val, (list, tuple)) or not val or \
-                    not all(isinstance(r, dict) for r in val):
-                yield bad("VERSION_LADDER must be a non-empty tuple of "
-                          "rung dicts")
-                continue
-            versions = [r.get("version") for r in val]
-            if not all(isinstance(v, int) for v in versions) or \
-                    any(b <= a for a, b in zip(versions, versions[1:])):
-                yield bad("VERSION_LADDER versions must be strictly "
-                          "increasing ints (got %r)" % (versions,))
-            for rung in val:
-                extra = set(rung) - _LADDER_KEYS
-                removed = {k for k in extra if k.startswith("removed")}
-                if removed:
-                    yield bad(
-                        "rung v%r is non-additive: %s. The schema only "
-                        "ever *adds* kinds/fields — removing one breaks "
-                        "every reader of an older stream"
-                        % (rung.get("version"), ", ".join(sorted(removed))))
-            schema_version = self._sibling(model, path, "SCHEMA_VERSION")
-            if isinstance(schema_version, int) and versions and \
-                    isinstance(versions[-1], int) and \
-                    versions[-1] != schema_version:
-                yield bad("VERSION_LADDER tops out at v%r but "
-                          "SCHEMA_VERSION is %r — the ladder must "
-                          "record every bump" % (versions[-1],
-                                                 schema_version))
-            events = self._sibling(model, path, "EVENTS")
-            required = self._sibling(model, path, "REQUIRED")
-            if isinstance(events, (list, tuple)):
-                for kind in events:
-                    rungs = [r.get("version") for r in val
-                             if isinstance(r.get("added_kinds"),
-                                           (list, tuple))
-                             and kind in r["added_kinds"]]
-                    if len(rungs) != 1:
-                        yield bad("record kind %r must be introduced by "
-                                  "exactly one ladder rung (found in %r)"
-                                  % (kind, rungs))
-                    if isinstance(required, dict) and \
-                            not required.get(kind):
-                        yield bad("record kind %r has no REQUIRED core "
-                                  "— every kind needs a stable required-"
-                                  "field set" % (kind,))
+            for kind in events:
+                if not required.get(kind):
+                    yield _mk_finding(
+                        self, model.live, path, line, 0,
+                        "schema contract violated: record kind %r has no "
+                        "REQUIRED core — every kind needs a stable "
+                        "required-field set" % (kind,), ())
 
     # --------------------------------------------- checker existence
 

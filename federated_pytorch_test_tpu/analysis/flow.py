@@ -76,7 +76,7 @@ SUMMARY_VERSION = 3
 #: cache-validity check, so a rule edit invalidates sha1-matched entries
 #: that would otherwise serve stale summaries (the shape-only
 #: SUMMARY_VERSION cannot catch logic changes)
-ANALYSIS_VERSION = 3
+ANALYSIS_VERSION = 4
 
 #: callable wrappers that pass their first argument's signature through
 _TRANSPARENT_WRAPPERS = {"vmap", "pmap", "jit", "pjit", "shard_map",
@@ -144,10 +144,31 @@ _RECORDER_METHODS = {"round": "round", "span": "span", "alert": "alert",
 #: extracted with ``ast.literal_eval`` so the rules never import linted
 #: code — the tables must therefore stay pure literals at their source
 CONTRACT_TABLE_NAMES = (
-    "ADVISORY_FIELDS", "ENVELOPE_FIELDS", "VERSION_LADDER",
+    "ADVISORY_FIELDS", "ENVELOPE_FIELDS",
     "RESERVED_META_NAMESPACES", "DIAGNOSTIC_KINDS",
-    "REPLAY_CHECKERS", "REPLAY_EXEMPT_KINDS",
-    "SCHEMA_VERSION", "EVENTS", "REQUIRED")
+    "REPLAY_CHECKERS", "REPLAY_EXEMPT_KINDS", "EVENTS", "REQUIRED")
+
+
+def extract_tables(tree: ast.Module) -> Dict[str, list]:
+    """``{name: [value, line]}`` of the contract tables a module declares:
+    module-level pure-literal assignments only, so the contract pass reads
+    the declared contract without importing the code that declares it."""
+    tables: Dict[str, list] = {}
+    for node in tree.body:
+        tgt = None
+        if (isinstance(node, ast.Assign) and len(node.targets) == 1
+                and isinstance(node.targets[0], ast.Name)):
+            tgt = node.targets[0].id
+        elif (isinstance(node, ast.AnnAssign)
+              and isinstance(node.target, ast.Name)
+              and node.value is not None):
+            tgt = node.target.id
+        if tgt in CONTRACT_TABLE_NAMES:
+            try:
+                tables[tgt] = [ast.literal_eval(node.value), node.lineno]
+            except (ValueError, SyntaxError, TypeError):
+                pass
+    return tables
 
 
 def _canon_call(d: str, import_mods: Dict[str, str],
@@ -1222,26 +1243,6 @@ def extract_module_summary(module: ModuleContext) -> dict:
     functions["<module>"].update(
         _extract_contracts(tree, import_mods, import_syms))
 
-    # machine-readable contract tables (ADVISORY_FIELDS, VERSION_LADDER,
-    # REPLAY_CHECKERS, ...): module-level pure-literal assignments only,
-    # so the contract pass reads the declared contract without importing
-    # the code that declares it
-    tables: Dict[str, list] = {}
-    for node in tree.body:
-        tgt = None
-        if (isinstance(node, ast.Assign) and len(node.targets) == 1
-                and isinstance(node.targets[0], ast.Name)):
-            tgt = node.targets[0].id
-        elif (isinstance(node, ast.AnnAssign)
-              and isinstance(node.target, ast.Name)
-              and node.value is not None):
-            tgt = node.target.id
-        if tgt in CONTRACT_TABLE_NAMES:
-            try:
-                tables[tgt] = [ast.literal_eval(node.value), node.lineno]
-            except (ValueError, SyntaxError, TypeError):
-                pass
-
     summary = {
         "version": SUMMARY_VERSION,
         "path": module.path,
@@ -1250,7 +1251,7 @@ def extract_module_summary(module: ModuleContext) -> dict:
         "import_syms": import_syms,
         "jnp_aliases": sorted(index.jnp_aliases),
         "classes": classes,
-        "tables": tables,
+        "tables": extract_tables(tree),
         "functions": functions,
         "suppress": [[ln, sorted(ids)] for ln, ids in
                      sorted(suppressed_rules_by_line(module.source).items())],

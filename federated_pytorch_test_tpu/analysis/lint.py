@@ -46,9 +46,8 @@ def build_parser() -> argparse.ArgumentParser:
                    help="files or directories (directories recurse to *.py)")
     p.add_argument("--selftest", action="store_true",
                    help="run the built-in self-check (each determinism-"
-                        "contract rule fires on its canary snippet and "
-                        "the DEFAULT_TABLES mirror matches the declaring "
-                        "modules) and exit")
+                        "contract rule fires on its canary snippet, the "
+                        "clean canary stays silent) and exit")
     p.add_argument("--json", action="store_true",
                    help="emit findings as JSON instead of text")
     p.add_argument("--sarif", action="store_true",
@@ -182,15 +181,8 @@ _SELFTEST_SNIPPETS = {
               "    t = time.time()\n"
               "    rec = {'event': 'control', 'observed': t}\n"
               "    sink.control_event(rec)\n"),
-    "JG118": ("SCHEMA_VERSION = 2\n"
-              "EVENTS = ('round',)\n"
-              "REQUIRED = {'round': ('event',)}\n"
-              "VERSION_LADDER = (\n"
-              "    {'version': 1, 'added_kinds': ('round',),\n"
-              "     'added_fields': ()},\n"
-              "    {'version': 2, 'added_kinds': (), 'added_fields': (),\n"
-              "     'removed_fields': ('loss',)},\n"
-              ")\n"),
+    "JG118": ("EVENTS = ('round', 'probe')\n"
+              "REQUIRED = {'round': ('event',), 'probe': ()}\n"),
     "JG119": ("def emit(sink, xs):\n"
               "    ids = [x for x in set(xs)]\n"
               "    rec = {'event': 'client', 'clients': ids}\n"
@@ -216,9 +208,7 @@ _SELFTEST_CLEAN = (
 
 
 def selftest() -> int:
-    """Exit 0 when the contract rules and tables are healthy."""
-    from .contracts import DEFAULT_TABLES
-
+    """Exit 0 when the contract rules are healthy."""
     failures: List[str] = []
     engine = LintEngine(ALL_RULES)
     for rule_id, source in sorted(_SELFTEST_SNIPPETS.items()):
@@ -235,33 +225,12 @@ def selftest() -> int:
     if got:
         failures.append(f"clean canary fired {sorted(got)}")
 
-    # the DEFAULT_TABLES mirror (used when the declaring modules are
-    # not in the lint run) must match what the declaring modules say
-    here = Path(__file__).resolve().parent.parent
-    declared: Dict[str, object] = {}
-    for rel in ("obs/schema.py", "control/replay.py"):
-        src = (here / rel).read_text()
-        module, _ = engine._parse(src, str(here / rel))
-        if module is None:
-            failures.append(f"{rel}: failed to parse for table check")
-            continue
-        for name, (value, _line) in \
-                extract_module_summary(module)["tables"].items():
-            declared[name] = value
-    for name, mirror in sorted(DEFAULT_TABLES.items()):
-        if name not in declared:
-            failures.append(f"table {name}: not declared in "
-                            "obs/schema.py or control/replay.py")
-        elif declared[name] != mirror:
-            failures.append(f"table {name}: DEFAULT_TABLES mirror is out "
-                            "of sync with the declaring module")
-
     if failures:
         for f in failures:
             print(f"graftcheck selftest: FAIL: {f}", file=sys.stderr)
         return 1
     print(f"graftcheck selftest: ok ({len(_SELFTEST_SNIPPETS)} contract "
-          f"canaries, clean canary, {len(DEFAULT_TABLES)} tables in sync)")
+          "canaries, clean canary)")
     return 0
 
 

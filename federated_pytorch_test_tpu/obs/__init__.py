@@ -1,15 +1,13 @@
 """Observability: structured run telemetry for every engine.
 
-- :mod:`.metrics`  — host-side counters / gauges / timers.
 - :mod:`.schema`   — versioned run_header / round / summary records.
-- :mod:`.sinks`    — JSONL / CSV / stdout / in-memory emitters.
+- :mod:`.sinks`    — JSONL / in-memory emitters.
 - :mod:`.recorder` — the per-run emitter the engines thread through.
 - :mod:`.report`   — ``python -m federated_pytorch_test_tpu.obs.report``.
 - :mod:`.trace`    — span timeline → Chrome trace-event JSON exporter.
 - :mod:`.health`   — streaming anomaly watchdog (``--health-action``).
 - :mod:`.compare`  — cross-run regression CLI (CI gate).
-- :mod:`.costs`    — per-jit-site compile/HLO device-cost ledger.
-- :mod:`.profile`  — ``python -m federated_pytorch_test_tpu.obs.profile``.
+- :mod:`.costs`    — per-jit-site compile and dispatch ledger.
 - :mod:`.clients`  — client-grain flight recorder: per-client ledgers,
   deterministic anomaly ranking, cohort rollups
   (``python -m federated_pytorch_test_tpu.obs.clients``).
@@ -35,12 +33,6 @@ from federated_pytorch_test_tpu.obs.health import (  # noqa: F401
     RunHealthAbort,
     monitor_from_config,
 )
-from federated_pytorch_test_tpu.obs.metrics import (  # noqa: F401
-    Counter,
-    Gauge,
-    Metrics,
-    Timer,
-)
 from federated_pytorch_test_tpu.obs.recorder import (  # noqa: F401
     RunRecorder,
     device_memory_stats,
@@ -54,11 +46,9 @@ from federated_pytorch_test_tpu.obs.schema import (  # noqa: F401
     validate_record,
 )
 from federated_pytorch_test_tpu.obs.sinks import (  # noqa: F401
-    CsvSink,
     JsonlSink,
     MemorySink,
     Sink,
-    StdoutSink,
     make_sinks,
 )
 from federated_pytorch_test_tpu.obs.trace import (  # noqa: F401

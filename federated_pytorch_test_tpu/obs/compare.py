@@ -46,14 +46,6 @@ _DIRECTION = {
     "comm_overhead_frac": -1,
     "mfu": +1,
     "value": +1,
-    # device-cost ledger metrics (schema v6; obs/profile.py): a compile-
-    # time or device-memory regression fails the gate like a throughput
-    # regression does
-    "compile_seconds": -1,
-    "compile_seconds_cold": -1,
-    "peak_device_bytes": -1,
-    "utilization": +1,
-    "cache_hit_rate": +1,
     # soak campaigns (schema v12; bench.py --soak): the availability
     # gate — losing availability or losing more rounds to restarts than
     # the committed SOAK_BASELINE fails CI like a throughput regression
@@ -142,7 +134,6 @@ def load_source(path: str) -> Dict[str, Any]:
     src: Dict[str, Any] = {"path": path, "kind": "?", "metrics": {},
                            "notes": [], "baseline_ref": None}
     if path.endswith(".jsonl"):
-        from federated_pytorch_test_tpu.obs.profile import profile_metrics
         from federated_pytorch_test_tpu.obs.report import (
             read_records,
             summarize,
@@ -209,12 +200,6 @@ def load_source(path: str) -> Dict[str, Any]:
                 f"soak campaign stream: {s.get('segments')} segment(s), "
                 f"{s.get('campaign_virtual_hours')} virtual h, "
                 f"availability {s.get('availability_pct')}%")
-        # device-cost metrics (schema v6): present only when the run's
-        # ledger emitted them, so pre-v6 streams compare unchanged
-        for k, val in profile_metrics(records).items():
-            v = _num(val)
-            if v is not None:
-                src["metrics"][k] = v
         if s.get("status") != "completed":
             src["notes"].append(f"status={s.get('status')}")
         # control-plane records (schema v8): a supervised run that

@@ -30,7 +30,6 @@ import time
 import uuid
 from typing import Any, Dict, List, Optional, Sequence
 
-from federated_pytorch_test_tpu.obs.metrics import Metrics
 from federated_pytorch_test_tpu.obs.schema import (
     SCHEMA_VERSION,
     SchemaError,
@@ -41,8 +40,8 @@ from federated_pytorch_test_tpu.obs.sinks import MemorySink, Sink, make_sinks
 
 #: round fields summed into *_total summary fields
 _SUMMED = ("bytes_on_wire", "bytes_dense", "images", "guard_trips",
-           "fault_dropped", "fault_straggled", "fault_corrupted")
-_SUMMED_SECONDS = ("round_seconds", "stage_seconds", "comm_seconds")
+           "fault_dropped", "fault_straggled", "fault_corrupted",
+           "round_seconds", "stage_seconds", "comm_seconds")
 
 
 def device_memory_stats() -> Dict[str, int]:
@@ -99,7 +98,9 @@ class RunRecorder:
         self.run_id = run_id or uuid.uuid4().hex[:8]
         self.jsonl_path = jsonl_path
         self.enabled = bool(self.sinks)
-        self.totals = Metrics()
+        self._rounds = 0
+        self._sums: Dict[str, Any] = {}  # round field -> sum, where seen
+        self._quarantined_last: Optional[int] = None
         self._opened = False
         self._closed = False
         self._t0 = None
@@ -121,14 +122,12 @@ class RunRecorder:
         # intervention tally surfaced on the summary
         self.control = None
         self._controls = 0
-        # device-cost ledger totals (schema v6): compile events emitted
+        # compile-ledger totals: compile events emitted
         # through compile_event(), and the device-memory high-watermark
         # tracked across round records (device_memory_stats is
         # instantaneous; the run-level peak belongs on the summary)
         self._compile_events = 0
         self._compile_seconds = 0.0
-        self._cache_hits = 0
-        self._cache_misses = 0
         self._mem_watermark: Optional[int] = None
         self._mem_final: Optional[int] = None
 
@@ -258,17 +257,13 @@ class RunRecorder:
                     rec["t_end"] = float(t_start) + float(secs)
             self._grow_extent(t_start, rec.get("t_end", t_start))
         if self.enabled:
-            self.totals.counter("rounds").inc()
+            self._rounds += 1
             for k in _SUMMED:
                 v = rec.get(k)
                 if isinstance(v, (int, float)) and not isinstance(v, bool):
-                    self.totals.counter(k + "_total").inc(v)
-            for k in _SUMMED_SECONDS:
-                v = rec.get(k)
-                if isinstance(v, (int, float)):
-                    self.totals.timer(k[: -len("_seconds")]).observe(v)
+                    self._sums[k] = self._sums.get(k, 0) + v
             if isinstance(rec.get("quarantined"), int):
-                self.totals.gauge("quarantined_last").set(rec["quarantined"])
+                self._quarantined_last = rec["quarantined"]
             for k in ("mem_peak_bytes_in_use", "mem_bytes_in_use"):
                 v = rec.get(k)
                 if isinstance(v, int) and not isinstance(v, bool):
@@ -435,14 +430,14 @@ class RunRecorder:
 
     def compile_event(self, fields: Dict[str, Any], *,
                       parent_span: Optional[str] = None) -> Optional[dict]:
-        """Emit one ``compile`` record (schema v6; obs/costs.py).
+        """Emit one ``compile`` record (obs/costs.py).
 
         ``fields`` is a :meth:`~..obs.costs.CompileEvent.record` body:
-        ``site`` + ``compile_seconds`` required, AOT cost fields
-        optional.  When it carries ``t_start``/``t_end`` the record
-        doubles as a span — parented to ``parent_span`` (the enclosing
-        round) or, for events drained outside any round window, to the
-        run span, keeping the Chrome-trace nesting laminar.
+        ``site`` + ``compile_seconds`` required.  When it carries
+        ``t_start``/``t_end`` the record doubles as a span — parented
+        to ``parent_span`` (the enclosing round) or, for events drained
+        outside any round window, to the run span, keeping the
+        Chrome-trace nesting laminar.
         """
         if not self.enabled:
             return None
@@ -464,11 +459,6 @@ class RunRecorder:
         secs = rec.get("compile_seconds")
         if isinstance(secs, (int, float)) and not isinstance(secs, bool):
             self._compile_seconds += float(secs)
-        hit = rec.get("cache_hit")
-        if hit is True:
-            self._cache_hits += 1
-        elif hit is False:
-            self._cache_misses += 1
         return self._emit(rec)
 
     def close(self, status: str = "completed",
@@ -490,8 +480,7 @@ class RunRecorder:
                 "t_start": self._span_extent[0],
                 "t_end": self._span_extent[1],
             })
-        snap = self.totals.snapshot()
-        rounds = int(snap.get("rounds", 0))
+        rounds = self._rounds
         rec: Dict[str, Any] = {
             "event": "summary", "schema": SCHEMA_VERSION,
             "run_id": self.run_id, "status": status, "rounds": rounds,
@@ -500,16 +489,15 @@ class RunRecorder:
         if self._t0 is not None:
             rec["total_seconds"] = time.monotonic() - self._t0
         for k in _SUMMED:
-            if k + "_total" in snap:
-                v = snap[k + "_total"]
-                rec[k + "_total"] = (int(v) if float(v).is_integer()
-                                     else float(v))
-        for k in _SUMMED_SECONDS:
-            base = k[: -len("_seconds")]
-            if base + "_seconds" in snap:
-                rec[k + "_total"] = snap[base + "_seconds"]
-        if "quarantined_last" in snap:
-            rec["quarantined_last"] = snap["quarantined_last"]
+            if k not in self._sums:
+                continue
+            v = self._sums[k]
+            # counters may arrive as float from a psum; seconds stay float
+            rec[k + "_total"] = (int(v) if float(v).is_integer()
+                                 and not k.endswith("_seconds")
+                                 else float(v))
+        if self._quarantined_last is not None:
+            rec["quarantined_last"] = self._quarantined_last
         if self._loss_first is not None:
             rec["loss_first"] = self._loss_first
             rec["loss_final"] = self._loss_final
@@ -520,9 +508,6 @@ class RunRecorder:
         if self._compile_events:
             rec["compile_events_total"] = self._compile_events
             rec["compile_seconds_total"] = self._compile_seconds
-            if self._cache_hits or self._cache_misses:
-                rec["cache_hits_total"] = self._cache_hits
-                rec["cache_misses_total"] = self._cache_misses
         if self._mem_watermark is not None:
             rec["mem_peak_bytes_watermark"] = int(self._mem_watermark)
             if self._mem_final is not None:

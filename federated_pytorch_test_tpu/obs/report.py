@@ -472,7 +472,6 @@ def format_report(s: Dict[str, Any]) -> str:
         msg = f"{s.get('compile_events', 0)} event(s)"
         if s.get("compile_seconds_total") is not None:
             msg += f", {s['compile_seconds_total']:.2f} s"
-        msg += "  (details: python -m federated_pytorch_test_tpu.obs.profile)"
         row("compile", msg)
     if s.get("mem_peak_bytes_watermark") is not None:
         msg = "watermark " + _fmt_bytes(s["mem_peak_bytes_watermark"])
@@ -488,10 +487,9 @@ def format_report(s: Dict[str, Any]) -> str:
 
 def selftest() -> str:
     """Recorder → JSONL → parse → validate → summarise round-trip, plus
-    the trace-exporter, watchdog, compare, cost-profile, and
-    control-replay selftests (tier-1 runs this, so the whole
-    live-health + device-cost + control-plane layer is exercised
-    without a prior training run)."""
+    the trace-exporter, watchdog, compare and control-replay selftests
+    (tier-1 runs this, so the whole live-health + control-plane layer is
+    exercised without a prior training run)."""
     import os
     import tempfile
 
@@ -630,7 +628,7 @@ def selftest() -> str:
         schedule as campaign_schedule)
     from federated_pytorch_test_tpu.control import replay as control_replay
     from federated_pytorch_test_tpu.obs import (
-        clients, compare, health, profile, trace,
+        clients, compare, health, trace,
     )
     from federated_pytorch_test_tpu.serve import (
         batcher as serve_batcher,
@@ -642,7 +640,6 @@ def selftest() -> str:
     trace.selftest()
     health.selftest()
     compare.selftest()
-    profile.selftest()
     control_replay.selftest()
     clients.selftest()
     campaign_schedule.selftest()
@@ -661,7 +658,6 @@ def selftest() -> str:
             + "\nobs trace selftest: OK (Chrome trace valid)"
             + "\nobs health selftest: OK (NaN streak alerted)"
             + "\nobs compare selftest: OK (regression gate works)"
-            + "\nobs profile selftest: OK (cost attribution reconstructs)"
             + "\ncontrol replay selftest: OK (decisions reproduce)"
             + "\nobs clients selftest: OK (anomaly ranking replayable)"
             + "\ncampaign selftests: OK (schedule pure; clock scales "
@@ -669,7 +665,7 @@ def selftest() -> str:
             + "\nserve selftests: OK (batcher deterministic; swap "
             "never torn; predictor pads to buckets; drift scored)"
             + "\ngraftcheck contract selftest: OK (JG117-JG121 canaries "
-            "fire; contract tables in sync)"
+            "fire)"
             + "\nobs report selftest: OK")
 
 

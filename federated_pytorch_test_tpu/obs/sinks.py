@@ -11,10 +11,6 @@ A sink receives every schema-validated record (``run_header`` /
   overflow buffer (one structured warning, the run keeps going —
   telemetry must never kill training).  ``close()`` makes one last
   attempt to land the overflow on disk.
-- :class:`CsvSink`    — ``round`` records only; columns fixed by the
-  first round record (later extra keys are dropped, missing keys blank)
-  so the file stays loadable by anything that reads CSV.
-- :class:`StdoutSink` — raw JSONL to stdout (pipe into ``obs.report``).
 - :class:`MemorySink` — in-process list, for tests.
 
 ``make_sinks`` parses the ``--obs-sinks`` spec (comma-separated; see
@@ -32,7 +28,7 @@ import sys
 import time
 from typing import IO, List, Optional, Tuple
 
-SINK_CHOICES = ("auto", "none", "jsonl", "csv", "stdout", "memory")
+SINK_CHOICES = ("auto", "none", "jsonl", "memory")
 
 
 class Sink:
@@ -126,48 +122,6 @@ class JsonlSink(Sink):
             self._f = None
 
 
-class CsvSink(Sink):
-    def __init__(self, path: str):
-        self.path = path
-        self._f: Optional[IO[str]] = None
-        self._writer = None
-        self._columns: Optional[List[str]] = None
-
-    def emit(self, record: dict) -> None:
-        import csv
-
-        if record.get("event") != "round":
-            return
-        if self._f is None:
-            os.makedirs(os.path.dirname(self.path) or ".", exist_ok=True)
-            # append mode like JsonlSink; a resumed run whose first new
-            # record has the same shape just keeps extending the table
-            new = not os.path.exists(self.path)
-            self._f = open(self.path, "a", newline="")
-            self._columns = list(record.keys())
-            self._writer = csv.DictWriter(self._f, self._columns,
-                                          extrasaction="ignore",
-                                          restval="")
-            if new:
-                self._writer.writeheader()
-        row = {k: record.get(k, "") for k in self._columns}
-        # lists (e.g. accuracy) would explode the cell; keep them JSON
-        row = {k: json.dumps(v) if isinstance(v, (list, dict)) else v
-               for k, v in row.items()}
-        self._writer.writerow(row)
-        self._f.flush()
-
-    def close(self) -> None:
-        if self._f is not None:
-            self._f.close()
-            self._f = None
-
-
-class StdoutSink(Sink):
-    def emit(self, record: dict) -> None:
-        print(json.dumps(record), flush=True)
-
-
 class MemorySink(Sink):
     def __init__(self):
         self.records: List[dict] = []
@@ -181,10 +135,10 @@ def make_sinks(spec: str, obs_dir: Optional[str] = None,
     """Build sinks from a comma-separated spec.
 
     Returns ``(sinks, jsonl_path)`` — the path is reported back so
-    callers (bench.py) can record where the artifact went.  File sinks
-    land in ``obs_dir`` (created on first write) as
-    ``<run_name>.jsonl`` / ``<run_name>.csv``; requesting one without
-    an ``obs_dir`` defaults to ``./obs``.
+    callers (bench.py) can record where the artifact went.  The JSONL
+    file lands in ``obs_dir`` (created on first write) as
+    ``<run_name>.jsonl``; requesting it without an ``obs_dir`` defaults
+    to ``./obs``.
     """
     tokens = [t.strip() for t in (spec or "auto").split(",") if t.strip()]
     resolved: List[str] = []
@@ -199,15 +153,11 @@ def make_sinks(spec: str, obs_dir: Optional[str] = None,
     sinks: List[Sink] = []
     jsonl_path = None
     for t in resolved:
-        if t in ("jsonl", "csv") and obs_dir is None:
-            obs_dir = "obs"
         if t == "jsonl":
+            if obs_dir is None:
+                obs_dir = "obs"
             jsonl_path = os.path.join(obs_dir, run_name + ".jsonl")
             sinks.append(JsonlSink(jsonl_path))
-        elif t == "csv":
-            sinks.append(CsvSink(os.path.join(obs_dir, run_name + ".csv")))
-        elif t == "stdout":
-            sinks.append(StdoutSink())
         elif t == "memory":
             sinks.append(MemorySink())
     return sinks, jsonl_path

@@ -358,7 +358,7 @@ class FederatedConfig:
     # a run-header event, one validated record per comm round, and a
     # closing summary — through the sinks named here ("auto" resolves to
     # jsonl when obs_dir is set, else none; comma-separable choices:
-    # none|jsonl|csv|stdout|memory).  Drivers default obs_dir to
+    # none|jsonl|memory).  Drivers default obs_dir to
     # <checkpoint_dir>/obs so real runs are observable out of the box;
     # "--obs-sinks none" disables file output (emission is host-side at
     # round boundaries either way, so the math is bit-identical).
@@ -421,15 +421,12 @@ class FederatedConfig:
     sanitize: bool = False
     retrace_sentinel: bool = False
 
-    # device-cost ledger (obs/costs.py) — default ON: per-jit-site
-    # compile wall-seconds, AOT cost-model FLOPs/bytes, and persistent-
-    # compile-cache hit/miss attribution, drained into the obs round
-    # records (schema v6) and `compile` events.  The wrappers only time
-    # dispatch and read cached AOT analyses — training math is
-    # bit-identical on/off (tested); --no-cost-ledger rebuilds the
-    # literal uninstrumented chain.  AOT depth: FEDTPU_COST_AOT
-    # (off|lowered|full, default lowered; "full" adds memory_analysis at
-    # the price of a second compile per program).
+    # compile ledger (obs/costs.py) — default ON: per-jit-site compile
+    # wall-seconds and the host seconds inside every instrumented call,
+    # drained into the obs round records (`compile_seconds`,
+    # `dispatch_seconds`) and `compile` events.  The wrappers only time
+    # the dispatch — training math is bit-identical on/off (tested);
+    # --no-cost-ledger rebuilds the literal uninstrumented chain.
     cost_ledger: bool = True
 
     # client-grain flight recorder (obs/clients.py) — default ON: one

@@ -274,9 +274,9 @@ class BlockwiseFederatedTrainer(RoundKernel):
         # builders wrap nothing and the jitted chain is literally the
         # uninstrumented one
         self._sentinel = TraceSentinel() if cfg.retrace_sentinel else None
-        # device-cost ledger (obs/costs.py): per-jit-site compile
-        # wall-seconds + AOT cost-model numbers + compile-cache
-        # attribution, drained into the obs round records each round.
+        # compile ledger (obs/costs.py): per-jit-site compile
+        # wall-seconds and dispatch seconds, drained into the obs round
+        # records each round.
         # None when off so the jitted chain is literally the
         # uninstrumented one (same contract as the sentinel)
         self._ledger = CostLedger() if cfg.cost_ledger else None

@@ -111,14 +111,12 @@ def param_leaves(state):
 
 
 def det_view(rec):
-    # wall-clock and compile/cache-attribution fields legitimately
-    # differ between a resumed process and an uninterrupted one
+    # wall-clock fields legitimately differ between a resumed process
+    # and an uninterrupted one, and a resumed segment's first round is a
+    # block visit's first round: it stamps a switch
     return {k: v for k, v in rec.items()
             if isinstance(v, (int, float)) and not k.endswith("_seconds")
-            and k not in ("cache_hit", "peak_device_bytes",
-                          # a resumed segment's first round is a block
-                          # visit's first round: it stamps a switch
-                          "block_switch_h2d_bytes")}
+            and k != "block_switch_h2d_bytes"}
 
 
 # ----------------------------------------------------------------------

@@ -894,12 +894,10 @@ class TestCPCSupervised:
                               cfg=FederatedConfig(check_results=False))
 
         # same normalization as tests/test_resume.py: the restarted
-        # process re-compiles, so cache_hit / peak_device_bytes land on
-        # rounds the uninterrupted run attributed differently
+        # process re-compiles, so its wall-clock fields differ
         strip = lambda h: [
             {k: v for k, v in r.items()
-             if not k.endswith("_seconds")
-             and k not in ("cache_hit", "peak_device_bytes")} for r in h]
+             if not k.endswith("_seconds")} for r in h]
         _, want = make().run(Nloop=1, Nadmm=2, log=lambda m: None)
 
         ck = str(tmp_path / "cpc_sup_ck")

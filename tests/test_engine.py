@@ -638,10 +638,7 @@ class TestBlockSwitchOnDevice:
 
         t._emit_round_obs = spy
         state, hist = t.run(log=lambda m: None)
-        # the cost model's per-round totals count the switch's program too
-        skip = ADVISORY_FIELDS + ("cache_hit", "flops_round",
-                                  "hlo_bytes_accessed", "peak_device_bytes")
-        core = [{k: v for k, v in r.items() if k not in skip}
+        core = [{k: v for k, v in r.items() if k not in ADVISORY_FIELDS}
                 for r in hist]
         return t, jax.device_get(state.params), core, seen
 

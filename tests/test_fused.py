@@ -118,16 +118,13 @@ def param_leaves(state):
 
 
 def strip(rec):
-    # wall-clock and compile/cache-attribution fields legitimately
-    # differ between runs: a resumed process re-compiles at its first
-    # continued round, so cache_hit lands on rounds the uninterrupted
-    # run compiled nothing in (obs/costs.py)
+    # wall-clock fields legitimately differ between runs (a resumed
+    # process re-compiles at its first continued round), and a resumed
+    # segment's first round is a block visit's first round: it stamps a
+    # switch
     return {k: v for k, v in rec.items()
             if isinstance(v, (int, float)) and not k.endswith("_seconds")
-            and k not in ("cache_hit", "peak_device_bytes",
-                          # a resumed segment's first round is a block
-                          # visit's first round: it stamps a switch
-                          "block_switch_h2d_bytes")}
+            and k != "block_switch_h2d_bytes"}
 
 
 ALGOS = [("fedavg", FedAvg), ("fedprox", FedProx),

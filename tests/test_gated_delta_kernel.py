@@ -21,12 +21,6 @@ if REPO not in sys.path:
 
 from federated_pytorch_test_tpu.data.tokens import FederatedTokens  # noqa: E402
 from federated_pytorch_test_tpu.models import get_model  # noqa: E402
-from federated_pytorch_test_tpu.obs.schema import (  # noqa: E402
-    ADVISORY_FIELDS,
-    SCHEMA_VERSION,
-    VERSION_LADDER,
-    validate_record,
-)
 from federated_pytorch_test_tpu.ops import gated_delta as gd  # noqa: E402
 from federated_pytorch_test_tpu.train import (  # noqa: E402
     FedAvg,
@@ -276,19 +270,6 @@ def test_rounds_through_the_kernels_train_what_the_scan_trains(rounds):
         assert np.max(np.abs(a - b)) < 1e-4
         moved = max(moved, float(np.max(np.abs(a[0] - a[1]))))
     assert moved == 0.0             # FedAvg left the clients equal
-
-
-def test_schema_v18_declares_gdn_scan_impl():
-    assert SCHEMA_VERSION >= 18
-    rung = next(r for r in VERSION_LADDER if r["version"] == 18)
-    assert rung["added_fields"] == ("gdn_scan_impl",)
-    assert rung["added_kinds"] == ()
-    assert "gdn_scan_impl" in ADVISORY_FIELDS
-    base = {"event": "round", "schema": 18, "run_id": "r", "engine": "lm",
-            "round_index": 0, "round_seconds": 0.5, "loss": 1.0}
-    validate_record(dict(base, gdn_scan_impl="pallas"))
-    with pytest.raises(ValueError):
-        validate_record(dict(base, gdn_scan_impl=1))
 
 
 # ----------------------------------------------------------------------

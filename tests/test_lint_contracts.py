@@ -3,12 +3,13 @@
 The clean-tree gate (test_lint_clean.py) proves the shipped sources
 pass; the fixture gate (test_lint_rules.py) proves each rule fires on
 its minimal trigger.  This module proves the contract layer is *not
-vacuous against the real contract surfaces*: mutating the shipped
-``obs/schema.py`` version ladder or deleting a registered replay
-checker from the shipped ``control/replay.py`` must flip JG118 from
-silent to firing, entropy taint must survive a call chain (and its
-deterministic twin must not), the machine-readable outputs must
-round-trip contract findings, and the summary cache must refuse
+vacuous against the real contract surfaces*: emptying a kind's
+``REQUIRED`` core in the shipped ``obs/schema.py`` or deleting a
+registered replay checker from the shipped ``control/replay.py`` must
+flip JG118 from silent to firing, a rule run over one file alone must see
+the tables of the shipped sources, entropy taint must survive a call
+chain (and its deterministic twin must not), the machine-readable outputs
+must round-trip contract findings, and the summary cache must refuse
 entries written by a previous analysis generation.
 """
 
@@ -16,7 +17,7 @@ import json
 import subprocess
 from pathlib import Path
 
-from federated_pytorch_test_tpu.analysis import LintEngine, Severity
+from federated_pytorch_test_tpu.analysis import LintEngine, Severity, contracts
 from federated_pytorch_test_tpu.analysis.flow import (ALL_RULES,
                                                       ANALYSIS_VERSION,
                                                       SUMMARY_VERSION,
@@ -40,31 +41,49 @@ def _lint_source(src, name):
     return LintEngine(ALL_RULES).lint_source(src, name)
 
 
-class TestSchemaAdditivity:
+class TestSchemaContract:
     def test_shipped_contract_modules_are_clean(self):
         result = LintEngine(ALL_RULES).lint_paths([str(SCHEMA), str(REPLAY)])
         assert result.failing(Severity.WARNING) == [], \
             "\n".join(f.render() for f in result.findings)
 
-    def test_field_removal_appended_to_real_ladder_fires_jg118(self):
-        """The acceptance mutation: a ``removed_fields`` entry grafted
-        onto the shipped VERSION_LADDER must break the gate."""
+    def test_emptied_required_core_fires_jg118(self):
+        """The acceptance mutation: a kind of the shipped schema whose
+        ``REQUIRED`` core is emptied must break the gate."""
         src = SCHEMA.read_text()
         mutated = src.replace(
-            '"added_fields": ()}',
-            '"added_fields": (), "removed_fields": ("loss",)}', 1)
-        assert mutated != src, "VERSION_LADDER spelling changed"
+            '"client": ("event", "schema", "run_id", "round_index", '
+            '"clients"),', '"client": (),', 1)
+        assert mutated != src, "REQUIRED spelling changed"
         result = _lint_source(mutated, str(SCHEMA))
         assert _ids(result) == {"JG118"}, \
             [f.render() for f in result.findings]
-        assert any("removed" in f.message for f in result.findings)
+        assert any("'client'" in f.message and "REQUIRED" in f.message
+                   for f in result.findings)
 
-    def test_nonmonotonic_version_fires_jg118(self):
+    def test_contract_tables_come_from_source(self, tmp_path, monkeypatch):
+        """A lint run over one file alone reads ``ADVISORY_FIELDS`` from
+        the shipped obs/schema.py (parsed, never imported): a word added
+        to a copy of that source is a word the rule sees, with no second
+        table anywhere to keep in step."""
+        emit = ("import time\n"
+                "def emit(sink, r):\n"
+                "    rec = {'event': 'round', 'round_index': r,\n"
+                "           'brand_new_seconds': time.time()}\n"
+                "    sink.round(rec)\n")
+        assert _ids(_lint_source(emit, "one_file.py")) == {"JG117"}
         src = SCHEMA.read_text()
-        mutated = src.replace('{"version": 2,', '{"version": 1,', 1)
-        assert mutated != src
-        result = _lint_source(mutated, str(SCHEMA))
-        assert "JG118" in _ids(result)
+        grown = src.replace('    "captured_utc",\n)',
+                            '    "captured_utc", "brand_new_seconds",\n)', 1)
+        assert grown != src, "ADVISORY_FIELDS spelling changed"
+        copy = tmp_path / "schema.py"
+        copy.write_text(grown)
+        monkeypatch.setattr(contracts, "TABLE_SOURCES", (copy, REPLAY))
+        assert "brand_new_seconds" in \
+            contracts.shipped_tables()["ADVISORY_FIELDS"]
+        assert _ids(_lint_source(emit, "one_file.py")) == set()
+        # control/replay.py's tables arrive the same way
+        assert "client" in contracts.shipped_tables()["REPLAY_CHECKERS"]
 
 
 class TestReplayCoverage:
@@ -242,7 +261,7 @@ class TestSummaryFacts:
         module, err = engine._parse(SCHEMA.read_text(), str(SCHEMA))
         assert err is None
         tables = extract_module_summary(module)["tables"]
-        assert {"VERSION_LADDER", "ADVISORY_FIELDS",
+        assert {"ADVISORY_FIELDS", "REQUIRED",
                 "RESERVED_META_NAMESPACES"} <= set(tables)
 
 

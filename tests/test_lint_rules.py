@@ -36,7 +36,7 @@ CASES = [
     ("jg115_jit_from_thread.py", "JG115"),
     ("jg116_lifecycle.py", "JG116"),
     ("jg117_entropy_into_record.py", "JG117"),
-    ("jg118_schema_ladder.py", "JG118"),
+    ("jg118_schema_contract.py", "JG118"),
     ("jg119_unordered_into_record.py", "JG119"),
     ("jg120_meta_contract.py", "JG120"),
     ("jg121_rogue_prng.py", "JG121"),

@@ -27,7 +27,6 @@ from federated_pytorch_test_tpu.models.base import (
     pairs,
 )
 from federated_pytorch_test_tpu.obs import (
-    Metrics,
     RunRecorder,
     SCHEMA_VERSION,
     SchemaError,
@@ -41,7 +40,16 @@ from federated_pytorch_test_tpu.obs.report import (
     record_ips,
     summarize,
 )
-from federated_pytorch_test_tpu.obs.sinks import JsonlSink, MemorySink
+from federated_pytorch_test_tpu.obs.schema import (
+    ADVISORY_FIELDS,
+    ENVELOPE_FIELDS,
+    FIELDS,
+)
+from federated_pytorch_test_tpu.obs.sinks import (
+    SINK_CHOICES,
+    JsonlSink,
+    MemorySink,
+)
 from federated_pytorch_test_tpu.train import (
     AdmmConsensus,
     BlockwiseFederatedTrainer,
@@ -96,32 +104,6 @@ def round_record(i=0, **kw):
 
 
 # ----------------------------------------------------------------------
-# metrics
-
-
-class TestMetrics:
-    def test_counter_gauge_timer(self):
-        m = Metrics()
-        m.counter("hits").inc()
-        m.counter("hits").inc(2)
-        m.gauge("depth").set(7)
-        with m.timer("step").time():
-            pass
-        m.timer("step").observe(1.5)
-        snap = m.snapshot()
-        assert snap["hits"] == 3
-        assert snap["depth"] == 7
-        assert snap["step_calls"] == 2
-        assert snap["step_seconds"] >= 1.5
-
-    def test_registry_rejects_kind_change(self):
-        m = Metrics()
-        m.counter("x")
-        with pytest.raises(TypeError):
-            m.gauge("x")
-
-
-# ----------------------------------------------------------------------
 # schema
 
 
@@ -173,6 +155,38 @@ class TestSchema:
         validate_record(round_record(loss=float("nan")))
 
 
+#: the fields PRs 24-28 added: (kinds, a valid value, an ill-typed one,
+#: advisory or core).  A new field is one more line here (and one in
+#: obs/schema.py: README "Observability", "how to add a field").
+DECLARED = {
+    "block_switch_seconds": (("round",), 0.03, "soon", True),
+    "gap_seconds": (("round",), 0.04, "soon", True),
+    "dispatch_seconds": (("round",), 0.002, "soon", True),
+    "block_switch_h2d_bytes": (("round",), 0, "none", True),
+    "tokens": (("round",), 65536, "many", False),
+    "block_kind": (("round",), "gdn", 1, False),
+    "moe_pairs_local": (("round",), 1024, 0.5, False),
+    "moe_load_max_over_mean": (("round",), 1.6, "high", False),
+    "moe_dropped": (("round",), 0, "none", False),
+    "gdn_scan_impl": (("round",), "pallas", 1, True),
+}
+
+
+@pytest.mark.parametrize("name", sorted(DECLARED))
+def test_declared_field(name):
+    kinds, good, bad, advisory = DECLARED[name]
+    assert FIELDS[name][0] == kinds
+    assert (name in ADVISORY_FIELDS) == advisory
+    assert name not in ENVELOPE_FIELDS
+    validate_record(round_record(**{name: good}))
+    with pytest.raises(SchemaError, match=name):
+        validate_record(round_record(**{name: bad}))
+    with pytest.raises(SchemaError, match="not valid"):
+        validate_record({"event": "summary", "schema": SCHEMA_VERSION,
+                         "run_id": "t" * 8, "status": "completed",
+                         "rounds": 1, name: good})
+
+
 # ----------------------------------------------------------------------
 # sinks
 
@@ -204,17 +218,14 @@ class TestSinks:
         with open(path) as f:
             assert [json.loads(ln)["round_index"] for ln in f] == [0, 1]
 
-    def test_csv_keeps_rounds_only_and_fixed_columns(self, tmp_path):
-        sinks, _ = make_sinks("csv", str(tmp_path), "r")
-        s = sinks[0]
-        s.emit({"event": "run_header", "schema": 1})
-        s.emit({"event": "round", "round_index": 0, "loss": 1.0})
-        s.emit({"event": "round", "round_index": 1, "loss": 0.5,
-                "surprise": 9})
-        s.close()
-        lines = (tmp_path / "r.csv").read_text().strip().splitlines()
-        assert lines[0] == "event,round_index,loss"
-        assert len(lines) == 3            # header + 2 rounds, no run_header
+    @pytest.mark.parametrize("gone", ["csv", "stdout"])
+    def test_removed_sinks_are_rejected(self, gone, tmp_path):
+        assert SINK_CHOICES == ("auto", "none", "jsonl", "memory")
+        with pytest.raises(ValueError, match="unknown obs sink") as e:
+            make_sinks("jsonl," + gone, str(tmp_path))
+        for choice in SINK_CHOICES:
+            assert choice in str(e.value)
+        assert not list(tmp_path.iterdir())
 
 
 # ----------------------------------------------------------------------
@@ -253,6 +264,45 @@ class TestRecorder:
         assert s["loss_first"] == 2.0 and s["loss_final"] == 0.0
         assert s["comm_overhead_frac"] == pytest.approx(0.2)
         assert s["images_per_sec"] == pytest.approx(192 / 1.5)
+
+    def test_summary_totals_without_metrics(self):
+        """The summary's totals, value for value what the recorder wrote
+        when obs/metrics.py kept them (numbers from a run of that tree)."""
+        rec = make_recorder("memory", None, run_name="x",
+                            engine="classifier", algorithm="fedavg")
+        rec.open(config={"K": 4})
+        rec.round(dict(round_index=0, round_seconds=0.5,
+                       stage_seconds=0.125, comm_seconds=0.0625, loss=2.0,
+                       bytes_on_wire=100, bytes_dense=400, images=64,
+                       guard_trips=1.0, fault_dropped=1, quarantined=2))
+        rec.round(dict(round_index=1, round_seconds=0.25,
+                       comm_seconds=0.0625, loss=1.5, bytes_on_wire=100,
+                       bytes_dense=400, images=64, guard_trips=0.5,
+                       fault_straggled=2, quarantined=1))
+        rec.round(dict(round_index=2, round_seconds=1, stage_seconds=0.25,
+                       loss=1.0, bytes_on_wire=50, bytes_dense=400,
+                       images=64, fault_corrupted=3))
+        rec.compile_event({"site": "s", "compile_seconds": 0.25})
+        s = rec.close()
+        for k in ("run_id", "time_unix", "total_seconds"):
+            del s[k]
+        want = {
+            "event": "summary", "schema": SCHEMA_VERSION,
+            "status": "completed", "rounds": 3,
+            "bytes_on_wire_total": 250, "bytes_dense_total": 1200,
+            "images_total": 192, "guard_trips_total": 1.5,
+            "fault_dropped_total": 1, "fault_straggled_total": 2,
+            "fault_corrupted_total": 3, "round_seconds_total": 1.75,
+            "stage_seconds_total": 0.375, "comm_seconds_total": 0.125,
+            "quarantined_last": 1, "loss_first": 2.0, "loss_final": 1.0,
+            "compile_events_total": 1, "compile_seconds_total": 0.25,
+            "rounds_per_sec": 1.7142857142857142,
+            "images_per_sec": 109.71428571428571,
+            "comm_overhead_frac": 0.07142857142857142,
+            "compression_savings_frac": 0.7916666666666666}
+        assert s == want and list(s) == list(want)
+        assert type(s["round_seconds_total"]) is float
+        assert type(s["fault_dropped_total"]) is int
 
     def test_round_index_must_increase(self):
         rec = make_recorder("memory", None, run_name="x", engine="e")
@@ -341,6 +391,77 @@ class TestReport:
             env=dict(os.environ, JAX_PLATFORMS="cpu"))
         assert r.returncode == 0, r.stderr
         assert "obs report selftest: OK" in r.stdout
+
+
+# ----------------------------------------------------------------------
+# every field an engine emits is declared
+
+
+def _classifier_stream(data):
+    t, _, _ = run_with_obs(data, AdmmConsensus())
+    return t.obs_recorder.memory
+
+
+def _vae_stream(data):
+    from federated_pytorch_test_tpu.models.vae import AutoEncoderCNN
+    from federated_pytorch_test_tpu.train.vae_engine import VAETrainer
+
+    t = VAETrainer(AutoEncoderCNN(), small_cfg(update_guard=True), data,
+                   FedAvg())
+    t.L = 1
+    t.run(log=lambda m: None)
+    return t.obs_recorder.memory
+
+
+def _cpc_stream(data):
+    from federated_pytorch_test_tpu.data.lofar import CPCDataSource
+    from federated_pytorch_test_tpu.train.cpc_engine import CPCTrainer
+
+    src = CPCDataSource(["a.h5", "b.h5"], ["0", "1"], batch_size=2, seed=7)
+    t = CPCTrainer(src, latent_dim=8, reduced_dim=4, lbfgs_history=3,
+                   lbfgs_max_iter=1, Niter=1)
+    t.run(Nloop=1, Nadmm=1, log=lambda m: None, obs_sinks="memory")
+    return t.obs_recorder.memory
+
+
+def _lm_stream(data):
+    from test_lm_engine import MOE, lm_trainer
+
+    t = lm_trainer([MOE], obs_sinks="memory")
+    t.run(log=lambda m: None)
+    return t.obs_recorder.memory
+
+
+STREAMS = {"classifier": _classifier_stream, "vae": _vae_stream,
+           "cpc": _cpc_stream, "lm": _lm_stream}
+#: the kinds every engine writes with its defaults and a memory sink
+EMITTED = ("run_header", "round", "client", "span", "compile", "summary")
+
+
+@pytest.fixture(scope="module")
+def stream_of(data):
+    made = {}
+
+    def get(engine):
+        if engine not in made:
+            made[engine] = STREAMS[engine](data)
+        return made[engine]
+
+    return get
+
+
+@pytest.mark.parametrize("kind", EMITTED)
+@pytest.mark.parametrize("engine", sorted(STREAMS))
+def test_every_emitted_field_is_declared(stream_of, engine, kind):
+    """``validate_record`` passes a field it does not know, so a field an
+    engine writes without a ``FIELDS`` line would go unnoticed: every key
+    of every record is declared for that record's kind."""
+    records = [r for r in stream_of(engine) if r["event"] == kind]
+    assert records, f"{engine} wrote no {kind!r} record"
+    for rec in records:
+        for name in rec:
+            assert name in FIELDS, f"{engine} {kind}: undeclared {name!r}"
+            assert kind in FIELDS[name][0], (engine, kind, name)
 
 
 # ----------------------------------------------------------------------

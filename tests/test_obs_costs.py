@@ -1,15 +1,11 @@
-"""Device-cost observability tests (obs/costs.py + obs/profile.py).
+"""Compile-ledger tests (obs/costs.py).
 
-Covers the PR 10 surface: the v1->v6 schema ladder and the new
-``compile`` record kind, the CostLedger compile-detection/AOT-analysis
-path on the CPU backend (availability probed — absent cost fields must
-be OMITTED, never zeroed), compile-span nesting under the PR 8
-Chrome-trace validator, bitwise math identity with the ledger on/off,
-the profile CLI exit-code contract, and the bytes-on-wire
-reconciliation math against hand-computed numbers.
+Covers the ``compile`` record kind, the CostLedger's compile detection
+and dispatch timer on the CPU backend (and that it asks jax for nothing
+more: no second lowering), compile-span nesting under the Chrome-trace
+validator, and bitwise math identity with the ledger on/off.
 """
 
-import json
 import os
 import time
 
@@ -34,19 +30,11 @@ from federated_pytorch_test_tpu.obs import (
     make_recorder,
     validate_record,
 )
-from federated_pytorch_test_tpu.obs.compare import _direction, load_source
 from federated_pytorch_test_tpu.obs.costs import (
-    AOT_MODES,
     CompileEvent,
     CostLedger,
     RoundCosts,
     round_cost_fields,
-)
-from federated_pytorch_test_tpu.obs.profile import (
-    collect,
-    main as profile_main,
-    profile_metrics,
-    selftest as profile_selftest,
 )
 from federated_pytorch_test_tpu.obs.report import read_records
 from federated_pytorch_test_tpu.obs.trace import (
@@ -120,7 +108,7 @@ def compile_record(**kw):
 
 
 # ----------------------------------------------------------------------
-# schema ladder v1 -> v6
+# the compile record kind and the round's compile fields
 
 
 class TestSchemaV6:
@@ -138,11 +126,7 @@ class TestSchemaV6:
     def test_compile_record_kind(self):
         validate_record(compile_record(
             engine="classifier", algorithm="fedavg", round_index=0,
-            trace_count=1, cache_hit=False, flops=1.0e9,
-            hlo_bytes_accessed=1.5e6, transcendentals=2.0e3,
-            argument_bytes=1024, output_bytes=512, temp_bytes=256,
-            generated_code_bytes=4096, peak_device_bytes=1792,
-            span_id="ab12", parent_span="cd34",
+            trace_count=1, span_id="ab12", parent_span="cd34",
             t_start=1.0, t_end=1.25))
 
     def test_compile_required_fields(self):
@@ -156,12 +140,12 @@ class TestSchemaV6:
                              "run_id": "t" * 8, "site": "x"})
 
     def test_compile_fields_typed(self):
-        with pytest.raises(SchemaError, match="cache_hit"):
-            validate_record(compile_record(cache_hit="yes"))
-        with pytest.raises(SchemaError, match="flops"):
-            validate_record(compile_record(flops="many"))
-        with pytest.raises(SchemaError, match="peak_device_bytes"):
-            validate_record(compile_record(peak_device_bytes=1.5))
+        with pytest.raises(SchemaError, match="site"):
+            validate_record(compile_record(site=3))
+        with pytest.raises(SchemaError, match="compile_seconds"):
+            validate_record(compile_record(compile_seconds="slow"))
+        with pytest.raises(SchemaError, match="trace_count"):
+            validate_record(compile_record(trace_count=1.5))
 
     def test_unknown_fields_pass_on_compile(self):
         # additive contract: a v7 writer's extra field must not break us
@@ -169,16 +153,15 @@ class TestSchemaV6:
 
     def test_round_cost_fields_additive(self):
         validate_record(round_record(
-            compile_seconds=0.5, cache_hit=True, flops_round=1.0e9,
-            hlo_bytes_accessed=2.0e6, peak_device_bytes=4096))
+            compile_seconds=0.5, dispatch_seconds=0.51))
 
     def test_cost_fields_event_gated(self):
         # site belongs to compile records only
         with pytest.raises(SchemaError, match="not valid"):
             validate_record(round_record(site="train_epoch[blk=0]"))
-        # flops (per-program) belongs to compile, not round
+        # trace_count (per-site) belongs to compile, not round
         with pytest.raises(SchemaError, match="not valid"):
-            validate_record(round_record(flops=1.0e9))
+            validate_record(round_record(trace_count=1))
 
     def test_summary_cost_totals(self):
         validate_record({"event": "summary", "schema": SCHEMA_VERSION,
@@ -186,7 +169,6 @@ class TestSchemaV6:
                          "rounds": 2, "time_unix": 1.0,
                          "compile_events_total": 3,
                          "compile_seconds_total": 0.42,
-                         "cache_hits_total": 1, "cache_misses_total": 2,
                          "mem_peak_bytes_watermark": 1 << 20,
                          "mem_final_vs_peak_bytes": 1 << 10})
 
@@ -198,75 +180,37 @@ class TestSchemaV6:
 class TestLedgerUnit:
     def test_round_cost_fields_windowing(self):
         ev_in = CompileEvent(site="a", seconds=0.2, t_start=10.2,
-                             t_end=10.4, trace_count=1, cache_hit=None)
+                             t_end=10.4, trace_count=1)
         ev_out = CompileEvent(site="b", seconds=0.3, t_start=11.5,
-                              t_end=11.8, trace_count=1, cache_hit=None)
-        costs = RoundCosts(events=(ev_in, ev_out), flops=0.0,
-                           bytes_accessed=0.0, peak_bytes=0)
+                              t_end=11.8, trace_count=1)
+        costs = RoundCosts(events=(ev_in, ev_out))
         fields = round_cost_fields(costs, t_start=10.0, seconds=1.0)
         # out-of-window event excluded; absent data omitted, not zeroed
         assert fields == {"compile_seconds": pytest.approx(0.2)}
 
-    def test_round_cost_fields_exec_accumulators(self):
-        costs = RoundCosts(events=(), flops=2.0e9, bytes_accessed=3.0e6,
-                           peak_bytes=4096)
-        fields = round_cost_fields(costs, t_start=0.0, seconds=1.0)
-        assert fields == {"flops_round": 2.0e9,
-                          "hlo_bytes_accessed": 3.0e6,
-                          "peak_device_bytes": 4096}
-        assert isinstance(fields["peak_device_bytes"], int)
-
     def test_round_cost_fields_dispatch_seconds(self):
-        # schema v15: written when the window held a dispatch, omitted
-        # (not zeroed) when it held none or the field was never set
-        costs = RoundCosts(events=(), flops=0.0, bytes_accessed=0.0,
-                           peak_bytes=0, dispatch_seconds=0.0125)
+        # written when the window held a dispatch, omitted (not zeroed)
+        # when it held none or the field was never set
+        costs = RoundCosts(events=(), dispatch_seconds=0.0125)
         assert round_cost_fields(costs, t_start=0.0, seconds=1.0) == {
             "dispatch_seconds": pytest.approx(0.0125)}
-        bare = RoundCosts(events=(), flops=0.0, bytes_accessed=0.0,
-                          peak_bytes=0)
+        bare = RoundCosts(events=())
         assert bare.dispatch_seconds == 0.0
         assert round_cost_fields(bare, t_start=0.0, seconds=1.0) == {}
         validate_record(round_record(dispatch_seconds=0.0125))
 
-    def test_event_record_omits_absent_fields(self):
+    def test_event_record_fields(self):
         ev = CompileEvent(site="s", seconds=0.1, t_start=0.0, t_end=0.1,
-                          trace_count=1, cache_hit=None, costs={})
-        rec = ev.record()
-        assert "cache_hit" not in rec and "flops" not in rec
-        ev2 = CompileEvent(site="s", seconds=0.1, t_start=0.0, t_end=0.1,
-                           trace_count=2, cache_hit=True,
-                           costs={"flops": 7.0})
-        rec2 = ev2.record(round_index=3)
-        assert rec2["cache_hit"] is True and rec2["flops"] == 7.0
-        assert rec2["round_index"] == 3 and rec2["trace_count"] == 2
-
-    def test_cache_classification(self, tmp_path):
-        led = CostLedger(aot_mode="off", cache_dir=str(tmp_path),
-                         fast_compile_s=0.15)
-        # empty dir, fast compile, no baseline delta -> heuristic hit
-        assert led._classify_cache(0.01) is True
-        # a fresh persisted entry across the compile -> genuine miss,
-        # regardless of speed
-        (tmp_path / "entry-0").write_bytes(b"x" * 64)
-        assert led._classify_cache(0.01) is False
-        # no new entry: fast -> hit, slow -> miss
-        assert led._classify_cache(0.01) is True
-        assert led._classify_cache(0.5) is False
-
-    def test_no_cache_dir_is_unattributable(self):
-        led = CostLedger(aot_mode="off", cache_dir="")
-        assert led._classify_cache(0.01) is None
-        assert led.cache_hit_rate() is None
+                          trace_count=2)
+        assert ev.record() == {"site": "s", "compile_seconds": 0.1,
+                               "t_start": 0.0, "t_end": 0.1,
+                               "trace_count": 2}
+        assert ev.record(round_index=3)["round_index"] == 3
+        validate_record(compile_record(**ev.record(round_index=3)))
 
 
 # ----------------------------------------------------------------------
 # ledger on real jit dispatches (CPU backend; availability probed)
-
-_COST_KEYS = {"flops", "hlo_bytes_accessed", "transcendentals",
-              "argument_bytes", "output_bytes", "temp_bytes",
-              "generated_code_bytes", "peak_device_bytes"}
-
 
 def _instrumented(led, site, fn):
     return led.instrument(jax.jit(led.mark(fn, site)), site)
@@ -274,7 +218,7 @@ def _instrumented(led, site, fn):
 
 class TestLedgerJit:
     def test_cold_compile_detected_once(self):
-        led = CostLedger(aot_mode="lowered", cache_dir="")
+        led = CostLedger()
         f = _instrumented(led, "tanh2", lambda x: jnp.tanh(x) * 2.0)
         x = jnp.ones((8, 8), jnp.float32)
         np.testing.assert_allclose(np.asarray(f(x)),
@@ -287,17 +231,37 @@ class TestLedgerJit:
         # warm dispatch: no new event
         f(x)
         assert len(led.all_events) == 1
-        # availability probed: whatever the backend produced is typed
-        # and nonzero-or-absent — never a zeroed placeholder
-        assert set(ev.costs) <= _COST_KEYS
-        for k, v in ev.costs.items():
-            assert isinstance(v, (int, float)) and v >= 0, (k, v)
-        rec = ev.record()
-        for k in _COST_KEYS - set(ev.costs):
-            assert k not in rec
+        assert led.totals() == {"compile_events": 1, "sites": 1,
+                                "compile_seconds": ev.seconds}
+
+    def test_a_compile_lowers_once(self):
+        """The ledger times the dispatch and counts the trace; it never
+        asks jax to lower (or compile) the program a second time."""
+
+        class CountsLower:
+            def __init__(self, jfn):
+                self.jfn, self.lowered = jfn, 0
+
+            def __call__(self, *args):
+                return self.jfn(*args)
+
+            def lower(self, *args, **kwargs):
+                self.lowered += 1
+                return self.jfn.lower(*args, **kwargs)
+
+        led = CostLedger()
+        jfn = CountsLower(jax.jit(led.mark(lambda x: x * x + 1.0, "sq")))
+        f = led.instrument(jfn, "sq")
+        f(jnp.ones((8,)))
+        f(jnp.ones((8,)))
+        assert jfn.lowered == 0
+        (ev,) = led.all_events
+        assert ev.seconds > 0 and ev.trace_count == 1
+        assert set(ev.record()) == {"site", "compile_seconds", "t_start",
+                                    "t_end", "trace_count"}
 
     def test_retrace_on_new_shape(self):
-        led = CostLedger(aot_mode="off", cache_dir="")
+        led = CostLedger()
         f = _instrumented(led, "s", lambda x: x + 1.0)
         f(jnp.ones((4,)))
         f(jnp.ones((5,)))
@@ -305,29 +269,22 @@ class TestLedgerJit:
         assert [e.trace_count for e in led.all_events] == [1, 2]
 
     def test_drain_resets_window(self):
-        led = CostLedger(aot_mode="lowered", cache_dir="")
+        led = CostLedger()
         f = _instrumented(led, "d", lambda x: x * x)
         f(jnp.ones((16,)))
         rc = led.drain()
         assert len(rc.events) == 1
-        if "flops" in rc.events[0].costs:
-            assert rc.flops == pytest.approx(rc.events[0].costs["flops"])
-        # drained: next window starts empty, exec accumulators reset
+        # drained: next window starts empty
         rc2 = led.drain()
-        assert rc2.events == () and rc2.flops == 0.0
-        # warm dispatches keep accumulating executed cost
+        assert rc2.events == () and rc2.dispatch_seconds == 0.0
+        # warm dispatches add no event; the run history keeps the first
         f(jnp.ones((16,)))
-        f(jnp.ones((16,)))
-        rc3 = led.drain()
-        if "flops" in led.all_events[0].costs:
-            assert rc3.flops == pytest.approx(
-                2 * led.all_events[0].costs["flops"])
+        assert led.drain().events == () and len(led.all_events) == 1
 
-    @pytest.mark.parametrize("aot_mode", ["off", "lowered"])
-    def test_dispatch_seconds_accumulates_and_resets(self, aot_mode):
-        """Every instrumented call's own timer adds up, compiling or not,
-        with or without a cost model; ``drain`` hands it out and resets."""
-        led = CostLedger(aot_mode=aot_mode, cache_dir="")
+    def test_dispatch_seconds_accumulates_and_resets(self):
+        """Every instrumented call's own timer adds up, compiling or not;
+        ``drain`` hands it out and resets."""
+        led = CostLedger()
         f = _instrumented(led, "a", lambda x: x * 3.0)
         g = _instrumented(led, "b", lambda x: x - 3.0)
         assert led.drain().dispatch_seconds == 0.0
@@ -353,36 +310,6 @@ class TestLedgerJit:
         fields = round_cost_fields(many, t_start=t0, seconds=wall)
         assert fields["dispatch_seconds"] == many.dispatch_seconds
 
-    def test_off_mode_records_timing_only(self):
-        led = CostLedger(aot_mode="off", cache_dir="")
-        f = _instrumented(led, "o", lambda x: x - 1.0)
-        f(jnp.ones((4,)))
-        ev = led.all_events[0]
-        assert ev.costs == {}
-        assert "flops" not in ev.record()
-        tot = led.totals()
-        assert tot["compile_events"] == 1 and tot["sites"] == 1
-        assert tot["cache_unknown"] == 1
-
-    def test_full_mode_memory_analysis(self):
-        led = CostLedger(aot_mode="full", cache_dir="")
-        f = _instrumented(led, "m", lambda x: jnp.dot(x, x))
-        f(jnp.ones((8, 8), jnp.float32))
-        ev = led.all_events[0]
-        # memory_analysis availability is backend-dependent: probe, and
-        # when present assert the derived peak identity
-        if "peak_device_bytes" in ev.costs:
-            parts = sum(ev.costs.get(k, 0) for k in
-                        ("argument_bytes", "output_bytes", "temp_bytes"))
-            assert ev.costs["peak_device_bytes"] == parts > 0
-        if "argument_bytes" in ev.costs:
-            assert ev.costs["argument_bytes"] >= 8 * 8 * 4
-
-    def test_aot_modes_constant(self):
-        assert AOT_MODES == ("off", "lowered", "full")
-        # bad mode falls back to the env default rather than raising
-        assert CostLedger(aot_mode="bogus").aot_mode in AOT_MODES
-
 
 # ----------------------------------------------------------------------
 # engine integration: one real FedAvg run, shared by the assertions
@@ -406,9 +333,7 @@ class TestEngineIntegration:
         assert t._ledger is not None  # default-on
         # the cold round(s) must show nonzero in-window compile seconds
         assert any(r.get("compile_seconds", 0) > 0 for r in hist)
-        # executed-cost fields ride along when the backend produced them
-        if any("flops" in e.costs for e in t._ledger.all_events):
-            assert any(r.get("flops_round", 0) > 0 for r in hist)
+        assert all(r["dispatch_seconds"] > 0 for r in hist)
 
     def test_compile_records_emitted_and_valid(self, cost_run):
         t, _, _, _ = cost_run
@@ -440,22 +365,6 @@ class TestEngineIntegration:
         cats = {e.get("cat") for e in trace["traceEvents"]}
         assert "compile" in cats
 
-    def test_profile_on_real_run(self, cost_run):
-        _, _, _, path = cost_run
-        a = collect(read_records(path))
-        assert a["compile_events"] > 0 and a["rounds"] > 0
-        # acceptance: attribution covers round wall-clock within 5%
-        assert a["attribution"]["coverage"] == pytest.approx(1.0,
-                                                             abs=0.05)
-        m = profile_metrics(read_records(path))
-        assert m["compile_seconds"] > 0
-
-    def test_compare_ingests_cost_metrics(self, cost_run):
-        _, _, _, path = cost_run
-        src = load_source(path)
-        assert "compile_seconds" in src["metrics"]
-        assert src["metrics"]["compile_seconds"] > 0
-
 
 class TestBitwiseIdentity:
     def test_ledger_and_obs_toggles_do_not_move_math(self, data):
@@ -475,57 +384,6 @@ class TestBitwiseIdentity:
                         jax.tree_util.tree_leaves(p_dark)):
             np.testing.assert_array_equal(a, b)
         assert [r["loss"] for r in h_on] == [r["loss"] for r in h_off]
-
-
-# ----------------------------------------------------------------------
-# profile CLI
-
-
-class TestProfileCLI:
-    def test_selftest_exit_0(self, capsys):
-        assert profile_main(["--selftest"]) == 0
-        assert "OK" in capsys.readouterr().out
-
-    def test_selftest_math(self):
-        assert "OK" in profile_selftest()
-
-    def test_missing_file_exit_1(self, tmp_path, capsys):
-        assert profile_main([str(tmp_path / "nope.jsonl")]) == 1
-        assert "error" in capsys.readouterr().err
-
-    def test_no_args_exit_2(self):
-        with pytest.raises(SystemExit) as e:
-            profile_main([])
-        assert e.value.code == 2
-
-    def test_report_and_json_on_real_run(self, cost_run, capsys):
-        _, _, _, path = cost_run
-        assert profile_main([path]) == 0
-        out = capsys.readouterr().out
-        assert "device-cost profile" in out and "attribution" in out
-        assert profile_main([path, "--json"]) == 0
-        parsed = json.loads(capsys.readouterr().out)
-        assert parsed["compile_events"] > 0
-
-    def test_reconciliation_hand_math(self):
-        # 2 rounds, mean predicted wire bytes (1000 + 3000) / 2 = 2000;
-        # comm site HLO bytes 5000 -> ratio 2.5
-        records = [
-            round_record(0, bytes_on_wire=1000, t_start=1.0),
-            round_record(1, bytes_on_wire=3000, t_start=2.0),
-            compile_record(site="comm[plain,blk=0]", trace_count=1,
-                           hlo_bytes_accessed=5000.0),
-            compile_record(site="train_epoch[blk=0]", trace_count=1,
-                           hlo_bytes_accessed=9.0e9),
-        ]
-        a = collect(records)
-        rows = {r["site"]: r for r in a["reconciliation"]}
-        # train sites never show up in the wire reconciliation
-        assert set(rows) == {"comm[plain,blk=0]"}
-        row = rows["comm[plain,blk=0]"]
-        assert row["predicted_wire_bytes"] == pytest.approx(2000.0)
-        assert row["ratio"] == pytest.approx(2.5)
-        assert row["fused"] is False
 
 
 # ----------------------------------------------------------------------
@@ -572,7 +430,7 @@ class TestRecorderCosts:
 
 
 # ----------------------------------------------------------------------
-# satellites: compile-cache knobs + compare directions
+# satellite: compile-cache knobs
 
 
 class TestCompileCacheSatellite:
@@ -649,12 +507,3 @@ class TestCompileCacheSatellite:
         with pytest.raises(SystemExit):
             build_parser(FederatedConfig(), "prog").parse_args(
                 ["--compile-cache-dir", "/x"])
-
-
-class TestCompareDirections:
-    @pytest.mark.parametrize("name,sign", [
-        ("compile_seconds", -1), ("compile_seconds_cold", -1),
-        ("peak_device_bytes", -1), ("utilization", +1),
-        ("cache_hit_rate", +1)])
-    def test_new_metric_directions(self, name, sign):
-        assert _direction(name) == sign

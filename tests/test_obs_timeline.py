@@ -1,4 +1,4 @@
-"""The host timeline outside the round windows (obs schema v15, v16).
+"""The host timeline outside the round windows.
 
 A round record spans ``[t_round, t_round + round_seconds]``.  The engine
 also stamps what the host does outside of it: ``block_switch`` (with its
@@ -7,7 +7,7 @@ ahead of each block visit's first round and ``round_tail`` behind every
 round, so that rounds, switches and tails tile the whole run, and three
 round fields carry the same seconds whether the recorder is on or off:
 ``block_switch_seconds``, ``gap_seconds``, ``dispatch_seconds``.  Beside
-the first (schema v16): ``block_switch_h2d_bytes``, the bytes that switch
+the first: ``block_switch_h2d_bytes``, the bytes that switch
 staged from host memory; the per-block state is made on the device, so
 only a stateful compressor's fresh rows are left to count.
 """
@@ -29,10 +29,6 @@ from federated_pytorch_test_tpu.models.base import (
 from federated_pytorch_test_tpu.obs import SCHEMA_VERSION, validate_record
 from federated_pytorch_test_tpu.obs import trace as obs_trace
 from federated_pytorch_test_tpu.obs.report import read_records
-from federated_pytorch_test_tpu.obs.schema import (
-    ADVISORY_FIELDS,
-    VERSION_LADDER,
-)
 from federated_pytorch_test_tpu.train import (
     AdmmConsensus,
     BlockwiseFederatedTrainer,
@@ -43,7 +39,6 @@ from federated_pytorch_test_tpu.train.rounds import BLOCK_SWITCH_PARTS
 
 K = 4
 BLOCKS, ROUNDS = 2, 2           # two block visits x two rounds each
-FIELDS = ("block_switch_seconds", "gap_seconds", "dispatch_seconds")
 
 
 class TinyNet(BlockModule):
@@ -374,37 +369,3 @@ def test_resume_inside_a_block_stamps_a_switch_too(data, tmp_path, algo):
     # only the fresh switches made block state: block 0's came from disk
     made = [k for k in t2._fn_cache if k[0] == "fresh"]
     assert len(made) == BLOCKS - 1
-
-
-def test_schema_v15_declares_the_fields():
-    assert SCHEMA_VERSION >= 15
-    rung = next(r for r in VERSION_LADDER if r["version"] == 15)
-    assert set(rung["added_fields"]) == set(FIELDS)
-    assert rung["added_kinds"] == ()
-    for f in FIELDS:
-        assert f in ADVISORY_FIELDS
-    validate_record({"event": "round", "schema": 15, "run_id": "r",
-                     "engine": "classifier", "round_index": 0,
-                     "round_seconds": 0.5, "loss": 1.0,
-                     "block_switch_seconds": 0.03, "gap_seconds": 0.04,
-                     "dispatch_seconds": 0.002})
-    with pytest.raises(ValueError):
-        validate_record({"event": "round", "schema": 15, "run_id": "r",
-                         "engine": "classifier", "round_index": 0,
-                         "round_seconds": 0.5, "loss": 1.0,
-                         "gap_seconds": "soon"})
-
-
-def test_schema_v16_declares_h2d_bytes():
-    assert SCHEMA_VERSION >= 16
-    rung = next(r for r in VERSION_LADDER if r["version"] == 16)
-    assert rung["added_fields"] == ("block_switch_h2d_bytes",)
-    assert rung["added_kinds"] == ()
-    assert "block_switch_h2d_bytes" in ADVISORY_FIELDS
-    base = {"event": "round", "schema": 16, "run_id": "r",
-            "engine": "classifier", "round_index": 0,
-            "round_seconds": 0.5, "loss": 1.0,
-            "block_switch_seconds": 0.03}
-    validate_record(dict(base, block_switch_h2d_bytes=0))
-    with pytest.raises(ValueError):
-        validate_record(dict(base, block_switch_h2d_bytes="none"))

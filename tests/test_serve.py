@@ -122,11 +122,9 @@ def param_leaves(state):
 
 
 def det_view(rec):
-    # wall-clock and compile/cache-attribution fields legitimately
-    # differ between processes
+    # wall-clock fields legitimately differ between processes
     return {k: v for k, v in rec.items()
-            if isinstance(v, (int, float)) and not k.endswith("_seconds")
-            and k not in ("cache_hit", "peak_device_bytes")}
+            if isinstance(v, (int, float)) and not k.endswith("_seconds")}
 
 
 def pure_fields(rec):
