@@ -47,6 +47,10 @@ import jax.numpy as jnp
 
 from federated_pytorch_test_tpu.models.base import BlockModule
 from federated_pytorch_test_tpu.ops import moe as moelib
+from federated_pytorch_test_tpu.ops.flash_attention import (
+    causal_attention,
+    plan as attn_plan,
+)
 from federated_pytorch_test_tpu.ops.gated_delta import (
     gated_delta_chunked,
     plan as gdn_scan_plan,
@@ -178,6 +182,16 @@ class Qwen3Next(BlockModule):
             self.chunk, self.linear_key_head_dim,
             self.linear_value_head_dim, self.dtype)["impl"]
 
+    def attn_impl(self, tokens: int) -> str:
+        """What runs the attention core (scores, mask, softmax, ``a v``)
+        for a sequence of ``tokens`` in a gated-attention layer here
+        ("pallas" | "pallas_interpret" | "xla":
+        ``ops/flash_attention.py:plan``)."""
+        return attn_plan(
+            tokens, self.num_key_value_heads,
+            self.num_attention_heads // self.num_key_value_heads,
+            self.head_dim, self.dtype)["impl"]
+
     def _spec(self, name: str):
         H, s = self.hidden_size, _normal(self.init_scale)
         if name == "embed":
@@ -264,27 +278,9 @@ def gated_attention(cfg: Qwen3Next, p, x):
     cos, sin = rope_tables(T, int(d * cfg.partial_rotary_factor),
                            cfg.rope_theta)
     q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
-    rep = nq // nkv
-    q = q.reshape(T, nkv, rep, d) * (1.0 / math.sqrt(d))
-    kc, vc = _op(k, cfg.dtype), _op(v, cfg.dtype)
-    bq = min(cfg.attn_block, T)
-    pad = (-T) % bq
-    qp = jnp.pad(q, ((0, pad), (0, 0), (0, 0), (0, 0))).reshape(
-        -1, bq, nkv, rep, d)
-    pos_k = jnp.arange(T)
-
-    @jax.checkpoint
-    def block(args):
-        qb, start = args                       # [bq, nkv, rep, d]
-        s = jnp.einsum("qgrd,kgd->grqk", _op(qb, cfg.dtype), kc,
-                       preferred_element_type=_F32)
-        seen = (start + jnp.arange(bq))[:, None] >= pos_k[None, :]
-        a = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
-        return jnp.einsum("grqk,kgd->qgrd", _op(a, cfg.dtype), vc,
-                          preferred_element_type=_F32)
-
-    starts = jnp.arange(qp.shape[0]) * bq
-    o = jax.lax.map(block, (qp, starts)).reshape(-1, nq, d)[:T]
+    q = q.reshape(T, nkv, nq // nkv, d) * (1.0 / math.sqrt(d))
+    o = causal_attention(q, k, v, dtype=cfg.dtype,
+                         block=cfg.attn_block).reshape(T, nq, d)
     o = o * jax.nn.sigmoid(gate)
     return _mm(cfg, o.reshape(T, nq * d), p["o_proj"])
 

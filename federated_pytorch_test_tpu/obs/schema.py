@@ -185,6 +185,8 @@ FIELDS: Dict[str, Any] = {
     # plan): pallas | pallas_interpret | xla.  Names the machine's path,
     # not the trajectory, hence advisory
     "gdn_scan_impl": (("round",), _STR),
+    # the same for the attention core (ops/flash_attention.py: plan)
+    "attn_impl": (("round",), _STR),
     # fault / guard counters
     "guard_trips":  (("round",), _NUM),
     "guard_norm_mean": (("round",), _NUM),
@@ -358,8 +360,9 @@ ADVISORY_FIELDS = (
     # host timeline outside the round window
     "block_switch_seconds", "gap_seconds", "dispatch_seconds",
     "block_switch_h2d_bytes",
-    # which implementation this backend took for the recurrence
-    "gdn_scan_impl",
+    # which implementation this backend took for the recurrence and for
+    # the attention core
+    "gdn_scan_impl", "attn_impl",
     # serving-plane latency/throughput telemetry
     "serve_p50_ms", "serve_p99_ms", "serve_qps", "swap_gap_seconds",
     "serve_accuracy", "drift_score", "forced_refresh",

@@ -12,6 +12,11 @@ so every op runs identically on CPU/interpret mode.  Currently:
     its recurrence over the chunks is a forward and a backward kernel
     under one ``custom_vjp`` that keep the state ``S`` in VMEM
     (``gated_delta.force_gdn_scan_impl`` for tests).
+  * ``flash_attention.causal_attention`` — causal softmax attention with
+    grouped query heads, blockwise with an online softmax: a forward and
+    a backward kernel under one ``custom_vjp`` that keep the score tiles
+    in VMEM and skip the masked half
+    (``flash_attention.force_attn_impl`` for tests).
 """
 
 from federated_pytorch_test_tpu.ops.infonce import (  # noqa: F401

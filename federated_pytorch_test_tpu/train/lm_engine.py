@@ -8,8 +8,9 @@ sequence.  The model's routing counts ride in the engine's per-client
 non-parameter state (``ClientState.batch_stats``), summed over the steps,
 and ``round_fields`` turns them into the round record's ``tokens``,
 ``block_kind``, ``moe_pairs_local``, ``moe_load_max_over_mean`` and
-``moe_dropped``, and adds ``gdn_scan_impl``: which implementation of the
-delta rule's recurrence the model's shapes take on this backend.
+``moe_dropped``, and adds ``gdn_scan_impl`` and ``attn_impl``: which
+implementation of the delta rule's recurrence and of the attention core
+the model's shapes take on this backend.
 """
 
 from __future__ import annotations
@@ -126,6 +127,8 @@ class LMTrainer(BlockwiseFederatedTrainer):
                 "moe_dropped": int(d["moe_dropped"]),
                 "moe_load_max_over_mean": d["moe_load_sum"] / steps,
                 "gdn_scan_impl": self.model.gdn_scan_impl(
+                    self.data.tokens_per_sample),
+                "attn_impl": self.model.attn_impl(
                     self.data.tokens_per_sample)}
 
     def _block_index(self, ci: int) -> int:

@@ -3,7 +3,10 @@
 token-by-token recurrence and against the ``lax.scan`` path, the backward
 kernel line by line against ``jax.vjp`` of the XLA step, the rule that
 picks the path, the round field that reports it, and a compile of both
-kernels at the published widths for a described v5e.
+kernels at the published widths for a described v5e, and of the attention
+kernel pair (``ops/flash_attention.py``) beside them: one file holds the
+topology fixture, because only one test process may load the TPU's
+library.
 """
 
 import functools
@@ -21,6 +24,7 @@ if REPO not in sys.path:
 
 from federated_pytorch_test_tpu.data.tokens import FederatedTokens  # noqa: E402
 from federated_pytorch_test_tpu.models import get_model  # noqa: E402
+from federated_pytorch_test_tpu.ops import flash_attention as fa  # noqa: E402
 from federated_pytorch_test_tpu.ops import gated_delta as gd  # noqa: E402
 from federated_pytorch_test_tpu.train import (  # noqa: E402
     FedAvg,
@@ -217,24 +221,26 @@ def test_plan_takes_the_most_heads_that_divide_and_fit(H, heads):
 # ----------------------------------------------------------------------
 # the round field
 # ----------------------------------------------------------------------
-def lm_trainer():
+def lm_trainer(block=1, seq_len=24, **widths):
     """Two layers (GDN, attention) at tiny widths but for the GDN heads,
-    which are as wide as the kernels ask; the GDN block is active."""
-    model = get_model(
-        "qwen3_next", hidden_size=32, num_attention_heads=2,
+    which are as wide as the kernels ask; the GDN block is active.
+    (``tests/test_flash_attention_kernel.py`` asks for wide attention
+    heads and the attention block instead.)"""
+    model = get_model("qwen3_next", **{**dict(
+        hidden_size=32, num_attention_heads=2,
         num_key_value_heads=1, head_dim=16, linear_num_key_heads=1,
         linear_num_value_heads=2, linear_key_head_dim=D,
         linear_value_head_dim=D, num_experts=8, num_experts_per_tok=2,
         moe_intermediate_size=16, shared_expert_intermediate_size=16,
         layers=2, full_attention_interval=2, experts_held=4, vocab_rows=64,
-        chunk=16, attn_block=16, pair_rows_factor=8.0, dtype=F32)
-    data = FederatedTokens(K=2, batch=2, samples_per_client=2, seq_len=24,
-                           vocab=64, seed=3, head=16)
+        chunk=16, attn_block=16, pair_rows_factor=8.0, dtype=F32), **widths})
+    data = FederatedTokens(K=2, batch=2, samples_per_client=2,
+                           seq_len=seq_len, vocab=64, seed=3, head=16)
     cfg = FederatedConfig(K=2, Nloop=1, Nepoch=1, Nadmm=2, default_batch=2,
                           check_results=False, lr=1e-3, num_devices=1,
                           save_model=False)
     t = LMTrainer(model, cfg, data, FedAvg())
-    t.block_ids, t.L = [t.block_ids[1]], 1
+    t.block_ids, t.L = [t.block_ids[block]], 1
     return t
 
 
@@ -288,12 +294,25 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+def compiled_for(f, *ops):
+    """The text of ``f`` compiled for the described chip."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without the chip
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return jax.jit(f).lower(*ops).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+
+
 @pytest.mark.parametrize("what", ["forward", "forward_and_backward"])
 def test_kernels_compile_for_a_v5e_at_the_published_widths(one_chip, what):
     """Mosaic takes both kernels at 32 heads of 128, 64 chunks of 64 and
     the heads per step that ``plan`` picks; interpret mode cannot tell."""
-    from jax.experimental.compilation_cache import compilation_cache
-
     H, N = 32, 64
     with gd.force_gdn_scan_impl("pallas"):
         heads = gd.plan(H, N, CHUNK, D, D, BF16)["heads"]
@@ -305,13 +324,26 @@ def test_kernels_compile_for_a_v5e_at_the_published_widths(one_chip, what):
     if what != "forward":
         f = jax.grad(lambda *a, f=f: jnp.sum(f(*a)), argnums=(0, 1, 2, 3, 4,
                                                               5))
-    # a compile for a described chip is written to the persistent cache
-    # but cannot be read back without the chip
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        text = jax.jit(f).lower(*ops).compile().as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", True)
-        compilation_cache.reset_cache()
+    text = compiled_for(f, *ops)
+    assert text.count("tpu_custom_call") == (1 if what == "forward" else 2)
+
+
+@pytest.mark.parametrize("what", ["forward", "forward_and_backward"])
+def test_attention_kernels_compile_for_a_v5e_at_the_published_widths(
+        one_chip, what):
+    """The attention pair at 16 / 2 heads of 256 over 4,096 tokens with
+    ``plan``'s blocks: the whole key/value head and its float32 ``dk``,
+    ``dv`` in VMEM, a transposed-operand product, a dynamic trip count."""
+    T, n_kv, rep, d = 4096, 2, 8, 256
+    sh = lambda *s: jax.ShapeDtypeStruct(s, F32, sharding=one_chip)
+    ops = (sh(T, n_kv, rep, d), sh(T, n_kv, d), sh(T, n_kv, d))
+
+    def f(*a):
+        with fa.force_attn_impl("pallas"):
+            assert fa.plan(T, n_kv, rep, d, BF16)["impl"] == "pallas"
+            return fa.causal_attention(*a, dtype=BF16)
+
+    if what != "forward":
+        f = jax.grad(lambda *a, f=f: jnp.sum(f(*a)), argnums=(0, 1, 2))
+    text = compiled_for(f, *ops)
     assert text.count("tpu_custom_call") == (1 if what == "forward" else 2)

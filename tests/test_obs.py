@@ -169,6 +169,7 @@ DECLARED = {
     "moe_load_max_over_mean": (("round",), 1.6, "high", False),
     "moe_dropped": (("round",), 0, "none", False),
     "gdn_scan_impl": (("round",), "pallas", 1, True),
+    "attn_impl": (("round",), "xla", 0, True),
 }
 
 

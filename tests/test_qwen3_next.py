@@ -22,6 +22,9 @@ from federated_pytorch_test_tpu.data.tokens import FederatedTokens  # noqa: E402
 from federated_pytorch_test_tpu.models import get_model  # noqa: E402
 from federated_pytorch_test_tpu.models import qwen3_next as qn  # noqa: E402
 from federated_pytorch_test_tpu.ops import moe as moelib  # noqa: E402
+from federated_pytorch_test_tpu.ops.flash_attention import (  # noqa: E402
+    force_attn_impl,
+)
 from federated_pytorch_test_tpu.ops.gated_delta import (  # noqa: E402
     gated_delta_chunked,
     gated_delta_stepwise,
@@ -130,6 +133,41 @@ def test_published_widths_give_the_issue_s_parameter_counts():
     assert count(shapes["layer1_moe"]) == 104_861_696
     assert count(shapes["embed"]) + count(shapes["head"]) == 77_793_280
     assert count(shapes) == 625_667_136
+
+
+# ----------------------------------------------------------------------
+# the attention core as a kernel pair (interpret mode) against the XLA path
+# ----------------------------------------------------------------------
+@pytest.mark.parametrize("block", [7], ids=["attention_block"])
+def test_model_through_the_attention_kernels_matches_the_xla_path(block):
+    """The file's model has heads of 16, which ``plan()`` sends to the XLA
+    path: this one has heads of 128 and a sequence of three key blocks."""
+    model = tiny_model(head_dim=128, attn_block=128)
+    ids = jax.random.randint(jax.random.PRNGKey(1), (1, 385), 0, 64)
+    x, y = ids[:, :-1], ids[:, 1:]
+    params, _ = model.init_variables(jax.random.PRNGKey(0), x[:, :8])
+    lo, hi = model.train_order_block_ids()[block]
+    paths = model.param_order()[lo:hi + 1]
+
+    def run(impl):
+        with force_attn_impl(impl), jax.default_matmul_precision("highest"):
+            assert model.attn_impl(384) == impl
+            logits, _ = model.apply({"params": params}, x)
+            grads = jax.grad(lambda p: qn.next_token_loss(
+                model.apply({"params": p}, x)[0], y))(params)
+        return logits, [get_by_path(grads, path) for path in paths]
+
+    (logits, grads), (want, want_grads) = run("pallas_interpret"), run("xla")
+    assert rel(logits, want) < 2e-5
+    for path, g, w in zip(paths, grads, want_grads):
+        assert rel(g, w) < 2e-4, path
+
+
+def test_heads_of_16_take_the_xla_path_even_when_the_kernel_is_forced():
+    with force_attn_impl("pallas_interpret"):
+        assert tiny_model().attn_impl(256) == "xla"
+        assert tiny_model(head_dim=128).attn_impl(256) == "pallas_interpret"
+        assert tiny_model(head_dim=128).attn_impl(T) == "xla"   # 40 tokens
 
 
 # ----------------------------------------------------------------------
