@@ -15,6 +15,7 @@ from federated_pytorch_test_tpu.models.cpc import (  # noqa: F401
     PredictorCNN,
 )
 from federated_pytorch_test_tpu.models.qwen3_next import Qwen3Next  # noqa: F401
+from federated_pytorch_test_tpu.models.glm4_moe_lite import Glm4MoeLite  # noqa: F401
 
 MODEL_REGISTRY = {
     "net": Net,
@@ -28,6 +29,7 @@ MODEL_REGISTRY = {
     "cpc_contextgen": ContextgenCNN,
     "cpc_predictor": PredictorCNN,
     "qwen3_next": Qwen3Next,
+    "glm4_moe_lite": Glm4MoeLite,
 }
 
 

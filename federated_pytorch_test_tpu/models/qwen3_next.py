@@ -16,6 +16,10 @@ the gates, the state decay and the loss are float32; each sub-layer is
 rematerialised in the backward pass (``jax.checkpoint``), the mixers
 sequence by sequence.
 
+What this decoder shares with ``models/glm4_moe_lite.py`` (a block's
+leaves, the rotary tables, the loss helpers, the held experts' sort,
+grouped products and scatter) lies in ``models/decoder.py``.
+
 The expert layer is told which experts it holds (``experts_held`` of
 ``n_experts`` from ``ep_rank * experts_held``): it routes over all of
 them and adds only its own experts' terms (``ops/moe.py``).
@@ -39,13 +43,27 @@ a single rank that cannot, leaves the router as it is.
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, List, Tuple
+from typing import Any, Dict, List
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
 from federated_pytorch_test_tpu.models.base import BlockModule
+from federated_pytorch_test_tpu.models.decoder import (  # noqa: F401
+    _F32,
+    _ONES,
+    _ZEROS,
+    _Leaves,
+    _mm,
+    _normal,
+    apply_rope,
+    held_experts,
+    next_token_loss,
+    rope_tables,
+    sequence_loss,
+    weighted_mean,
+)
 from federated_pytorch_test_tpu.ops import moe as moelib
 from federated_pytorch_test_tpu.ops.flash_attention import (
     causal_attention,
@@ -55,14 +73,6 @@ from federated_pytorch_test_tpu.ops.gated_delta import (
     gated_delta_chunked,
     plan as gdn_scan_plan,
 )
-
-_F32 = jnp.float32
-_op = moelib.operand
-
-
-def _normal(scale):
-    return lambda key, shape, dtype=_F32: scale * jax.random.normal(
-        key, shape, dtype)
 
 
 def _a_log(key, shape, dtype=_F32):
@@ -76,45 +86,11 @@ def _conv_taps(key, shape, dtype=_F32):
     return jax.random.uniform(key, shape, dtype, -bound, bound)
 
 
-_ZEROS, _ONES = nn.initializers.zeros, nn.initializers.ones
-
-
-class _Leaves(nn.Module):
-    """The parameters of one block: ``((name, shape, init), ...)``."""
-
-    spec: Tuple[Tuple[str, Tuple[int, ...], Any], ...]
-
-    @nn.compact
-    def __call__(self) -> Dict[str, jnp.ndarray]:
-        return {n: self.param(n, init, shape, _F32)
-                for n, shape, init in self.spec}
-
-
 def rms_norm(x, w, eps):
     """Zero-centred weight: ``(1 + w)``."""
     x = x.astype(_F32)
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) \
         * (1.0 + w)
-
-
-def rope_tables(T: int, rot: int, theta: float):
-    """``cos, sin [T, rot]`` (rotate-half layout: the ``rot / 2``
-    frequencies repeated)."""
-    inv = 1.0 / theta ** (jnp.arange(0, rot, 2, dtype=_F32) / rot)
-    ang = jnp.arange(T, dtype=_F32)[:, None] * inv[None, :]
-    ang = jnp.concatenate([ang, ang], -1)
-    return jnp.cos(ang), jnp.sin(ang)
-
-
-def apply_rope(x, cos, sin):
-    """``x [..., T, heads, d]``: rotate the first ``cos.shape[-1]``
-    dimensions of each head."""
-    rot = cos.shape[-1]
-    xr, rest = x[..., :rot], x[..., rot:]
-    half = rot // 2
-    turned = jnp.concatenate([-xr[..., half:], xr[..., :half]], -1)
-    c, s = cos[:, None, :], sin[:, None, :]
-    return jnp.concatenate([xr * c + turned * s, rest], -1)
 
 
 class Qwen3Next(BlockModule):
@@ -192,6 +168,12 @@ class Qwen3Next(BlockModule):
             self.num_attention_heads // self.num_key_value_heads,
             self.head_dim, self.dtype)["impl"]
 
+    def impl_fields(self, tokens: int) -> Dict[str, str]:
+        """The round record's fields that name this backend's
+        implementations for sequences of ``tokens``."""
+        return {"gdn_scan_impl": self.gdn_scan_impl(tokens),
+                "attn_impl": self.attn_impl(tokens)}
+
     def _spec(self, name: str):
         H, s = self.hidden_size, _normal(self.init_scale)
         if name == "embed":
@@ -260,11 +242,6 @@ class Qwen3Next(BlockModule):
         return forward(self, p, ids, labels)
 
 
-def _mm(cfg, x, w):
-    return jnp.dot(_op(x, cfg.dtype), _op(w, cfg.dtype),
-                   preferred_element_type=_F32)
-
-
 def gated_attention(cfg: Qwen3Next, p, x):
     """``x [T, H]`` (already normed) -> ``[T, H]``."""
     T = x.shape[0]
@@ -325,24 +302,11 @@ def gated_delta_net(cfg: Qwen3Next, p, x):
 
 def expert_layer(cfg: Qwen3Next, p, x):
     """``x [T, H]`` (already normed) -> ``([T, H], routing)``."""
-    T, H = x.shape
-    E, k = cfg.experts_held, cfg.num_experts_per_tok
-    rows = int(math.ceil(cfg.pair_rows_factor * T * k * E / cfg.num_experts
-                         / 8.0)) * 8
-    rows = min(rows, T * min(k, E))
     with jax.named_scope("moe_route"):
         logits = jnp.dot(x, p["router"], precision=jax.lax.Precision.HIGHEST)
-        w, e = moelib.router_weights(logits, k, cfg.norm_topk_prob)
-        r = moelib.route_local(w, e, cfg.ep_rank * E, E, rows)
-        xs = x[r.token]
-    with jax.named_scope("moe_experts"):
-        gm = lambda a, wt: moelib.grouped_matmul(a, wt, r.group_sizes,
-                                                 cfg.dtype)
-        h = jax.nn.silu(gm(xs, p["experts_gate"])) * gm(xs, p["experts_up"])
-        ys = gm(h, p["experts_down"])
-    with jax.named_scope("moe_route"):
-        ys = jnp.where(r.weight[:, None] > 0, ys * r.weight[:, None], 0.0)
-        y = jnp.zeros((T, H), _F32).at[r.token].add(ys)
+        w, e = moelib.router_weights(logits, cfg.num_experts_per_tok,
+                                     cfg.norm_topk_prob)
+    y, r = held_experts(cfg, p, x, w, e, cfg.num_experts)
     with jax.named_scope("moe_shared"):
         hs = jax.nn.silu(_mm(cfg, x, p["shared_gate_proj"])) \
             * _mm(cfg, x, p["shared_up"])
@@ -400,28 +364,3 @@ def forward(cfg: Qwen3Next, p, ids, labels=None):
         return head(x), aux
     one = jax.checkpoint(lambda a: sequence_loss(head(a[0]), a[1]))
     return jax.lax.map(one, (x, labels)), aux
-
-
-def sequence_loss(logits, labels):
-    """Mean cross-entropy of ``logits [..., T, V]`` against ``labels
-    [..., T]`` over ``T``, in float32."""
-    with jax.named_scope("lm_head_loss"):
-        logits = logits.astype(_F32)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        picked = jnp.take_along_axis(logits, labels[..., None], -1)[..., 0]
-        return jnp.mean(lse - picked, axis=-1)
-
-
-def weighted_mean(per_sequence, weights=None):
-    """Mean over the sequences; ``weights [B]`` (0/1) leaves pad
-    sequences out."""
-    if weights is None:
-        return jnp.mean(per_sequence)
-    return jnp.sum(per_sequence * weights) / jnp.maximum(jnp.sum(weights),
-                                                         1.0)
-
-
-def next_token_loss(logits, labels, weights=None):
-    """Mean cross-entropy of ``logits [B, T, V]`` against ``labels [B, T]``
-    in float32; ``weights [B]`` (0/1) leaves pad sequences out."""
-    return weighted_mean(sequence_loss(logits, labels), weights)

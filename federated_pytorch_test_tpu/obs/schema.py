@@ -171,16 +171,21 @@ FIELDS: Dict[str, Any] = {
     # the language-model trainer (train/lm_engine.py: round_fields), pure
     # functions of (seed, config, round coordinates).  tokens: consumed by
     # the round's local steps, over the clients.  block_kind: embed | gdn
-    # | attn | moe | head.  moe_pairs_local: token-expert pairs that hit
-    # an expert this chip holds, over layers, steps, clients.
+    # | attn | mla | mlp | moe | head | mtp_mixer | mtp_moe.
+    # moe_pairs_local: token-expert pairs that hit an expert this chip
+    # holds, over layers, steps, clients.
     # moe_load_max_over_mean: most loaded held expert over the held mean,
     # worst layer, mean over steps.  moe_dropped: pairs that found no row
-    # in the sorted pair buffer (ops/moe.py); 0, or `correct` fails
+    # in the sorted pair buffer (ops/moe.py); 0, or `correct` fails.
+    # mtp_loss: the multi-token-prediction term of the loss, unweighted,
+    # summed over the round's steps and clients as `loss` is (0.0 where
+    # the model has no such layer)
     "tokens":       (("round",), _INT),
     "block_kind":   (("round",), _STR),
     "moe_pairs_local": (("round",), _INT),
     "moe_load_max_over_mean": (("round",), _NUM),
     "moe_dropped":  (("round",), _INT),
+    "mtp_loss":     (("round",), _NUM),
     # what ran the delta rule's chunk recurrence (ops/gated_delta.py:
     # plan): pallas | pallas_interpret | xla.  Names the machine's path,
     # not the trajectory, hence advisory

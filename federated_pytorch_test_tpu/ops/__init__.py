@@ -17,6 +17,10 @@ so every op runs identically on CPU/interpret mode.  Currently:
     a backward kernel under one ``custom_vjp`` that keep the score tiles
     in VMEM and skip the masked half
     (``flash_attention.force_attn_impl`` for tests).
+  * ``moe`` — no kernel of its own: the two router rules
+    (``router_weights``, ``sigmoid_router_weights``) and the held
+    experts' sorted pairs and grouped products (``jax.lax.ragged_dot``,
+    which the TPU compiler turns into a grouped Mosaic kernel).
 """
 
 from federated_pytorch_test_tpu.ops.infonce import (  # noqa: F401

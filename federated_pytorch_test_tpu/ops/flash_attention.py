@@ -55,10 +55,6 @@ _BLOCK_K = 256              # keys a loop step visits, at most
 # ask Mosaic for (the v5e has 128 MiB of VMEM, 16 MiB of it by default)
 _VMEM_BUDGET = 64 * 2**20
 _MASKED = -1e30             # a score above the diagonal: exp() gives 0.0
-# the program's scope for the mixer (models/qwen3_next.py opens it around
-# the forward call; a custom_vjp's backward rule is traced outside it, so
-# the rule opens it again and a trace still finds the kernel)
-_SCOPE = "gated_attn"
 
 # None = by the backend; "pallas_interpret" stands in for a TPU in tests
 _FORCE_IMPL = None
@@ -121,11 +117,15 @@ def _grad_vmem_bytes(T: int, rows: int, block_k: int, d: int, b: int) -> int:
     return 2 * (head + step) + 5 * block_k * rows * 4
 
 
-def causal_attention(q, k, v, *, dtype=jnp.bfloat16, block: int = 512):
+def causal_attention(q, k, v, *, dtype=jnp.bfloat16, block: int = 512,
+                     scope: str = "gated_attn"):
     """``q [T, n_kv, rep, d]`` (scaled, normed, rotated), ``k, v [T, n_kv,
     d]`` -> ``o [T, n_kv, rep, d]`` float32: query head ``(g, r)`` attends
     to key/value head ``g`` at its own and earlier positions.  ``block``
-    is the XLA path's query block."""
+    is the XLA path's query block.  ``scope`` is the caller's
+    ``jax.named_scope`` around this call: a custom_vjp's backward rule is
+    traced outside it, so the rule opens it again and a trace still finds
+    the backward kernel under the mixer's name."""
     T, n_kv, rep, d = q.shape
     p = plan(T, n_kv, rep, d, dtype)
     kc, vc = operand(k, dtype), operand(v, dtype)
@@ -135,8 +135,8 @@ def causal_attention(q, k, v, *, dtype=jnp.bfloat16, block: int = 512):
     # a group's heads side by side as the rows of one query block
     qr = operand(q, dtype).reshape(nq, bq, n_kv, rep, d).transpose(
         2, 0, 3, 1, 4).reshape(n_kv, nq, rep * bq, d)
-    o = _attention(bq, p["block_k"], p["impl"] == "pallas_interpret", qr,
-                   kc.transpose(1, 0, 2), vc.transpose(1, 0, 2))
+    o = _attention(bq, p["block_k"], p["impl"] == "pallas_interpret", scope,
+                   qr, kc.transpose(1, 0, 2), vc.transpose(1, 0, 2))
     return o.reshape(n_kv, nq, d, rep, bq).transpose(1, 4, 0, 3, 2).reshape(
         T, n_kv, rep, d)
 
@@ -295,8 +295,8 @@ def _call(kernel, interpret, semantics, ins, outs, scratch=()):
     )(*ins)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-def _attention(bq, bk, interpret, q, k, v):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
+def _attention(bq, bk, interpret, scope, q, k, v):
     """``q [n_kv, nq, rep * bq, d]``, ``k, v [n_kv, T, d]``, all in the
     products' dtype -> ``o [n_kv, nq, d, rep * bq]`` float32."""
     return _forward(bq, bk, interpret, q, k, v)[0]
@@ -313,14 +313,14 @@ def _forward(bq, bk, interpret, q, k, v):
                  [pltpu.VMEM((1, rows), _F32)] * 2)
 
 
-def _attention_fwd(bq, bk, interpret, q, k, v):
+def _attention_fwd(bq, bk, interpret, scope, q, k, v):
     o, lse = _forward(bq, bk, interpret, q, k, v)
     return o, (q, k, v, o, lse)
 
 
-def _attention_bwd(bq, bk, interpret, res, do):
+def _attention_bwd(bq, bk, interpret, scope, res, do):
     q, k, v, o, lse = res
-    with jax.named_scope(_SCOPE):
+    with jax.named_scope(scope):
         di = jnp.sum(o * do, axis=2, keepdims=True)
         outs = [jax.ShapeDtypeStruct(q.shape, _F32),
                 jax.ShapeDtypeStruct(k.shape, _F32),

@@ -1,8 +1,10 @@
 """Expert layer of a chip that holds a share of the experts.
 
 The chip routes over ALL ``n_experts`` (the router keeps its published
-width), keeps each token's top-k weights as they are, and computes only
-the terms of the experts ``[first, first + held)`` it holds.  What the
+width; :func:`router_weights` is the softmax rule,
+:func:`sigmoid_router_weights` the sigmoid rule with a selection bias),
+keeps each token's top-k weights as they are, and computes only the terms
+of the experts ``[first, first + held)`` it holds.  What the
 absent experts would add is left out; on one chip there is no exchange.
 
 :func:`route_local` sorts the token-expert pairs that hit a held expert
@@ -45,6 +47,22 @@ def router_weights(logits: jnp.ndarray, top_k: int, renormalise: bool):
     if renormalise:
         w = w / jnp.sum(w, axis=-1, keepdims=True)
     return w, e.astype(jnp.int32)
+
+
+def sigmoid_router_weights(logits: jnp.ndarray, bias: jnp.ndarray, top_k: int,
+                           renormalise: bool, scale: float):
+    """``(weights [T, k] f32, experts [T, k] int32)`` of the
+    auxiliary-loss-free rule (``topk_method noaux_tc`` with one group,
+    arXiv:2412.19437 section 2.1.2): sigmoid scores over all experts in
+    float32; the top-k are chosen by score + ``bias [n_experts]``, their
+    weights are the scores WITHOUT the bias, renormalised to sum 1, times
+    ``scale``."""
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, e = lax.top_k(scores + lax.stop_gradient(bias), top_k)
+    w = jnp.take_along_axis(scores, e, axis=-1)
+    if renormalise:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return w * scale, e.astype(jnp.int32)
 
 
 def route_local(weights, experts, first: int, held: int, rows: int) -> Routing:

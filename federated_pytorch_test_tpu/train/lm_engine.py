@@ -8,9 +8,13 @@ sequence.  The model's routing counts ride in the engine's per-client
 non-parameter state (``ClientState.batch_stats``), summed over the steps,
 and ``round_fields`` turns them into the round record's ``tokens``,
 ``block_kind``, ``moe_pairs_local``, ``moe_load_max_over_mean`` and
-``moe_dropped``, and adds ``gdn_scan_impl`` and ``attn_impl``: which
-implementation of the delta rule's recurrence and of the attention core
-the model's shapes take on this backend.
+``moe_dropped`` and ``mtp_loss`` (the summed multi-token-prediction term
+of a model that has such a layer, else 0), and adds the model's own
+``impl_fields``: which implementations its shapes take on this backend
+(``attn_impl``, ``gdn_scan_impl``).  The trainer names no model: a
+decoder is a ``BlockModule`` whose ``__call__(ids, labels)`` returns
+``(loss per sequence, aux)`` and that has ``block_kinds()`` and
+``impl_fields(tokens)``.
 """
 
 from __future__ import annotations
@@ -22,7 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.custom_batching import sequential_vmap
 
-from federated_pytorch_test_tpu.models.qwen3_next import weighted_mean
+from federated_pytorch_test_tpu.models.decoder import weighted_mean
 from federated_pytorch_test_tpu.parallel.mesh import (
     client_sharding,
     fetch,
@@ -34,12 +38,15 @@ from federated_pytorch_test_tpu.train.engine import (
 )
 
 #: the counters kept per client, all sums over local steps
-_COUNTERS = ("steps", "moe_pairs_local", "moe_dropped", "moe_load_sum")
+_COUNTERS = ("steps", "moe_pairs_local", "moe_dropped", "moe_load_sum",
+             "mtp_loss_sum")
+_FLOAT_COUNTERS = ("moe_load_sum", "mtp_loss_sum")
 
 
 class LMTrainer(BlockwiseFederatedTrainer):
     """Federated next-token training of a ``BlockModule`` whose
-    ``__call__(ids)`` returns ``(logits, aux)`` (``models/qwen3_next.py``).
+    ``__call__(ids)`` returns ``(logits, aux)`` (``models/qwen3_next.py``,
+    ``models/glm4_moe_lite.py``).
     No L1/L2 term on any block; evaluation is the mean test loss."""
 
     obs_engine = "lm"
@@ -50,7 +57,7 @@ class LMTrainer(BlockwiseFederatedTrainer):
         # the per-client counters ARE the engine's batch_stats here (the
         # model has none of its own); has_bn stays False so nothing takes
         # them for batch-norm statistics
-        zeros = {k: np.zeros((cfg.K,), np.float32 if k == "moe_load_sum"
+        zeros = {k: np.zeros((cfg.K,), np.float32 if k in _FLOAT_COUNTERS
                              else np.int32) for k in _COUNTERS}
         self.batch_stats0 = stage_tree_global(zeros,
                                               client_sharding(self.mesh))
@@ -96,7 +103,10 @@ class LMTrainer(BlockwiseFederatedTrainer):
                + aux["moe_pairs_local"],
                "moe_dropped": bs["moe_dropped"] + aux["moe_dropped"],
                "moe_load_sum": bs["moe_load_sum"]
-               + aux["moe_load_max_over_mean"]}
+               + aux["moe_load_max_over_mean"],
+               "mtp_loss_sum": bs["mtp_loss_sum"] + (
+                   weighted_mean(aux["mtp_loss"], wb) if "mtp_loss" in aux
+                   else 0.0)}
         return weighted_mean(per_seq, wb), new
 
     def eval_batch_metric(self, p, bs, xb, yb, wb):
@@ -126,10 +136,8 @@ class LMTrainer(BlockwiseFederatedTrainer):
                 "moe_pairs_local": int(d["moe_pairs_local"]),
                 "moe_dropped": int(d["moe_dropped"]),
                 "moe_load_max_over_mean": d["moe_load_sum"] / steps,
-                "gdn_scan_impl": self.model.gdn_scan_impl(
-                    self.data.tokens_per_sample),
-                "attn_impl": self.model.attn_impl(
-                    self.data.tokens_per_sample)}
+                "mtp_loss": d["mtp_loss_sum"],
+                **self.model.impl_fields(self.data.tokens_per_sample)}
 
     def _block_index(self, ci: int) -> int:
         """Index into the model's own block list of sweep unit ``ci``

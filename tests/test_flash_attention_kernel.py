@@ -65,9 +65,9 @@ def xla_path(dtype, *args, block=128):
     return fa.causal_attention(*args, dtype=dtype, block=block)
 
 
-def kernels(dtype, *args):
+def kernels(dtype, *args, **kw):
     with fa.force_attn_impl("pallas_interpret"):
-        return xla_path(dtype, *args)
+        return fa.causal_attention(*args, dtype=dtype, block=128, **kw)
 
 
 def calls_a_kernel(f, *args):
@@ -135,6 +135,42 @@ def test_one_forward_and_one_backward_kernel():
     xla = str(jax.make_jaxpr(jax.grad(
         lambda *a: jnp.sum(xla_path(BF16, *a))))(*args))
     assert "checkpoint" in xla or "remat" in xla
+
+
+def kernel_paths(jaxpr):
+    """The name stacks of every ``pallas_call`` in ``jaxpr``, in order."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append(str(eqn.source_info.name_stack))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out += kernel_paths(sub)
+    return out
+
+
+@pytest.mark.parametrize("scope,want", [
+    (None, "gated_attn"), ("mla_attn/mla_core", "mla_attn/mla_core"),
+    ("mtp/mla_attn/mla_core", "mtp/mla_attn/mla_core")])
+def test_the_backward_kernel_s_path_carries_the_scope_given(scope, want):
+    """A ``custom_vjp``'s backward rule is traced outside the caller's
+    scope: the rule opens the scope it was given (the Qwen3-Next caller
+    gives none and keeps ``gated_attn``, which ``benchmarks/lib/
+    scopes.py`` reads)."""
+    args = attn_inputs(256, 2, 1, 128)
+    kw = {} if scope is None else {"scope": scope}
+
+    def loss(*a):
+        with jax.named_scope("forward_scope"):
+            return jnp.sum(kernels(BF16, *a, **kw))
+
+    fwd, bwd = kernel_paths(jax.make_jaxpr(
+        jax.grad(loss, argnums=(0, 1, 2)))(*args).jaxpr)
+    assert "forward_scope" in fwd
+    # what the rule opened comes after whatever JAX keeps of the forward
+    assert bwd.endswith("/" + want) and not bwd.startswith(want)
+    # the scope names a path and changes no number
+    base = jax.grad(lambda *a: jnp.sum(kernels(BF16, *a)))(*args)
+    assert np.array_equal(np.asarray(jax.grad(loss)(*args)), np.asarray(base))
 
 
 # ----------------------------------------------------------------------

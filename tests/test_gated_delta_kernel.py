@@ -328,20 +328,25 @@ def test_kernels_compile_for_a_v5e_at_the_published_widths(one_chip, what):
     assert text.count("tpu_custom_call") == (1 if what == "forward" else 2)
 
 
+@pytest.mark.parametrize("n_kv,rep", [(2, 8), (20, 1)],
+                         ids=["qwen3_next_16_2", "glm4_moe_lite_20_20"])
 @pytest.mark.parametrize("what", ["forward", "forward_and_backward"])
 def test_attention_kernels_compile_for_a_v5e_at_the_published_widths(
-        one_chip, what):
-    """The attention pair at 16 / 2 heads of 256 over 4,096 tokens with
-    ``plan``'s blocks: the whole key/value head and its float32 ``dk``,
-    ``dv`` in VMEM, a transposed-operand product, a dynamic trip count."""
-    T, n_kv, rep, d = 4096, 2, 8, 256
+        one_chip, what, n_kv, rep):
+    """The attention pair at 16 / 2 heads of 256 (Qwen3-Next) and at 20
+    / 20 heads of 256 (GLM-4.7-Flash's latent attention, expanded) over
+    4,096 tokens with ``plan``'s blocks: the whole key/value head and
+    its float32 ``dk``, ``dv`` in VMEM, a transposed-operand product, a
+    dynamic trip count."""
+    T, d = 4096, 256
     sh = lambda *s: jax.ShapeDtypeStruct(s, F32, sharding=one_chip)
     ops = (sh(T, n_kv, rep, d), sh(T, n_kv, d), sh(T, n_kv, d))
 
     def f(*a):
         with fa.force_attn_impl("pallas"):
             assert fa.plan(T, n_kv, rep, d, BF16)["impl"] == "pallas"
-            return fa.causal_attention(*a, dtype=BF16)
+            return fa.causal_attention(*a, dtype=BF16,
+                                       scope="mla_attn/mla_core")
 
     if what != "forward":
         f = jax.grad(lambda *a, f=f: jnp.sum(f(*a)), argnums=(0, 1, 2))

@@ -1,5 +1,6 @@
 """``LMTrainer`` on the engine's normal path at tiny widths on the CPU:
-two FedAvg rounds against ``benchmarks/reference/lm_round.py``, the
+two FedAvg rounds against ``benchmarks/reference/lm_round.py`` (and, for
+the second decoder with its two-term loss, ``decoder_round.py``), the
 optimizer over the active leaves, resume across the smaller optimizer
 tree.  Two layers (one Gated DeltaNet, one attention) keep the compiles
 short; the four-layer model is in ``tests/test_qwen3_next.py``."""
@@ -12,11 +13,17 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if REPO not in sys.path:
-    sys.path.insert(0, REPO)
+HERE = os.path.dirname(os.path.abspath(__file__))
+REPO = os.path.dirname(HERE)
+for path in (HERE, REPO):       # HERE: the second decoder's tiny model
+    if path not in sys.path:
+        sys.path.insert(0, path)
 
-from benchmarks.reference import lm_round  # noqa: E402
+from benchmarks.reference import (  # noqa: E402
+    decoder_round,
+    glm4_moe_lite as glm_ref,
+    lm_round,
+)
 from federated_pytorch_test_tpu.data.tokens import FederatedTokens  # noqa: E402
 from federated_pytorch_test_tpu.models import get_model  # noqa: E402
 from federated_pytorch_test_tpu.train import (  # noqa: E402
@@ -25,6 +32,10 @@ from federated_pytorch_test_tpu.train import (  # noqa: E402
     LMTrainer,
 )
 from federated_pytorch_test_tpu.utils.tree import get_by_path  # noqa: E402
+from test_glm4_moe_lite import (  # noqa: E402
+    REF_CFG as GLM_REF_CFG,
+    tiny_model as glm_tiny_model,
+)
 
 TINY = dict(hidden_size=32, num_attention_heads=4, num_key_value_heads=2,
             head_dim=16, linear_num_key_heads=2, linear_num_value_heads=4,
@@ -83,6 +94,71 @@ def test_two_fedavg_rounds_match_the_reference():
                 # gradient is rounding noise may land 2 lr away; none does
                 # at float32 on these shapes beyond a hundredth of lr
                 assert np.max(np.abs(leaf[k] - ref_leaves[k])) < 1e-5
+
+
+def test_two_fedavg_rounds_on_mtp_mixer_match_the_round_reference():
+    """The second decoder through the same trainer: the loss is two terms,
+    the round record carries the MTP term, and ``impl_fields`` is the
+    model's own (no ``gdn_scan_impl``)."""
+    model, ref_cfg = glm_tiny_model(layers=2), dict(GLM_REF_CFG, layers=2)
+    data = FederatedTokens(K=2, batch=2, samples_per_client=2, seq_len=T,
+                           vocab=64, seed=3, head=16)
+    cfg = FederatedConfig(K=2, Nloop=1, Nepoch=1, Nadmm=2, default_batch=2,
+                          check_results=False, lr=1e-3, num_devices=1,
+                          save_model=False)
+    t = LMTrainer(model, cfg, data, FedAvg())
+    block = model.block_names().index("mtp_mixer")
+    t.block_ids, t.L = [t.block_ids[block]], 1
+    lo, hi = t.block_ids[0]
+    paths = t.order[lo:hi + 1]
+    assert paths[0] == "mtp_mixer/enorm" and paths[-1] == "mtp_mixer/o_proj"
+    params = jax.tree.map(lambda a: np.asarray(a[0]), t.params0)
+    xs, ys = t.data.train_shards_raw()
+    seen = []
+    with jax.default_matmul_precision("highest"):
+        _, hist = t.run(log=lambda m: None, on_round=lambda s, r: seen.append(
+            [np.asarray(get_by_path(s.params, p)) for p in paths]))
+        want = decoder_round.run_rounds(
+            glm_ref, ref_cfg, params, paths, 1e-3,
+            [[[(xs[k], ys[k])] for k in range(2)] for _ in range(2)])
+        # the round's MTP term: both clients' one minibatch, unweighted
+        mtp = sum(float(glm_ref.loss_and_grad(
+            ref_cfg, params, [], jnp.asarray(xs[k][b]),
+            jnp.asarray(ys[k][b]))[1]["mtp_loss"]) / 2
+            for k in range(2) for b in range(2))
+    t.close()
+    assert hist[0]["mtp_loss"] == pytest.approx(mtp, rel=1e-5)
+    assert 0 < hist[1]["mtp_loss"] < hist[0]["mtp_loss"]
+    for got, w, rec in zip(seen, want, hist):
+        assert rec["loss"] == pytest.approx(w["loss"], rel=1e-5)
+        assert rec["block_kind"] == "mtp_mixer" and rec["moe_dropped"] == 0
+        assert rec["tokens"] == 2 * 2 * T and rec["attn_impl"] == "xla"
+        assert "gdn_scan_impl" not in rec
+        for leaf, ref_leaves in zip(got, zip(*w["x"])):
+            for k in range(2):
+                assert np.max(np.abs(leaf[k] - ref_leaves[k])) < 1e-5
+
+
+def test_a_model_without_an_mtp_layer_reports_zero():
+    t = lm_trainer([ATTN], Nadmm=1)
+    _, hist = t.run(log=lambda m: None)
+    t.close()
+    assert [r["mtp_loss"] for r in hist] == [0.0]
+    assert {"gdn_scan_impl", "attn_impl"} <= set(hist[0])
+
+
+def test_the_trainer_names_no_model():
+    import inspect
+
+    from federated_pytorch_test_tpu.train import lm_engine
+
+    src = inspect.getsource(lm_engine)
+    imports = [line for line in src.splitlines()
+               if line.startswith(("from ", "import "))]
+    assert imports and not any("qwen3_next" in line or "glm4_moe_lite" in line
+                               for line in imports)
+    # and calls no model-specific method by name
+    assert "gdn_scan_impl(" not in src and "attn_impl(" not in src
 
 
 def test_optimizer_and_gradient_hold_the_active_leaves_only():
