@@ -11,7 +11,9 @@ so every op runs identically on CPU/interpret mode.  Currently:
   * ``gated_delta.gated_delta_chunked`` — the chunked gated delta rule;
     its recurrence over the chunks is a forward and a backward kernel
     under one ``custom_vjp`` that keep the state ``S`` in VMEM
-    (``gated_delta.force_gdn_scan_impl`` for tests).
+    (``gated_delta.force_gdn_scan_impl`` for tests); the triangular
+    inverse of its chunk-local part is differentiated by the closed form
+    ``-T^T dT T^T`` on every backend.
   * ``flash_attention.causal_attention`` — causal softmax attention with
     grouped query heads, blockwise with an online softmax: a forward and
     a backward kernel under one ``custom_vjp`` that keep the score tiles

@@ -17,7 +17,11 @@ Sequences that are no multiple of ``chunk`` are padded at the end with
 The chunked form has two parts.  The chunk-local part (decays, the
 triangular inverse, the chunk's pseudo-values ``u``, the decayed keys
 ``w``, ``q k^T``) is plain XLA, and its backward pass is JAX's transpose
-of it.  The recurrence over the chunks::
+of it but for the triangular inverse, whose derivative is the closed form
+``-T^T dT T^T`` under a ``jax.custom_vjp`` (:func:`_unit_lower_inverse`:
+two products and ``T`` alone kept, where the transpose of the Neumann
+product runs nineteen and keeps every power).  The recurrence over the
+chunks::
 
     v_new = u - w S;   o = q_in S + qk v_new;   S <- g_last S + k_out^T v_new
 
@@ -58,9 +62,9 @@ _HEADS = 8                  # heads a grid step handles, at most
 # budget for plan()'s estimate of the larger (backward) kernel's blocks,
 # under the 16 MiB of scoped VMEM Mosaic allows a kernel on the v5e
 _VMEM_BUDGET = 12 * 2**20
-# the program's scope for the recurrence (models/qwen3_next.py opens it
+# the program's scope for this module (models/qwen3_next.py opens it
 # around the forward call; a custom_vjp's backward rule is traced outside
-# it, so the rule opens it again and a trace still finds the kernel)
+# it, so both rules open it again and a trace still finds their ops)
 _SCOPE = "gdn_scan"
 
 # None = by the backend; "pallas_interpret" stands in for a TPU in tests
@@ -96,21 +100,43 @@ def gated_delta_stepwise(q, k, v, g, beta):
     return o
 
 
+def _mm(x, y):
+    return jnp.einsum("...ij,...jk->...ik", x, y, precision=_HI)
+
+
+@jax.custom_vjp
 def _unit_lower_inverse(a):
     """``(I + a)^-1`` for strictly lower-triangular ``a [..., C, C]``:
     ``a`` is nilpotent, so the Neumann series ends and equals the product
     ``(I - a)(I + a^2)(I + a^4)...`` of ``log2 C`` factors: matrix
-    products instead of ``C`` steps of forward substitution."""
+    products instead of ``C`` steps of forward substitution.  Its
+    derivative is that of an inverse, ``dT = -T da T``, not the transpose
+    of the product factor by factor."""
     C = a.shape[-1]
     eye = jnp.eye(C, dtype=a.dtype)
-    mm = lambda x, y: jnp.einsum("...ij,...jk->...ik", x, y, precision=_HI)
     inv, power = eye - a, a
     span = 2
     while span < C:
-        power = mm(power, power)
-        inv = mm(inv, eye + power)
+        power = _mm(power, power)
+        inv = _mm(inv, eye + power)
         span *= 2
     return inv
+
+
+def _unit_lower_inverse_fwd(a):
+    inv = _unit_lower_inverse(a)
+    return inv, inv
+
+
+def _unit_lower_inverse_bwd(inv, d_inv):
+    """``-T^T dT T^T`` for any cotangent ``dT``, full or triangular (the
+    caller's mask on ``a`` transposes to the mask on this)."""
+    with jax.named_scope(_SCOPE):
+        inv_t = jnp.swapaxes(inv, -1, -2)
+        return (-_mm(inv_t, _mm(d_inv, inv_t)),)
+
+
+_unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
 
 
 def gated_delta_chunked(q, k, v, g, beta, *, chunk: int = 64,

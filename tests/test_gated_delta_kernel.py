@@ -123,6 +123,89 @@ def test_forward_without_a_gradient_writes_no_states():
 
 
 # ----------------------------------------------------------------------
+# the triangular inverse's closed-form derivative
+# ----------------------------------------------------------------------
+#: the Neumann product as JAX differentiates it, factor by factor
+neumann_product = gd._unit_lower_inverse.__wrapped__
+
+
+def linalg_inv(a):
+    return jnp.linalg.inv(jnp.eye(a.shape[-1], dtype=a.dtype) + a)
+
+
+def inverse_case(C, lead, seed=0):
+    """A strictly lower-triangular ``a [*lead, C, C]`` of the size the
+    delta rule gives it (``beta k_i . k_j`` of unit keys, decayed) and a
+    FULL cotangent, as ``u`` and ``w`` send back."""
+    ka, kc = jax.random.split(jax.random.PRNGKey(seed))
+    a = jnp.tril(0.1 * jax.random.normal(ka, (*lead, C, C)), -1)
+    return a, jax.random.normal(kc, a.shape)
+
+
+def inverse_grad(inverse, a, ct):
+    return jax.grad(lambda x: jnp.sum(inverse(x) * ct))(a)
+
+
+@pytest.mark.parametrize("reference", [neumann_product, linalg_inv],
+                         ids=["neumann_product", "linalg_inv"])
+@pytest.mark.parametrize("lead", [(3,), (2, 3)], ids=["one_axis", "two_axes"])
+@pytest.mark.parametrize("C", [8, 64])
+def test_inverse_gradient_is_the_derivative_of_an_inverse(C, lead, reference):
+    a, ct = inverse_case(C, lead)
+    assert rel(gd._unit_lower_inverse(a), reference(a)) < 1e-5
+    got = inverse_grad(gd._unit_lower_inverse, a, ct)
+    assert got.shape == a.shape and got.dtype == F32
+    assert rel(got, inverse_grad(reference, a, ct)) < 1e-5
+
+
+@pytest.mark.parametrize("inverse,products", [
+    (gd._unit_lower_inverse, 12), (neumann_product, 29)],
+    ids=["closed_form", "neumann_product"])
+def test_inverse_gradient_runs_two_products_more_not_nineteen(inverse,
+                                                              products):
+    """At the published chunk: ten products forward either way; the rule
+    adds two where JAX's transpose of the ten adds nineteen."""
+    a, ct = inverse_case(CHUNK, (2, 3))
+    lowered = jax.jit(functools.partial(inverse_grad, inverse)).lower(a, ct)
+    assert lowered.as_text().count("dot_general") == products
+
+
+def test_inverse_keeps_the_inverse_alone_for_the_backward_pass():
+    a, _ = inverse_case(CHUNK, (2, 3))
+    kept = lambda f: jax.tree_util.tree_leaves(jax.vjp(f, a)[1])
+    assert [(r.shape, r.dtype) for r in kept(gd._unit_lower_inverse)] \
+        == [(a.shape, F32)]
+    assert len(kept(neumann_product)) > 1       # powers, partial products
+
+
+def test_inverse_backward_rule_opens_the_scope_again():
+    """The rule is traced outside the caller's scope; without its own the
+    two products would be found under no scope in a trace."""
+    a, ct = inverse_case(CHUNK, (3,))
+    text = jax.jit(functools.partial(
+        inverse_grad, gd._unit_lower_inverse)).lower(a, ct).as_text(
+            debug_info=True)
+    products = [line for line in text.splitlines()
+                if line.startswith("#loc") and "dot_general" in line
+                and "transpose(jvp(" in line]
+    assert products and all(gd._SCOPE in line for line in products)
+
+
+@pytest.mark.parametrize("how", ["vmap", "checkpoint", "lax_map"])
+def test_inverse_gradient_is_the_same_as_the_model_calls_it(how):
+    """``models/qwen3_next.py`` reaches it under ``jax.checkpoint`` inside
+    ``lax.map`` over the sequences; a trainer may ``vmap`` its clients."""
+    a, ct = inverse_case(CHUNK, (2, 3))
+    wrapped = {"vmap": jax.vmap(gd._unit_lower_inverse),
+               "checkpoint": jax.checkpoint(gd._unit_lower_inverse),
+               "lax_map": lambda x: jax.lax.map(
+                   jax.checkpoint(gd._unit_lower_inverse), x)}[how]
+    want = inverse_grad(neumann_product, a, ct)
+    assert rel(jax.jit(functools.partial(inverse_grad, wrapped))(a, ct),
+               want) < 1e-5
+
+
+# ----------------------------------------------------------------------
 # the backward kernel, line by line
 # ----------------------------------------------------------------------
 STEP_GRADS = ("du", "dw", "dqk", "dq_in", "dk_out", "dg_last")
