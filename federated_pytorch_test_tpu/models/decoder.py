@@ -1,8 +1,12 @@
 """What the decoders share (``models/qwen3_next.py``,
-``models/glm4_moe_lite.py``): a block's leaves, the rotary tables, the
-loss helpers, and the part of an expert layer that follows the router on
-a chip that holds a share of the experts (sort, grouped products,
-scatter).  Each decoder keeps its own norm, mixers and router rule.
+``models/glm4_moe_lite.py``, ``models/xing4_0.py``): a block's leaves,
+the rotary tables (plain or YaRN's), the loss helpers, the part of an
+expert layer that follows the router on a chip that holds a share of the
+experts (sort, grouped products, scatter), and what the two decoders of
+the DeepSeek-V3 line share: the plain RMS norm, multi-head latent
+attention, the dense SwiGLU, the expert layer under the sigmoid rule
+with its ungated shared expert, and their leaves.  Each decoder keeps
+its own layer and residual path.
 """
 
 from __future__ import annotations
@@ -15,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from federated_pytorch_test_tpu.ops import moe as moelib
+from federated_pytorch_test_tpu.ops.flash_attention import causal_attention
 
 _F32 = jnp.float32
 _op = moelib.operand
@@ -42,13 +47,40 @@ def _mm(cfg, x, w):
                    preferred_element_type=_F32)
 
 
-def rope_tables(T: int, rot: int, theta: float):
+def rope_tables(T: int, rot: int, theta: float, inv=None):
     """``cos, sin [T, rot]`` (rotate-half layout: the ``rot / 2``
-    frequencies repeated)."""
-    inv = 1.0 / theta ** (jnp.arange(0, rot, 2, dtype=_F32) / rot)
+    frequencies repeated).  ``inv [rot / 2]`` takes the place of the
+    plain inverse frequencies (:func:`yarn_inv_freq`)."""
+    if inv is None:
+        inv = 1.0 / theta ** (jnp.arange(0, rot, 2, dtype=_F32) / rot)
     ang = jnp.arange(T, dtype=_F32)[:, None] * inv[None, :]
     ang = jnp.concatenate([ang, ang], -1)
     return jnp.cos(ang), jnp.sin(ang)
+
+
+def yarn_inv_freq(rot: int, theta: float, factor: float, original: int,
+                  beta_fast: float, beta_slow: float):
+    """YaRN's inverse frequencies ``[rot / 2]`` (arXiv:2309.00071, as the
+    DeepSeek-V3 line's ``rope_scaling`` of ``type yarn`` has them): a
+    frequency that turns more than ``beta_fast`` times over the
+    ``original`` context stays, one that turns fewer than ``beta_slow``
+    times is divided by ``factor``, and a linear ramp lies between."""
+    turns_at = lambda beta: rot * math.log(original / (beta * 2 * math.pi)) \
+        / (2 * math.log(theta))
+    low = max(math.floor(turns_at(beta_fast)), 0)
+    high = min(math.ceil(turns_at(beta_slow)), rot - 1)
+    i = jnp.arange(rot // 2, dtype=_F32)
+    keep = 1.0 - jnp.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
+    plain = 1.0 / theta ** (2.0 * i / rot)
+    return plain / factor * (1.0 - keep) + plain * keep
+
+
+def yarn_softmax_scale(factor: float, mscale_all_dim: float) -> float:
+    """What YaRN multiplies the softmax scale by: ``(0.1 mscale_all_dim
+    ln factor + 1)^2`` (1 where nothing is stretched)."""
+    if factor <= 1 or not mscale_all_dim:
+        return 1.0
+    return (0.1 * mscale_all_dim * math.log(factor) + 1.0) ** 2
 
 
 def apply_rope(x, cos, sin):
@@ -60,6 +92,112 @@ def apply_rope(x, cos, sin):
     turned = jnp.concatenate([-xr[..., half:], xr[..., :half]], -1)
     c, s = cos[:, None, :], sin[:, None, :]
     return jnp.concatenate([xr * c + turned * s, rest], -1)
+
+
+def rms_norm(x, w, eps):
+    x = x.astype(_F32)
+    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w
+
+
+def latent_attention(cfg, p, x, outer: str = "", scale=None, inv_freq=None):
+    """Multi-head latent attention in its expanded form (arXiv:2405.04434
+    section 2.1): ``x [T, H]`` (already normed) -> ``[T, H]``.  The value
+    heads' width ``cfg.v_head_dim`` is their own.  ``outer`` is the scope
+    path the caller stands in (``"mtp/"``), for the backward kernel's
+    name; ``scale`` the softmax scale (the key width's inverse root where
+    not given); ``inv_freq`` the rotary inverse frequencies (plain ones
+    of ``cfg.rope_theta`` where not given)."""
+    T, n = x.shape[0], cfg.num_attention_heads
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    eps = cfg.rms_norm_eps
+    if scale is None:
+        scale = 1.0 / math.sqrt(dn + dr)
+    c_q = rms_norm(_mm(cfg, x, p["q_a_proj"]), p["q_a_norm"], eps)
+    q = _mm(cfg, c_q, p["q_b_proj"]).reshape(T, n, dn + dr)
+    kv_a = _mm(cfg, x, p["kv_a_proj"])
+    # the norm is the latent's; the rotary key, shared by every head,
+    # goes by it untouched
+    c_kv = rms_norm(kv_a[:, :cfg.kv_lora_rank], p["kv_a_norm"], eps)
+    k_rope = kv_a[:, cfg.kv_lora_rank:].reshape(T, 1, dr)
+    kv = _mm(cfg, c_kv, p["kv_b_proj"]).reshape(T, n, dn + dv)
+    cos, sin = rope_tables(T, dr, cfg.rope_theta, inv_freq)
+    q = jnp.concatenate([q[..., :dn], apply_rope(q[..., dn:], cos, sin)], -1)
+    k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(
+        apply_rope(k_rope, cos, sin), (T, n, dr))], -1)
+    q = q.reshape(T, n, 1, dn + dr) * scale
+    with jax.named_scope("mla_core"):
+        o = causal_attention(q, k, kv[..., dn:], dtype=cfg.dtype,
+                             block=cfg.attn_block,
+                             scope=outer + "mla_attn/mla_core")
+    return _mm(cfg, o.reshape(T, n * dv), p["o_proj"])
+
+
+def mla_leaves(cfg):
+    """A latent-attention mixer's leaves with its input norm."""
+    H, s, n = cfg.hidden_size, _normal(cfg.init_scale), \
+        cfg.num_attention_heads
+    qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    return (("norm", (H,), _ONES),
+            ("q_a_proj", (H, cfg.q_lora_rank), s),
+            ("q_a_norm", (cfg.q_lora_rank,), _ONES),
+            ("q_b_proj", (cfg.q_lora_rank, n * qk), s),
+            ("kv_a_proj", (H, cfg.kv_lora_rank + cfg.qk_rope_head_dim), s),
+            ("kv_a_norm", (cfg.kv_lora_rank,), _ONES),
+            ("kv_b_proj", (cfg.kv_lora_rank,
+                           n * (cfg.qk_nope_head_dim + cfg.v_head_dim)), s),
+            ("o_proj", (n * cfg.v_head_dim, H), s))
+
+
+def dense_mlp_leaves(cfg):
+    H, F, s = cfg.hidden_size, cfg.intermediate_size, \
+        _normal(cfg.init_scale)
+    return (("norm", (H,), _ONES), ("gate_proj", (H, F), s),
+            ("up_proj", (H, F), s), ("down_proj", (F, H), s))
+
+
+def sigmoid_moe_leaves(cfg):
+    """An expert block's leaves under the sigmoid rule: the router and
+    its selection bias FIRST (they lie in no federated block), then the
+    norm, the held experts and the shared expert."""
+    H, s = cfg.hidden_size, _normal(cfg.init_scale)
+    E, F = cfg.experts_held, cfg.moe_intermediate_size
+    Fs = F * cfg.n_shared_experts
+    return (("router", (H, cfg.n_routed_experts), s),
+            ("router_bias", (cfg.n_routed_experts,),
+             _normal(cfg.bias_scale)),
+            ("norm", (H,), _ONES),
+            ("experts_gate", (E, H, F), s),
+            ("experts_up", (E, H, F), s),
+            ("experts_down", (E, F, H), s),
+            ("shared_gate_proj", (H, Fs), s),
+            ("shared_up", (H, Fs), s),
+            ("shared_down", (Fs, H), s))
+
+
+def dense_mlp(cfg, p, x):
+    """``x [T, H]`` (already normed) -> ``[T, H]``."""
+    with jax.named_scope("dense_mlp"):
+        h = jax.nn.silu(_mm(cfg, x, p["gate_proj"])) \
+            * _mm(cfg, x, p["up_proj"])
+        return _mm(cfg, h, p["down_proj"])
+
+
+def sigmoid_expert_layer(cfg, p, x):
+    """``x [T, H]`` (already normed) -> ``([T, H], routing)``: sigmoid
+    scores, the top-k by score + selection bias, weights without the
+    bias (``ops/moe.py``), the held experts' terms and one ungated
+    shared expert."""
+    with jax.named_scope("moe_route"):
+        logits = jnp.dot(x, p["router"], precision=jax.lax.Precision.HIGHEST)
+        w, e = moelib.sigmoid_router_weights(
+            logits, p["router_bias"], cfg.num_experts_per_tok,
+            cfg.norm_topk_prob, cfg.routed_scaling_factor)
+    y, r = held_experts(cfg, p, x, w, e, cfg.n_routed_experts)
+    with jax.named_scope("moe_shared"):
+        hs = jax.nn.silu(_mm(cfg, x, p["shared_gate_proj"])) \
+            * _mm(cfg, x, p["shared_up"])
+        y = y + _mm(cfg, hs, p["shared_down"])
+    return y, r
 
 
 def held_experts(cfg, p, x, w, e, n_experts: int):

@@ -50,7 +50,6 @@ positions that have one (``aux["mtp_loss"]``, per sequence, unweighted).
 from __future__ import annotations
 
 import functools
-import math
 from typing import Any, Dict, List
 
 import flax.linen as nn
@@ -64,21 +63,16 @@ from federated_pytorch_test_tpu.models.decoder import (
     _Leaves,
     _mm,
     _normal,
-    apply_rope,
-    held_experts,
-    rope_tables,
+    dense_mlp,
+    dense_mlp_leaves,
+    latent_attention,
+    mla_leaves,
+    rms_norm,
     sequence_loss,
+    sigmoid_expert_layer as expert_layer,
+    sigmoid_moe_leaves,
 )
-from federated_pytorch_test_tpu.ops import moe as moelib
-from federated_pytorch_test_tpu.ops.flash_attention import (
-    causal_attention,
-    plan as attn_plan,
-)
-
-
-def rms_norm(x, w, eps):
-    x = x.astype(_F32)
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w
+from federated_pytorch_test_tpu.ops.flash_attention import plan as attn_plan
 
 
 class Glm4MoeLite(BlockModule):
@@ -173,36 +167,6 @@ class Glm4MoeLite(BlockModule):
         implementations for sequences of ``tokens``."""
         return {"attn_impl": self.attn_impl(tokens)}
 
-    def _mla_spec(self):
-        H, s, n = self.hidden_size, _normal(self.init_scale), \
-            self.num_attention_heads
-        return (("norm", (H,), _ONES),
-                ("q_a_proj", (H, self.q_lora_rank), s),
-                ("q_a_norm", (self.q_lora_rank,), _ONES),
-                ("q_b_proj", (self.q_lora_rank, n * self.qk_head_dim), s),
-                ("kv_a_proj", (H, self.kv_lora_rank
-                               + self.qk_rope_head_dim), s),
-                ("kv_a_norm", (self.kv_lora_rank,), _ONES),
-                ("kv_b_proj", (self.kv_lora_rank,
-                               n * (self.qk_nope_head_dim
-                                    + self.v_head_dim)), s),
-                ("o_proj", (n * self.v_head_dim, H), s))
-
-    def _moe_spec(self):
-        H, s = self.hidden_size, _normal(self.init_scale)
-        E, F = self.experts_held, self.moe_intermediate_size
-        Fs = F * self.n_shared_experts
-        return (("router", (H, self.n_routed_experts), s),
-                ("router_bias", (self.n_routed_experts,),
-                 _normal(self.bias_scale)),
-                ("norm", (H,), _ONES),
-                ("experts_gate", (E, H, F), s),
-                ("experts_up", (E, H, F), s),
-                ("experts_down", (E, F, H), s),
-                ("shared_gate_proj", (H, Fs), s),
-                ("shared_up", (H, Fs), s),
-                ("shared_down", (Fs, H), s))
-
     def _spec(self, name: str):
         H, s = self.hidden_size, _normal(self.init_scale)
         if name == "embed":
@@ -213,16 +177,14 @@ class Glm4MoeLite(BlockModule):
                     ("kernel", (H, self.vocab_rows), s))
         if name == "mtp_mixer":
             return (("enorm", (H,), _ONES), ("hnorm", (H,), _ONES),
-                    ("eh_proj", (2 * H, H), s)) + self._mla_spec()
+                    ("eh_proj", (2 * H, H), s)) + mla_leaves(self)
         if name == "mtp_moe":
-            return self._moe_spec() + (("head_norm", (H,), _ONES),)
+            return sigmoid_moe_leaves(self) + (("head_norm", (H,), _ONES),)
         if name.endswith("_moe"):
-            return self._moe_spec()
+            return sigmoid_moe_leaves(self)
         if name.endswith("_mlp"):
-            F = self.intermediate_size
-            return (("norm", (H,), _ONES), ("gate_proj", (H, F), s),
-                    ("up_proj", (H, F), s), ("down_proj", (F, H), s))
-        return self._mla_spec()
+            return dense_mlp_leaves(self)
+        return mla_leaves(self)
 
     def param_order(self) -> List[str]:
         return [f"{b}/{leaf}" for b in self.block_names()
@@ -248,61 +210,6 @@ class Glm4MoeLite(BlockModule):
         float32 logits are alive at a time)."""
         p = {b: _Leaves(self._spec(b), name=b)() for b in self.block_names()}
         return forward(self, p, ids, labels)
-
-
-def latent_attention(cfg: Glm4MoeLite, p, x, outer: str = ""):
-    """``x [T, H]`` (already normed) -> ``[T, H]``.  ``outer`` is the
-    scope path the caller stands in (``"mtp/"``), for the backward
-    kernel's name."""
-    T, n = x.shape[0], cfg.num_attention_heads
-    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    eps = cfg.rms_norm_eps
-    if dv != dn + dr:
-        raise ValueError(
-            f"v_head_dim {dv} != qk_nope_head_dim + qk_rope_head_dim "
-            f"{dn + dr}: ops/flash_attention.py takes value heads as wide "
-            "as the key heads")
-    c_q = rms_norm(_mm(cfg, x, p["q_a_proj"]), p["q_a_norm"], eps)
-    q = _mm(cfg, c_q, p["q_b_proj"]).reshape(T, n, dn + dr)
-    kv_a = _mm(cfg, x, p["kv_a_proj"])
-    # the norm is the latent's; the rotary key, shared by every head,
-    # goes by it untouched
-    c_kv = rms_norm(kv_a[:, :cfg.kv_lora_rank], p["kv_a_norm"], eps)
-    k_rope = kv_a[:, cfg.kv_lora_rank:].reshape(T, 1, dr)
-    kv = _mm(cfg, c_kv, p["kv_b_proj"]).reshape(T, n, dn + dv)
-    cos, sin = rope_tables(T, dr, cfg.rope_theta)
-    q = jnp.concatenate([q[..., :dn], apply_rope(q[..., dn:], cos, sin)], -1)
-    k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(
-        apply_rope(k_rope, cos, sin), (T, n, dr))], -1)
-    q = q.reshape(T, n, 1, dn + dr) * (1.0 / math.sqrt(dn + dr))
-    with jax.named_scope("mla_core"):
-        o = causal_attention(q, k, kv[..., dn:], dtype=cfg.dtype,
-                             block=cfg.attn_block,
-                             scope=outer + "mla_attn/mla_core")
-    return _mm(cfg, o.reshape(T, n * dv), p["o_proj"])
-
-
-def dense_mlp(cfg: Glm4MoeLite, p, x):
-    """``x [T, H]`` (already normed) -> ``[T, H]``."""
-    with jax.named_scope("dense_mlp"):
-        h = jax.nn.silu(_mm(cfg, x, p["gate_proj"])) \
-            * _mm(cfg, x, p["up_proj"])
-        return _mm(cfg, h, p["down_proj"])
-
-
-def expert_layer(cfg: Glm4MoeLite, p, x):
-    """``x [T, H]`` (already normed) -> ``([T, H], routing)``."""
-    with jax.named_scope("moe_route"):
-        logits = jnp.dot(x, p["router"], precision=jax.lax.Precision.HIGHEST)
-        w, e = moelib.sigmoid_router_weights(
-            logits, p["router_bias"], cfg.num_experts_per_tok,
-            cfg.norm_topk_prob, cfg.routed_scaling_factor)
-    y, r = held_experts(cfg, p, x, w, e, cfg.n_routed_experts)
-    with jax.named_scope("moe_shared"):
-        hs = jax.nn.silu(_mm(cfg, x, p["shared_gate_proj"])) \
-            * _mm(cfg, x, p["shared_up"])
-        y = y + _mm(cfg, hs, p["shared_down"])
-    return y, r
 
 
 def decoder_layer(cfg: Glm4MoeLite, pm, pf, x, outer: str = ""):

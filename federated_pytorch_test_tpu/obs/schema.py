@@ -179,13 +179,17 @@ FIELDS: Dict[str, Any] = {
     # in the sorted pair buffer (ops/moe.py); 0, or `correct` fails.
     # mtp_loss: the multi-token-prediction term of the loss, unweighted,
     # summed over the round's steps and clients as `loss` is (0.0 where
-    # the model has no such layer)
+    # the model has no such layer).  mhc_marginal_err: the largest
+    # |row sum - 1| or |column sum - 1| of a step's hyper-connection
+    # mixing matrices, mean over the round's steps (0.0 for a model
+    # without streams)
     "tokens":       (("round",), _INT),
     "block_kind":   (("round",), _STR),
     "moe_pairs_local": (("round",), _INT),
     "moe_load_max_over_mean": (("round",), _NUM),
     "moe_dropped":  (("round",), _INT),
     "mtp_loss":     (("round",), _NUM),
+    "mhc_marginal_err": (("round",), _NUM),
     # what ran the delta rule's chunk recurrence (ops/gated_delta.py:
     # plan): pallas | pallas_interpret | xla.  Names the machine's path,
     # not the trajectory, hence advisory

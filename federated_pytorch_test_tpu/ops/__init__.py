@@ -19,6 +19,10 @@ so every op runs identically on CPU/interpret mode.  Currently:
     a backward kernel under one ``custom_vjp`` that keep the score tiles
     in VMEM and skip the masked half
     (``flash_attention.force_attn_impl`` for tests).
+  * ``hyper_connections`` — no kernel either: the maps, the Sinkhorn
+    projection and the stream mixing of manifold-constrained
+    hyper-connections in float32 ``jax.numpy``, laid out for the TPU
+    (streams stream-major, the maps' tokens along the lanes).
   * ``moe`` — no kernel of its own: the two router rules
     (``router_weights``, ``sigmoid_router_weights``) and the held
     experts' sorted pairs and grouped products (``jax.lax.ragged_dot``,

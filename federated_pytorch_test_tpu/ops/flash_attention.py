@@ -5,13 +5,19 @@ Per key/value head ``g`` and each of the ``rep`` query heads it serves::
     s_tu = q_t . k_u  (u <= t);   a = softmax_u(s);   o_t = sum_u a_tu v_u
 
 :func:`causal_attention` takes ``q`` already scaled, normed and rotated.
+The value heads may be narrower or wider than the key heads (latent
+attention with 192-wide keys and 128-wide values).
 Which path runs is decided per call from what the call shows
-(:func:`plan`): on a TPU, for a two- or four-byte ``dtype``, a head width
-that is a multiple of 128, a sequence that is a multiple of the kernels'
-key block and blocks inside the VMEM budget, a pair of Pallas kernels
-under one ``jax.custom_vjp``; everywhere else (the CPU, a one-byte
-``dtype``, odd shapes) the XLA path: query blocks of ``block`` against
-all keys under the mask, each block rematerialised.  Both do the same
+(:func:`plan`): on a TPU, for a two- or four-byte ``dtype``, a value
+width that is a multiple of 128, a sequence that is a multiple of the
+kernels' key block and blocks inside the VMEM budget, a pair of Pallas
+kernels under one ``jax.custom_vjp``; everywhere else (the CPU, a
+one-byte ``dtype``, odd shapes) the XLA path: query blocks of ``block``
+against all keys under the mask, each block rematerialised.  A key width
+that is no multiple of 128 reaches the kernels with zero columns appended
+to ``q`` and ``k`` up to the next multiple (:func:`plan`'s ``pad_k``):
+a zero adds nothing to a score, so the result is exact, and the columns'
+gradient is dropped by the padding's own transpose.  Both do the same
 arithmetic at the same precision: products in ``dtype`` with float32
 sums, maximum, exponent, sum and the output's normalisation in float32,
 the probabilities rounded to ``dtype`` only as the operand of ``a v``.
@@ -74,24 +80,27 @@ def force_attn_impl(impl: str):
         _FORCE_IMPL = prev
 
 
-def plan(T: int, n_kv: int, rep: int, d: int, dtype) -> dict:
-    """What :func:`causal_attention` runs for ``n_kv`` key/value heads of
-    width ``d``, ``rep`` query heads each, over ``T`` tokens on the
-    current backend, and what decided it: ``impl`` ("pallas" |
-    "pallas_interpret" | "xla"), the kernels' ``block_q`` (queries of one
-    head a grid step holds) and ``block_k``, and the backward kernel's
-    VMEM estimate."""
+def plan(T: int, n_kv: int, rep: int, d: int, dtype, dv: int = 0) -> dict:
+    """What :func:`causal_attention` runs for ``n_kv`` key/value heads
+    with keys of width ``d`` and values of width ``dv`` (``d`` where not
+    given), ``rep`` query heads each, over ``T`` tokens on the current
+    backend, and what decided it: ``impl`` ("pallas" | "pallas_interpret"
+    | "xla"), the kernels' ``block_q`` (queries of one head a grid step
+    holds) and ``block_k``, ``pad_k`` (zero columns appended to ``q`` and
+    ``k``) and the backward kernel's VMEM estimate."""
+    dv = dv or d
     b = jnp.dtype(dtype).itemsize
     backend = _FORCE_IMPL or ("pallas" if jax.default_backend() == "tpu"
                               else "xla")
-    out = {"impl": "xla", "block_q": 0, "block_k": 0, "vmem_bytes": 0,
-           "vmem_budget": _VMEM_BUDGET}
+    out = {"impl": "xla", "block_q": 0, "block_k": 0, "pad_k": 0,
+           "vmem_bytes": 0, "vmem_budget": _VMEM_BUDGET}
     if backend == "xla":
         return dict(out, why="no TPU")
     if b not in (2, 4):
         return dict(out, why=f"{jnp.dtype(dtype).name} operands")
-    if d % _LANE:
+    if dv % _LANE:
         return dict(out, why="head width no multiple of 128")
+    pad_k = (-d) % _LANE
     block_k = next((c for c in (_BLOCK_K, _LANE) if T % c == 0), 0)
     if not block_k:
         return dict(out, why="sequence no multiple of the kernels' blocks")
@@ -100,45 +109,55 @@ def plan(T: int, n_kv: int, rep: int, d: int, dtype) -> dict:
     block_q = block_k
     while rep * block_q > _ROWS and block_q > 32 // b:
         block_q //= 2
-    need = _grad_vmem_bytes(T, rep * block_q, block_k, d, b)
+    need = _grad_vmem_bytes(T, rep * block_q, block_k, d + pad_k, b, dv)
     if need > _VMEM_BUDGET:
         return dict(out, why="blocks exceed the VMEM budget")
     return dict(out, impl=backend, block_q=block_q, block_k=block_k,
-                vmem_bytes=need, why="fits")
+                pad_k=pad_k, vmem_bytes=need, why="fits")
 
 
-def _grad_vmem_bytes(T: int, rows: int, block_k: int, d: int, b: int) -> int:
+def _grad_vmem_bytes(T: int, rows: int, block_k: int, d: int, b: int,
+                     dv: int = 0) -> int:
     """VMEM estimate for ``_grad_kernel`` (the larger of the two): the
     head's ``k``, ``v`` and float32 ``dk``, ``dv`` and the step's ``q``,
     ``do``, ``dq`` and two rows, all double-buffered by the pipeline, and
-    about five ``[block_k, rows]`` float32 tiles."""
-    head = 2 * T * d * (b + 4)
-    step = rows * d * (2 * b + 4) + 2 * 8 * rows * 4
+    about five ``[block_k, rows]`` float32 tiles (keys ``d`` wide, values
+    ``dv``)."""
+    dv = dv or d
+    head = T * (d + dv) * (b + 4)
+    step = rows * (d * (b + 4) + dv * b) + 2 * 8 * rows * 4
     return 2 * (head + step) + 5 * block_k * rows * 4
 
 
 def causal_attention(q, k, v, *, dtype=jnp.bfloat16, block: int = 512,
                      scope: str = "gated_attn"):
-    """``q [T, n_kv, rep, d]`` (scaled, normed, rotated), ``k, v [T, n_kv,
-    d]`` -> ``o [T, n_kv, rep, d]`` float32: query head ``(g, r)`` attends
-    to key/value head ``g`` at its own and earlier positions.  ``block``
+    """``q [T, n_kv, rep, d]`` (scaled, normed, rotated), ``k [T, n_kv,
+    d]``, ``v [T, n_kv, dv]`` -> ``o [T, n_kv, rep, dv]`` float32: query
+    head ``(g, r)`` attends to key/value head ``g`` at its own and
+    earlier positions.  ``block``
     is the XLA path's query block.  ``scope`` is the caller's
     ``jax.named_scope`` around this call: a custom_vjp's backward rule is
     traced outside it, so the rule opens it again and a trace still finds
     the backward kernel under the mixer's name."""
     T, n_kv, rep, d = q.shape
-    p = plan(T, n_kv, rep, d, dtype)
+    dv = v.shape[-1]
+    p = plan(T, n_kv, rep, d, dtype, dv)
     kc, vc = operand(k, dtype), operand(v, dtype)
     if p["impl"] == "xla":
         return _blocks_against_all_keys(q, kc, vc, dtype, block)
     bq, nq = p["block_q"], T // p["block_q"]
+    qc = operand(q, dtype)
+    if p["pad_k"]:
+        qc, kc = (jnp.pad(a, ((0, 0),) * (a.ndim - 1) + ((0, p["pad_k"]),))
+                  for a in (qc, kc))
+        d += p["pad_k"]
     # a group's heads side by side as the rows of one query block
-    qr = operand(q, dtype).reshape(nq, bq, n_kv, rep, d).transpose(
+    qr = qc.reshape(nq, bq, n_kv, rep, d).transpose(
         2, 0, 3, 1, 4).reshape(n_kv, nq, rep * bq, d)
     o = _attention(bq, p["block_k"], p["impl"] == "pallas_interpret", scope,
                    qr, kc.transpose(1, 0, 2), vc.transpose(1, 0, 2))
-    return o.reshape(n_kv, nq, d, rep, bq).transpose(1, 4, 0, 3, 2).reshape(
-        T, n_kv, rep, d)
+    return o.reshape(n_kv, nq, dv, rep, bq).transpose(1, 4, 0, 3, 2).reshape(
+        T, n_kv, rep, dv)
 
 
 def _blocks_against_all_keys(q, kc, vc, dtype, block):
@@ -163,7 +182,8 @@ def _blocks_against_all_keys(q, kc, vc, dtype, block):
                           preferred_element_type=_F32)
 
     starts = jnp.arange(qp.shape[0]) * bq
-    return lax.map(one, (qp, starts)).reshape(-1, n_kv, rep, d)[:T]
+    return lax.map(one, (qp, starts)).reshape(-1, n_kv, rep,
+                                              vc.shape[-1])[:T]
 
 
 # ----------------------------------------------------------------------
@@ -206,11 +226,11 @@ def _seen(i, bq, diagonal, shape):
 
 def _attn_kernel(bq, bk, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref):
     """One query block of a group's heads, ``q_ref [rows, d]`` with row
-    ``r`` at position ``i * bq + r % bq``, against ``k_ref, v_ref [T,
-    d]``; score tiles have the keys in rows, ``[bk, rows]``, so the
-    running maximum ``m_ref`` and sum ``l_ref`` and the log-sum-exp
-    ``lse_ref`` are lane-dense rows ``[1, rows]`` and ``o_ref [d, rows]``
-    float32, the running output, is transposed."""
+    ``r`` at position ``i * bq + r % bq``, against ``k_ref [T, d]`` and
+    ``v_ref [T, dv]``; score tiles have the keys in rows, ``[bk, rows]``,
+    so the running maximum ``m_ref`` and sum ``l_ref`` and the
+    log-sum-exp ``lse_ref`` are lane-dense rows ``[1, rows]`` and ``o_ref
+    [dv, rows]`` float32, the running output, is transposed."""
     i = pl.program_id(1)
     q = q_ref[...]
     m_ref[...] = jnp.full_like(m_ref, _MASKED)
@@ -239,10 +259,10 @@ def _attn_kernel(bq, bk, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref):
 def _grad_kernel(bq, bk, q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
                  dq_ref, dk_ref, dv_ref):
     """The transpose of :func:`_attn_kernel` for one query block of a
-    group's heads, ``do_ref [d, rows]`` the cotangent of its ``o_ref``;
-    score tiles are recomputed.  ``dk_ref, dv_ref [T, d]`` float32 are
-    the whole key/value head's and stay in VMEM along the query
-    blocks."""
+    group's heads, ``do_ref [dv, rows]`` the cotangent of its ``o_ref``;
+    score tiles are recomputed.  ``dk_ref [T, d]`` and ``dv_ref [T, dv]``
+    float32 are the whole key/value head's and stay in VMEM along the
+    query blocks."""
     i = pl.program_id(1)
 
     @pl.when(i == 0)
@@ -297,16 +317,17 @@ def _call(kernel, interpret, semantics, ins, outs, scratch=()):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
 def _attention(bq, bk, interpret, scope, q, k, v):
-    """``q [n_kv, nq, rep * bq, d]``, ``k, v [n_kv, T, d]``, all in the
-    products' dtype -> ``o [n_kv, nq, d, rep * bq]`` float32."""
+    """``q [n_kv, nq, rep * bq, d]``, ``k [n_kv, T, d]``, ``v [n_kv, T,
+    dv]``, all in the products' dtype -> ``o [n_kv, nq, dv, rep * bq]``
+    float32."""
     return _forward(bq, bk, interpret, q, k, v)[0]
 
 
 def _forward(bq, bk, interpret, q, k, v):
     """``o`` (transposed) and the rows' log-sum-exp ``[n_kv, nq, 1, rep *
     bq]``."""
-    n_kv, nq, rows, d = q.shape
-    outs = [jax.ShapeDtypeStruct((n_kv, nq, d, rows), _F32),
+    n_kv, nq, rows, _ = q.shape
+    outs = [jax.ShapeDtypeStruct((n_kv, nq, v.shape[-1], rows), _F32),
             jax.ShapeDtypeStruct((n_kv, nq, 1, rows), _F32)]
     return _call(functools.partial(_attn_kernel, bq, bk), interpret,
                  ("parallel", "parallel"), (q, k, v), outs,

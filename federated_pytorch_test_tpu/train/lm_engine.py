@@ -8,8 +8,10 @@ sequence.  The model's routing counts ride in the engine's per-client
 non-parameter state (``ClientState.batch_stats``), summed over the steps,
 and ``round_fields`` turns them into the round record's ``tokens``,
 ``block_kind``, ``moe_pairs_local``, ``moe_load_max_over_mean`` and
-``moe_dropped`` and ``mtp_loss`` (the summed multi-token-prediction term
-of a model that has such a layer, else 0), and adds the model's own
+``moe_dropped``, ``mtp_loss`` (the summed multi-token-prediction term
+of a model that has such a layer, else 0) and ``mhc_marginal_err`` (the
+worst marginal error of a model's hyper-connection mixing matrices,
+averaged over the round's steps, else 0), and adds the model's own
 ``impl_fields``: which implementations its shapes take on this backend
 (``attn_impl``, ``gdn_scan_impl``).  The trainer names no model: a
 decoder is a ``BlockModule`` whose ``__call__(ids, labels)`` returns
@@ -39,14 +41,14 @@ from federated_pytorch_test_tpu.train.engine import (
 
 #: the counters kept per client, all sums over local steps
 _COUNTERS = ("steps", "moe_pairs_local", "moe_dropped", "moe_load_sum",
-             "mtp_loss_sum")
-_FLOAT_COUNTERS = ("moe_load_sum", "mtp_loss_sum")
+             "mtp_loss_sum", "mhc_err_sum")
+_FLOAT_COUNTERS = ("moe_load_sum", "mtp_loss_sum", "mhc_err_sum")
 
 
 class LMTrainer(BlockwiseFederatedTrainer):
     """Federated next-token training of a ``BlockModule`` whose
     ``__call__(ids)`` returns ``(logits, aux)`` (``models/qwen3_next.py``,
-    ``models/glm4_moe_lite.py``).
+    ``models/glm4_moe_lite.py``, ``models/xing4_0.py``).
     No L1/L2 term on any block; evaluation is the mean test loss."""
 
     obs_engine = "lm"
@@ -106,7 +108,9 @@ class LMTrainer(BlockwiseFederatedTrainer):
                + aux["moe_load_max_over_mean"],
                "mtp_loss_sum": bs["mtp_loss_sum"] + (
                    weighted_mean(aux["mtp_loss"], wb) if "mtp_loss" in aux
-                   else 0.0)}
+                   else 0.0),
+               "mhc_err_sum": bs["mhc_err_sum"]
+               + aux.get("mhc_marginal_err", 0.0)}
         return weighted_mean(per_seq, wb), new
 
     def eval_batch_metric(self, p, bs, xb, yb, wb):
@@ -137,6 +141,7 @@ class LMTrainer(BlockwiseFederatedTrainer):
                 "moe_dropped": int(d["moe_dropped"]),
                 "moe_load_max_over_mean": d["moe_load_sum"] / steps,
                 "mtp_loss": d["mtp_loss_sum"],
+                "mhc_marginal_err": d["mhc_err_sum"] / steps,
                 **self.model.impl_fields(self.data.tokens_per_sample)}
 
     def _block_index(self, ci: int) -> int:

@@ -1,12 +1,16 @@
 """Causal attention as a Pallas kernel pair (``ops/flash_attention.py``),
 on the CPU in interpret mode: forward and gradient against the XLA path
-and against a plain float32 softmax, causality bit for bit, the rule that
-picks the path, and the round field that reports it.  (Both kernels are
+and against a plain float32 softmax (keys and values of one width, and
+192-wide keys beside 128-wide values), causality bit for bit, the rule
+that picks the path, the round field that reports it, and the lowered
+programs of the two decoders whose widths are equal, which this file
+pins to the commit before the widths came apart.  (Both kernels are
 compiled at the published widths for a described v5e in
 ``tests/test_gated_delta_kernel.py``, which holds the topology fixture.)
 """
 
 import functools
+import hashlib
 import math
 import os
 import sys
@@ -45,11 +49,11 @@ def rel(a, b):
     return float(jnp.max(jnp.abs(a - b)) / (jnp.max(jnp.abs(b)) + 1e-30))
 
 
-def attn_inputs(T, n_kv, rep, d, seed=0):
+def attn_inputs(T, n_kv, rep, d, seed=0, dv=None):
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     q = jax.random.normal(ks[0], (T, n_kv, rep, d)) / math.sqrt(d)
     return (q, jax.random.normal(ks[1], (T, n_kv, d)),
-            jax.random.normal(ks[2], (T, n_kv, d)))
+            jax.random.normal(ks[2], (T, n_kv, dv or d)))
 
 
 def plain_softmax(q, k, v):
@@ -105,6 +109,53 @@ def test_kernel_path_matches_the_softmax_and_the_xla_path(T, d, rep, dtype):
         assert rel(got, want) < GRAD_TOL[dtype]
         # and no further from it than the XLA path's own gradient
         assert rel(got, want) < 2.0 * rel(xla, want) + 1e-5
+
+
+@pytest.mark.parametrize("dtype", [F32, BF16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("rep,d,dv", [(1, 192, 128), (4, 192, 128),
+                                      (1, 64, 128), (2, 128, 256)])
+def test_keys_and_values_of_different_widths(rep, d, dv, dtype):
+    """192-wide keys beside 128-wide values (latent attention of the
+    DeepSeek-V3 line), a key narrower than a lane tile, and values wider
+    than the keys: ``q`` and ``k`` reach the kernels zero-padded to the
+    next multiple of 128, ``v`` at its own width, and the result and all
+    three gradients are those of the softmax at the widths given."""
+    T = 512
+    args = attn_inputs(T, 2, rep, d, dv=dv)
+    f = functools.partial(kernels, dtype)
+    jaxpr = str(jax.make_jaxpr(f)(*args))
+    assert jaxpr.count("pallas_call") == 1
+    padded = d + (-d) % 128
+    assert f"{padded}]" in jaxpr and (padded == d or "pad" in jaxpr)
+    o, g = with_grad(f, args)
+    o_x, g_x = with_grad(functools.partial(xla_path, dtype), args)
+    o_w, g_w = with_grad(plain_softmax, args)
+    assert o.shape == (T, 2, rep, dv) == o_x.shape == o_w.shape
+    assert rel(o, o_w) < TOL[dtype]
+    assert rel(o, o_x) < (1e-6 if dtype == F32 else 1e-2)
+    for got, xla, want, arg in zip(g, g_x, g_w, args):
+        assert got.shape == arg.shape == want.shape and got.dtype == F32
+        assert rel(got, want) < GRAD_TOL[dtype]
+        assert rel(got, want) < 2.0 * rel(xla, want) + 1e-5
+
+
+def test_padding_the_keys_is_exact():
+    """A zero column adds nothing to a score: the kernels at 192 / 128
+    give bit for bit what they give on operands padded by hand to 256,
+    and the padded columns get no gradient."""
+    q, k, v = attn_inputs(256, 2, 1, 192, dv=128)
+    wide = lambda a: jnp.pad(a, ((0, 0),) * (a.ndim - 1) + ((0, 64),))
+    f = functools.partial(kernels, BF16)
+    assert np.array_equal(np.asarray(f(q, k, v)),
+                          np.asarray(f(wide(q), wide(k), v)))
+    loss = lambda *a: jnp.sum(f(*a) ** 2)
+    g = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    gw = jax.grad(loss, argnums=(0, 1, 2))(wide(q), wide(k), v)
+    for a, b in zip(g, gw):
+        assert np.array_equal(np.asarray(a),
+                              np.asarray(b[..., :a.shape[-1]]))
+    assert float(jnp.max(jnp.abs(gw[0][..., 192:]))) == 0.0
+    assert float(jnp.max(jnp.abs(gw[1][..., 192:]))) == 0.0
 
 
 @pytest.mark.parametrize("dtype", [F32, BF16], ids=["float32", "bfloat16"])
@@ -204,6 +255,27 @@ def test_without_a_tpu_the_xla_path_runs():
                               *attn_inputs(256, 1, 2, 128))
 
 
+def test_plan_for_192_wide_keys_and_128_wide_values():
+    """The published shape of the third decoder: 32 heads, one query
+    head a key head, 2,048 tokens."""
+    with fa.force_attn_impl("pallas"):
+        p = fa.plan(2048, 32, 1, 192, BF16, 128)
+        same = fa.plan(2048, 32, 1, 256, BF16, 256)
+        wide = fa.plan(2048, 32, 1, 256, BF16)
+        narrow_v = fa.plan(2048, 32, 1, 192, BF16, 64)
+    assert p["impl"] == "pallas" and p["why"] == "fits"
+    assert (p["pad_k"], p["block_q"], p["block_k"]) == (64, 256, 256)
+    # the estimate is that of keys of 256 beside values of 128: smaller
+    # than both at 256
+    assert 0 < p["vmem_bytes"] < same["vmem_bytes"] <= p["vmem_budget"]
+    assert p["vmem_bytes"] == fa._grad_vmem_bytes(2048, 256, 256, 256, 2, 128)
+    # equal widths: nothing is padded and the answer is what it was
+    assert same == wide and same["pad_k"] == 0
+    # values that are no multiple of 128 still fall to the XLA path
+    assert narrow_v["impl"] == "xla" and "128" in narrow_v["why"]
+    assert fa.plan(2048, 32, 1, 192, BF16, 128)["why"] == "no TPU"
+
+
 @pytest.mark.parametrize("T,rep,dtype,block_q,block_k", [
     (4096, 8, BF16, 128, 256),      # the published shape: 1,024 rows a step
     (4096, 1, BF16, 256, 256),
@@ -279,3 +351,63 @@ def test_fields_declare_attn_impl():
     validate_record(dict(base, attn_impl="pallas"))
     with pytest.raises(SchemaError, match="attn_impl"):
         validate_record(dict(base, attn_impl=1))
+
+
+# ----------------------------------------------------------------------
+# the decoders whose keys and values are equally wide: nothing moved
+# ----------------------------------------------------------------------
+#: sha256 of the lowered (StableHLO) forward and loss gradient of both
+#: sibling decoders at their cells' shapes (the configuration files'
+#: widths, batch and sequence), recorded on commit 0b8930e, the parent of
+#: the PR that let the key and value widths differ, with
+#: ``lowered_hashes`` below run on that tree under this file's own
+#: pytest set-up (``conftest.py``'s flags are part of a lowered module).
+#: ``pallas_interpret`` lowers the kernels' own bodies on the CPU,
+#: ``xla`` the XLA path.
+PARENT_LOWERED = {
+    "qwen3_next/pallas_interpret/forward": "f7d27a527e242f01",
+    "qwen3_next/pallas_interpret/loss_grad": "4c17b8322989a570",
+    "qwen3_next/xla/loss_grad": "0e565a1dbe4a7c57",
+    "glm4_moe_lite/pallas_interpret/forward": "4922d8ac4a5fa781",
+    "glm4_moe_lite/pallas_interpret/loss_grad": "49d3a98bd7d17231",
+    "glm4_moe_lite/xla/loss_grad": "916975caa0b1346e",
+}
+
+
+def lowered_hashes(cell_name):
+    from benchmarks.engines import decoder
+    from benchmarks.lib import cells
+    from federated_pytorch_test_tpu.ops import gated_delta as gd
+
+    cfg = cells.load_cell(cell_name).config
+    model = decoder.build_model(cfg)
+    ids = jax.ShapeDtypeStruct((int(cfg["batch"]), int(cfg["seq_len"])),
+                               jnp.int32)
+    params = jax.eval_shape(lambda: model.init_variables(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))[0])
+    sha = lambda f, *ops: hashlib.sha256(
+        jax.jit(f).lower(*ops).as_text().encode()).hexdigest()[:16]
+    out = {}
+    for impl, what in (("pallas_interpret", ("forward", "loss_grad")),
+                       ("xla", ("loss_grad",))):
+        with fa.force_attn_impl(impl), gd.force_gdn_scan_impl(impl):
+            # fresh functions: a jitted one would answer from the trace
+            # it made under the other implementation
+            if "forward" in what:
+                out[f"{cfg['model']}/{impl}/forward"] = sha(
+                    lambda p, x: model.apply({"params": p}, x)[0], params,
+                    ids)
+            out[f"{cfg['model']}/{impl}/loss_grad"] = sha(
+                jax.value_and_grad(lambda p, x, y: jnp.mean(
+                    model.apply({"params": p}, x, y)[0])), params, ids, ids)
+    return out
+
+
+@pytest.mark.parametrize("cell", ["qwen3next_fedavg_blocks",
+                                  "glm47flash_fedavg_mtp_blocks"])
+def test_equal_widths_lower_byte_for_byte_as_before(cell):
+    """``dk == dv`` a multiple of 128: the lowered programs of both
+    sibling models, at their cells' shapes, are the parent commit's."""
+    got = lowered_hashes(cell)
+    assert got and set(got) <= set(PARENT_LOWERED)
+    assert got == {k: PARENT_LOWERED[k] for k in got}

@@ -249,13 +249,6 @@ def test_model_through_the_attention_kernels_matches_the_xla_path(block):
         assert rel(g, w) < 2e-4, path
 
 
-def test_value_heads_must_be_as_wide_as_key_heads():
-    model = tiny_model(v_head_dim=8)
-    with pytest.raises(ValueError, match="v_head_dim"):
-        model.init_variables(jax.random.PRNGKey(0),
-                             jnp.zeros((1, 8), jnp.int32))
-
-
 # ----------------------------------------------------------------------
 # the router's rule and the expert-parallel share
 # ----------------------------------------------------------------------

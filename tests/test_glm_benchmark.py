@@ -79,15 +79,16 @@ def test_the_cell_and_its_entries_in_benchmark_json():
     entry = next(w for w in b["workloads"] if w["name"] == CELL)
     assert (entry["config"], entry["traffic"], entry["chips"]) == (
         cell.config_name, cell.traffic_name, 1) and len(entry["why"]) <= 200
-    assert b["workloads"][-1] is entry and len(b["workloads"]) == 5
-    conf = b["configs"][-1]
+    # the fifth cell and the fourth configuration; later PRs add after them
+    assert b["workloads"][4] is entry and len(b["workloads"]) >= 5
+    conf = b["configs"][3]
     assert conf["name"] == cell.config_name == cell.config["name"]
     assert conf["reduced"] == cell.config["reduced"]
     assert conf["source"] == cell.config["source"]
     assert conf["file"] == f"benchmarks/configs/{cell.config_name}.json"
     new = [m for m in b["per_layer"] if m.get("workloads") == [CELL]]
     assert [m["name"] for m in new] == NEW == [
-        m["name"] for m in b["per_layer"][-4:]]
+        m["name"] for m in b["per_layer"][22:26]]
     assert all(m["moves"] == "samples_per_s_chip" and m["unit"] == "%"
                for m in new)
     # the cell reports every metric without a list, and its own four
@@ -101,7 +102,7 @@ def test_the_cell_and_its_entries_in_benchmark_json():
     assert (cell.config["K"], cell.config["batch"],
             cell.config["seq_len"]) == (2, 2, 4096)
     assert cell.config["engine"] == "decoder"
-    # one cell in five on four chips
+    # one cell on four chips
     assert sum(w["chips"] == 4 for w in b["workloads"]) == 1
 
 
