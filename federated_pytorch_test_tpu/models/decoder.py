@@ -11,6 +11,7 @@ its own layer and residual path.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict, Tuple
 
@@ -204,26 +205,46 @@ def held_experts(cfg, p, x, w, e, n_experts: int):
     """The routed part of an expert layer on this chip: of the pairs
     ``w, e [T, k]`` (every token's weights and experts among all
     ``n_experts``) those that hit ``cfg.experts_held`` experts from
-    ``cfg.ep_rank * cfg.experts_held``, sorted by expert, through
+    ``cfg.ep_rank * cfg.experts_held``, sorted by expert into a buffer of
+    ``rows`` rows (``cfg.pair_rows_factor`` x the mean count, at most
+    every pair that can exist), through
     ``p["experts_gate" | "experts_up" | "experts_down"]`` and added into
-    ``y [T, H]``; ``-> (y, routing)``."""
-    T, H = x.shape
+    ``y [T, H]``; ``-> (y, routing)``.  The buffer is sized for the
+    worst traffic; what moves the tokens' rows into it and the experts'
+    rows out of it (``ops/moe.py``: ``dispatch``, ``combine``) visits the
+    filled rows only."""
+    T = x.shape[0]
     E, k = cfg.experts_held, e.shape[-1]
     rows = int(math.ceil(cfg.pair_rows_factor * T * k * E / n_experts
                          / 8.0)) * 8
     rows = min(rows, T * min(k, E))
     with jax.named_scope("moe_route"):
         r = moelib.route_local(w, e, cfg.ep_rank * E, E, rows)
-        xs = x[r.token]
+    xs = moelib.dispatch(x, r)
     with jax.named_scope("moe_experts"):
         gm = lambda a, wt: moelib.grouped_matmul(a, wt, r.group_sizes,
                                                  cfg.dtype)
         h = jax.nn.silu(gm(xs, p["experts_gate"])) * gm(xs, p["experts_up"])
         ys = gm(h, p["experts_down"])
-    with jax.named_scope("moe_route"):
-        ys = jnp.where(r.weight[:, None] > 0, ys * r.weight[:, None], 0.0)
-        y = jnp.zeros((T, H), _F32).at[r.token].add(ys)
-    return y, r
+    return moelib.combine(ys, r, T), r
+
+
+def routing_counts(r):
+    """What a step keeps of one expert layer's routing ``r``: the pairs
+    that hit a held expert, those of them that found no row, the load
+    ratio, and the pair buffer's (static) rows."""
+    return r.pairs_local, r.dropped, r.load_max_over_mean, r.token.shape[0]
+
+
+def moe_aux(routed):
+    """A step's ``aux`` counters from the :func:`routing_counts` of its
+    expert layers: sums over the layers, the worst layer's load ratio."""
+    pairs, dropped, load, rows = zip(*routed) if routed else ((),) * 4
+    return {"moe_pairs_local": sum(pairs, jnp.int32(0)),
+            "moe_dropped": sum(dropped, jnp.int32(0)),
+            "moe_load_max_over_mean": functools.reduce(jnp.maximum, load,
+                                                       _F32(0)),
+            "moe_rows": sum(rows, jnp.int32(0))}
 
 
 def sequence_loss(logits, labels):

@@ -49,7 +49,6 @@ positions that have one (``aux["mtp_loss"]``, per sequence, unweighted).
 
 from __future__ import annotations
 
-import functools
 from typing import Any, Dict, List
 
 import flax.linen as nn
@@ -67,7 +66,9 @@ from federated_pytorch_test_tpu.models.decoder import (
     dense_mlp_leaves,
     latent_attention,
     mla_leaves,
+    moe_aux,
     rms_norm,
+    routing_counts,
     sequence_loss,
     sigmoid_expert_layer as expert_layer,
     sigmoid_moe_leaves,
@@ -233,7 +234,7 @@ def decoder_layer(cfg: Glm4MoeLite, pm, pf, x, outer: str = ""):
         flat = rms_norm(h, pf["norm"], eps).reshape(B * T, H)
         if "router" in pf:
             y, r = expert_layer(cfg, pf, flat)
-            return y, (r.pairs_local, r.dropped, r.load_max_over_mean)
+            return y, routing_counts(r)
         return dense_mlp(cfg, pf, flat), None
 
     h = x + jax.lax.map(mix, x)
@@ -268,19 +269,14 @@ def mtp_layer(cfg: Glm4MoeLite, p, x, nxt):
 def forward(cfg: Glm4MoeLite, p, ids, labels=None):
     """``ids [B, T]`` -> ``(logits [B, T, V], aux)``, or with ``labels``
     ``(loss per sequence [B], aux)``."""
-    routed = []          # (pairs, dropped, load) of each expert layer
+    routed = []          # each expert layer's routing_counts
     x = p["embed"]["embedding"][ids]
     for i, kind in enumerate(cfg.layer_kinds()):
         x, counts = decoder_layer(cfg, p[f"layer{i}_mixer"],
                                   p[f"layer{i}_{kind}"], x)
         routed += [counts] if counts is not None else []
 
-    def aux(**more):
-        pairs, dropped, load = zip(*routed) if routed else ((), (), ())
-        return {"moe_pairs_local": sum(pairs, jnp.int32(0)),
-                "moe_dropped": sum(dropped, jnp.int32(0)),
-                "moe_load_max_over_mean": functools.reduce(
-                    jnp.maximum, load, _F32(0)), **more}
+    aux = lambda **more: {**moe_aux(routed), **more}
 
     if labels is None:
         return head_logits(cfg, p, x, p["head"]["norm"]), aux()

@@ -59,8 +59,10 @@ from federated_pytorch_test_tpu.models.decoder import (  # noqa: F401
     _normal,
     apply_rope,
     held_experts,
+    moe_aux,
     next_token_loss,
     rope_tables,
+    routing_counts,
     sequence_loss,
     weighted_mean,
 )
@@ -341,19 +343,14 @@ def forward(cfg: Qwen3Next, p, ids, labels=None):
 
         h = x + jax.lax.map(mix, x)
         y, r = experts(h)
-        return h + y.reshape(B, T, H), (r.pairs_local, r.dropped,
-                                        r.load_max_over_mean)
+        return h + y.reshape(B, T, H), routing_counts(r)
 
     x = p["embed"]["embedding"][ids]
-    pairs = dropped = jnp.int32(0)
-    load = _F32(0)
+    routed = []
     for i, kind in enumerate(cfg.layer_kinds()):
-        x, (pl, dr, ld) = layer(kind, p[f"layer{i}_mixer"],
-                                p[f"layer{i}_moe"], x)
-        pairs, dropped = pairs + pl, dropped + dr
-        load = jnp.maximum(load, ld)
-    aux = {"moe_pairs_local": pairs, "moe_dropped": dropped,
-           "moe_load_max_over_mean": load}
+        x, counts = layer(kind, p[f"layer{i}_mixer"], p[f"layer{i}_moe"], x)
+        routed.append(counts)
+    aux = moe_aux(routed)
 
     def head(xt):
         with jax.named_scope("lm_head_loss"):

@@ -48,7 +48,6 @@ published model is not built: a configuration with
 
 from __future__ import annotations
 
-import functools
 import math
 from typing import Any, Dict, List
 
@@ -68,7 +67,9 @@ from federated_pytorch_test_tpu.models.decoder import (
     dense_mlp_leaves,
     latent_attention,
     mla_leaves,
+    moe_aux,
     rms_norm,
+    routing_counts,
     sequence_loss,
     sigmoid_expert_layer as expert_layer,
     sigmoid_moe_leaves,
@@ -311,8 +312,7 @@ def decoder_layer(cfg: Xing4, pm, pf, X):
         flat = rms_norm(u, pf["norm"], eps).reshape(B * T, H)
         if "router" in pf:
             y, r = expert_layer(cfg, pf, flat)
-            return y.reshape(B, T, H), (r.pairs_local, r.dropped,
-                                        r.load_max_over_mean)
+            return y.reshape(B, T, H), routing_counts(r)
         return dense_mlp(cfg, pf, flat).reshape(B, T, H), None
 
     X, err_m, _ = sub_layer(cfg, pm, mix, X)
@@ -332,12 +332,7 @@ def forward(cfg: Xing4, p, ids, labels=None):
         err = jnp.maximum(err, e)
         routed += [counts] if counts is not None else []
     x = jnp.sum(X, axis=0)
-    pairs, dropped, load = zip(*routed) if routed else ((), (), ())
-    aux = {"moe_pairs_local": sum(pairs, jnp.int32(0)),
-           "moe_dropped": sum(dropped, jnp.int32(0)),
-           "moe_load_max_over_mean": functools.reduce(jnp.maximum, load,
-                                                      _F32(0)),
-           "mhc_marginal_err": err}
+    aux = {**moe_aux(routed), "mhc_marginal_err": err}
 
     def logits_of(a):
         with jax.named_scope("lm_head_loss"):

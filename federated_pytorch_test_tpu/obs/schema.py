@@ -177,6 +177,9 @@ FIELDS: Dict[str, Any] = {
     # moe_load_max_over_mean: most loaded held expert over the held mean,
     # worst layer, mean over steps.  moe_dropped: pairs that found no row
     # in the sorted pair buffer (ops/moe.py); 0, or `correct` fails.
+    # moe_fill_share: pairs that found a row / rows of the steps' pair
+    # buffers, over layers, steps, clients: the share of the buffer that
+    # dispatch and combine visit (0.0 for a model without experts).
     # mtp_loss: the multi-token-prediction term of the loss, unweighted,
     # summed over the round's steps and clients as `loss` is (0.0 where
     # the model has no such layer).  mhc_marginal_err: the largest
@@ -188,6 +191,7 @@ FIELDS: Dict[str, Any] = {
     "moe_pairs_local": (("round",), _INT),
     "moe_load_max_over_mean": (("round",), _NUM),
     "moe_dropped":  (("round",), _INT),
+    "moe_fill_share": (("round",), _NUM),
     "mtp_loss":     (("round",), _NUM),
     "mhc_marginal_err": (("round",), _NUM),
     # what ran the delta rule's chunk recurrence (ops/gated_delta.py:

@@ -15,6 +15,22 @@ Mosaic kernel whose work follows the group sizes).  A pair that finds no
 row is counted in ``dropped`` and never silently lost: the caller sizes
 ``rows`` for its traffic and the benchmark's ``correct`` fails on a
 non-zero count.
+
+:func:`dispatch` copies the tokens' rows into that buffer and
+:func:`combine` adds the experts' weighted rows back into the tokens.
+The buffer is sized for the worst traffic and is mostly empty (the sort
+puts the ``n = min(pairs_local, rows)`` filled rows first), so both, and
+both transposes, are loops over chunks of ``CHUNK`` rows whose trip
+count is ``ceil(n / CHUNK)``: data, read on the device, because the
+pair count is known only once the router has run.  Nothing
+differentiates through a loop (each function is one ``jax.custom_vjp``
+whose rule is such a loop; its ops carry the call's ``moe_route`` scope
+in their paths, as JAX's own transposes do).  What they promise about
+the rows from ``n`` on: ``dispatch`` and the cotangent of ``combine``
+hold exact zeros there, and nothing reads them in ``combine``'s operand
+or in ``dispatch``'s cotangent (a NaN there reaches no output).  A full
+buffer runs every chunk and costs what the whole-buffer gather and
+scatter cost, plus the loop.
 """
 
 from __future__ import annotations
@@ -157,3 +173,129 @@ def _gm_bwd(dtype, res, dy):
 
 
 grouped_matmul.defvjp(_gm_fwd, _gm_bwd)
+
+
+#: rows of the pair buffer that one iteration of a loop below moves
+CHUNK = 512
+
+
+def filled_rows(routing: Routing):
+    """``n`` () int32: the buffer's rows below ``n`` hold a pair."""
+    return jnp.minimum(routing.pairs_local, routing.token.shape[0])
+
+
+def _over_chunks(token, n, step, init):
+    """``init`` through ``step(carry, lo, token[lo:lo + c], fresh, filled)``
+    for the ``ceil(n / c)`` chunks of ``c = min(CHUNK, rows)`` rows that
+    hold a filled row.  The last chunk is moved back to end with the
+    buffer, so it may repeat rows of the one before: ``filled [c]`` marks
+    the rows below ``n`` (repeated ones among them: writing a row twice
+    is harmless), ``fresh [c]`` those of them no earlier chunk held
+    (adding a row twice is not)."""
+    rows = token.shape[0]
+    c = min(CHUNK, rows)
+
+    def body(i, carry):
+        lo = jnp.minimum(i * c, rows - c)
+        at = lo + jnp.arange(c)
+        filled = at < n
+        return step(carry, lo, lax.dynamic_slice(token, (lo,), (c,)),
+                    filled & (at >= i * c), filled)
+
+    return lax.fori_loop(0, (n + c - 1) // c, body, init)
+
+
+def _rows_at(a, lo, c):
+    return lax.dynamic_slice(a, (lo, 0), (c, a.shape[1]))
+
+
+def _masked(keep, a):
+    return jnp.where(keep[:, None], a, 0.0)
+
+
+def _weights_at(weight, lo, keep):
+    """``weight[lo:lo + c]``, 0 outside ``keep [c]``."""
+    return jnp.where(keep, lax.dynamic_slice(weight, (lo,), keep.shape), 0.0)
+
+
+def _weighted(w, ys):
+    # the routing weight of a filled row is positive; one that
+    # underflowed to 0 takes its row out, as an empty row's does
+    return jnp.where(w[:, None] > 0, ys * w[:, None], 0.0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _dispatch(T, x, token, n):
+    def step(xs, lo, t, fresh, filled):
+        return lax.dynamic_update_slice(xs, _masked(filled, x[t]), (lo, 0))
+
+    return _over_chunks(token, n, step,
+                        jnp.zeros((token.shape[0], x.shape[1]), x.dtype))
+
+
+def _dispatch_fwd(T, x, token, n):
+    return _dispatch(T, x, token, n), (token, n)
+
+
+def _dispatch_bwd(T, res, dxs):
+    token, n = res
+
+    def step(dx, lo, t, fresh, filled):
+        return dx.at[t].add(_masked(fresh, _rows_at(dxs, lo, t.shape[0])))
+
+    dx = _over_chunks(token, n, step, jnp.zeros((T, dxs.shape[1]), dxs.dtype))
+    return dx, None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _combine(T, ys, weight, token, n):
+    def step(y, lo, t, fresh, filled):
+        return y.at[t].add(_weighted(_weights_at(weight, lo, fresh),
+                                     _rows_at(ys, lo, t.shape[0])))
+
+    return _over_chunks(token, n, step, jnp.zeros((T, ys.shape[1]), ys.dtype))
+
+
+def _combine_fwd(T, ys, weight, token, n):
+    return _combine(T, ys, weight, token, n), (ys, weight, token, n)
+
+
+def _combine_bwd(T, res, dy):
+    ys, weight, token, n = res
+
+    def step(carry, lo, t, fresh, filled):
+        dys, dw = carry
+        w, g = _weights_at(weight, lo, filled), dy[t]
+        at_w = jnp.sum(_masked(w > 0, _rows_at(ys, lo, t.shape[0])) * g, -1)
+        return (lax.dynamic_update_slice(dys, _weighted(w, g), (lo, 0)),
+                lax.dynamic_update_slice(dw, at_w, (lo,)))
+
+    dys, dw = _over_chunks(token, n, step, (jnp.zeros_like(ys),
+                                            jnp.zeros_like(weight)))
+    return dys, dw, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def dispatch(x, routing: Routing):
+    """``xs [rows, H]``: ``x[token[i]]`` in the filled rows of the pair
+    buffer, exact zeros from :func:`filled_rows` on.  Its transpose adds
+    the filled rows' cotangents into their tokens and reads no other
+    row."""
+    with jax.named_scope("moe_route"):
+        return _dispatch(x.shape[0], x, routing.token, filled_rows(routing))
+
+
+def combine(ys, routing: Routing, T: int):
+    """``y [T, H]``: row ``t`` is the float32 sum of ``weight[i] *
+    ys[i]`` over the filled rows ``i`` of token ``t``; no row from
+    :func:`filled_rows` on is read.  Its transpose gives ``weight[i] *
+    dy[token[i]]`` in the filled rows of ``ys``' cotangent and exact
+    zeros in the rest, and ``sum(ys[i] * dy[token[i]])`` to the weights."""
+    with jax.named_scope("moe_route"):
+        return _combine(T, ys, routing.weight, routing.token,
+                        filled_rows(routing))

@@ -7,8 +7,11 @@ the block switch and the recorder are the engine's own.  A batch is
 sequence.  The model's routing counts ride in the engine's per-client
 non-parameter state (``ClientState.batch_stats``), summed over the steps,
 and ``round_fields`` turns them into the round record's ``tokens``,
-``block_kind``, ``moe_pairs_local``, ``moe_load_max_over_mean`` and
-``moe_dropped``, ``mtp_loss`` (the summed multi-token-prediction term
+``block_kind``, ``moe_pairs_local``, ``moe_load_max_over_mean``,
+``moe_dropped`` and ``moe_fill_share`` (the pairs that found a row over
+the rows of the steps' pair buffers: how much of what ``ops/moe.py``
+sizes for the worst traffic its loops visit; 0 for a model without
+experts), ``mtp_loss`` (the summed multi-token-prediction term
 of a model that has such a layer, else 0) and ``mhc_marginal_err`` (the
 worst marginal error of a model's hyper-connection mixing matrices,
 averaged over the round's steps, else 0), and adds the model's own
@@ -40,8 +43,8 @@ from federated_pytorch_test_tpu.train.engine import (
 )
 
 #: the counters kept per client, all sums over local steps
-_COUNTERS = ("steps", "moe_pairs_local", "moe_dropped", "moe_load_sum",
-             "mtp_loss_sum", "mhc_err_sum")
+_COUNTERS = ("steps", "moe_pairs_local", "moe_dropped", "moe_rows",
+             "moe_load_sum", "mtp_loss_sum", "mhc_err_sum")
 _FLOAT_COUNTERS = ("moe_load_sum", "mtp_loss_sum", "mhc_err_sum")
 
 
@@ -104,6 +107,7 @@ class LMTrainer(BlockwiseFederatedTrainer):
                "moe_pairs_local": bs["moe_pairs_local"]
                + aux["moe_pairs_local"],
                "moe_dropped": bs["moe_dropped"] + aux["moe_dropped"],
+               "moe_rows": bs["moe_rows"] + aux["moe_rows"],
                "moe_load_sum": bs["moe_load_sum"]
                + aux["moe_load_max_over_mean"],
                "mtp_loss_sum": bs["mtp_loss_sum"] + (
@@ -140,6 +144,8 @@ class LMTrainer(BlockwiseFederatedTrainer):
                 "moe_pairs_local": int(d["moe_pairs_local"]),
                 "moe_dropped": int(d["moe_dropped"]),
                 "moe_load_max_over_mean": d["moe_load_sum"] / steps,
+                "moe_fill_share": (d["moe_pairs_local"] - d["moe_dropped"])
+                / max(d["moe_rows"], 1.0),
                 "mtp_loss": d["mtp_loss_sum"],
                 "mhc_marginal_err": d["mhc_err_sum"] / steps,
                 **self.model.impl_fields(self.data.tokens_per_sample)}

@@ -58,13 +58,13 @@ def tiny_model(**kw):
                                       "dtype": jnp.float32, **kw})
 
 
-def lm_trainer(blocks, Nadmm=2, samples=2, **cfg_kw):
+def lm_trainer(blocks, Nadmm=2, samples=2, model=None, **cfg_kw):
     data = FederatedTokens(K=2, batch=2, samples_per_client=samples,
                            seq_len=T, vocab=64, seed=3, head=16)
     cfg = FederatedConfig(K=2, Nloop=1, Nepoch=1, Nadmm=Nadmm,
                           default_batch=2, check_results=False, lr=1e-3,
                           num_devices=1, save_model=False, **cfg_kw)
-    t = LMTrainer(tiny_model(), cfg, data, FedAvg())
+    t = LMTrainer(model or tiny_model(), cfg, data, FedAvg())
     t.block_ids = [t.block_ids[b] for b in blocks]
     t.L = len(blocks)
     return t
@@ -145,6 +145,28 @@ def test_a_model_without_an_mtp_layer_reports_zero():
     t.close()
     assert [r["mtp_loss"] for r in hist] == [0.0]
     assert {"gdn_scan_impl", "attn_impl"} <= set(hist[0])
+
+
+@pytest.mark.parametrize("experts", [True, False],
+                         ids=["experts", "no_experts"])
+def test_moe_fill_share_is_pairs_over_rows(experts):
+    # without: one dense layer and no MTP layer, no expert layer at all
+    t = lm_trainer([ATTN], Nadmm=1) if experts else lm_trainer(
+        [1], Nadmm=1, model=glm_tiny_model(layers=1,
+                                           num_nextn_predict_layers=0))
+    _, hist = t.run(log=lambda m: None)
+    t.close()
+    (rec,) = hist
+    if not experts:
+        assert rec["moe_fill_share"] == 0.0 and rec["moe_pairs_local"] == 0
+        return
+    # a step is 2 x T tokens with top-3 of 16 experts, 4 of them held:
+    # pair_rows_factor 8 asks for 288 rows a layer and every pair that
+    # can exist is 2 T x 3; two layers, one step, two clients
+    rows = 2 * T * 3 * 2 * 1 * 2
+    assert rec["moe_dropped"] == 0 and 0 < rec["moe_pairs_local"] < rows
+    assert rec["moe_fill_share"] == pytest.approx(
+        rec["moe_pairs_local"] / rows, rel=1e-6)
 
 
 def test_the_trainer_names_no_model():

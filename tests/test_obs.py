@@ -155,7 +155,7 @@ class TestSchema:
         validate_record(round_record(loss=float("nan")))
 
 
-#: the fields PRs 24-32 added: (kinds, a valid value, an ill-typed one,
+#: the fields PRs 24-35 added: (kinds, a valid value, an ill-typed one,
 #: advisory or core).  A new field is one more line here (and one in
 #: obs/schema.py: README "Observability", "how to add a field").
 DECLARED = {
@@ -168,6 +168,7 @@ DECLARED = {
     "moe_pairs_local": (("round",), 1024, 0.5, False),
     "moe_load_max_over_mean": (("round",), 1.6, "high", False),
     "moe_dropped": (("round",), 0, "none", False),
+    "moe_fill_share": (("round",), 0.125, "an eighth", False),
     "mtp_loss": (("round",), 9.87, "high", False),
     "gdn_scan_impl": (("round",), "pallas", 1, True),
     "attn_impl": (("round",), "xla", 0, True),
