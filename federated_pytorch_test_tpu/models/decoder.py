@@ -19,6 +19,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from federated_pytorch_test_tpu.obs.scopes import scope
 from federated_pytorch_test_tpu.ops import moe as moelib
 from federated_pytorch_test_tpu.ops.flash_attention import causal_attention
 
@@ -113,24 +114,35 @@ def latent_attention(cfg, p, x, outer: str = "", scale=None, inv_freq=None):
     eps = cfg.rms_norm_eps
     if scale is None:
         scale = 1.0 / math.sqrt(dn + dr)
-    c_q = rms_norm(_mm(cfg, x, p["q_a_proj"]), p["q_a_norm"], eps)
-    q = _mm(cfg, c_q, p["q_b_proj"]).reshape(T, n, dn + dr)
-    kv_a = _mm(cfg, x, p["kv_a_proj"])
-    # the norm is the latent's; the rotary key, shared by every head,
-    # goes by it untouched
-    c_kv = rms_norm(kv_a[:, :cfg.kv_lora_rank], p["kv_a_norm"], eps)
-    k_rope = kv_a[:, cfg.kv_lora_rank:].reshape(T, 1, dr)
-    kv = _mm(cfg, c_kv, p["kv_b_proj"]).reshape(T, n, dn + dv)
-    cos, sin = rope_tables(T, dr, cfg.rope_theta, inv_freq)
-    q = jnp.concatenate([q[..., :dn], apply_rope(q[..., dn:], cos, sin)], -1)
-    k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(
-        apply_rope(k_rope, cos, sin), (T, n, dr))], -1)
-    q = q.reshape(T, n, 1, dn + dr) * scale
-    with jax.named_scope("mla_core"):
+    # the scopes alternate so that the operations keep their order
+    with scope("attn_proj_in"):
+        c_q = _mm(cfg, x, p["q_a_proj"])
+    with scope("attn_norm_rope"):
+        c_q = rms_norm(c_q, p["q_a_norm"], eps)
+    with scope("attn_proj_in"):
+        q = _mm(cfg, c_q, p["q_b_proj"]).reshape(T, n, dn + dr)
+        kv_a = _mm(cfg, x, p["kv_a_proj"])
+        c_kv = kv_a[:, :cfg.kv_lora_rank]
+    with scope("attn_norm_rope"):
+        # the norm is the latent's; the rotary key, shared by every head,
+        # goes by it untouched
+        c_kv = rms_norm(c_kv, p["kv_a_norm"], eps)
+    with scope("attn_proj_in"):
+        k_rope = kv_a[:, cfg.kv_lora_rank:].reshape(T, 1, dr)
+        kv = _mm(cfg, c_kv, p["kv_b_proj"]).reshape(T, n, dn + dv)
+    with scope("attn_norm_rope"):
+        cos, sin = rope_tables(T, dr, cfg.rope_theta, inv_freq)
+        q = jnp.concatenate([q[..., :dn],
+                             apply_rope(q[..., dn:], cos, sin)], -1)
+        k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(
+            apply_rope(k_rope, cos, sin), (T, n, dr))], -1)
+        q = q.reshape(T, n, 1, dn + dr) * scale
+    with scope("mla_core"):
         o = causal_attention(q, k, kv[..., dn:], dtype=cfg.dtype,
                              block=cfg.attn_block,
                              scope=outer + "mla_attn/mla_core")
-    return _mm(cfg, o.reshape(T, n * dv), p["o_proj"])
+    with scope("attn_proj_out"):
+        return _mm(cfg, o.reshape(T, n * dv), p["o_proj"])
 
 
 def mla_leaves(cfg):
@@ -177,7 +189,7 @@ def sigmoid_moe_leaves(cfg):
 
 def dense_mlp(cfg, p, x):
     """``x [T, H]`` (already normed) -> ``[T, H]``."""
-    with jax.named_scope("dense_mlp"):
+    with scope("dense_mlp"):
         h = jax.nn.silu(_mm(cfg, x, p["gate_proj"])) \
             * _mm(cfg, x, p["up_proj"])
         return _mm(cfg, h, p["down_proj"])
@@ -188,13 +200,13 @@ def sigmoid_expert_layer(cfg, p, x):
     scores, the top-k by score + selection bias, weights without the
     bias (``ops/moe.py``), the held experts' terms and one ungated
     shared expert."""
-    with jax.named_scope("moe_route"):
+    with scope("moe_route"), scope("route_scores"):
         logits = jnp.dot(x, p["router"], precision=jax.lax.Precision.HIGHEST)
         w, e = moelib.sigmoid_router_weights(
             logits, p["router_bias"], cfg.num_experts_per_tok,
             cfg.norm_topk_prob, cfg.routed_scaling_factor)
     y, r = held_experts(cfg, p, x, w, e, cfg.n_routed_experts)
-    with jax.named_scope("moe_shared"):
+    with scope("moe_shared"):
         hs = jax.nn.silu(_mm(cfg, x, p["shared_gate_proj"])) \
             * _mm(cfg, x, p["shared_up"])
         y = y + _mm(cfg, hs, p["shared_down"])
@@ -218,10 +230,10 @@ def held_experts(cfg, p, x, w, e, n_experts: int):
     rows = int(math.ceil(cfg.pair_rows_factor * T * k * E / n_experts
                          / 8.0)) * 8
     rows = min(rows, T * min(k, E))
-    with jax.named_scope("moe_route"):
+    with scope("moe_route"), scope("route_sort"):
         r = moelib.route_local(w, e, cfg.ep_rank * E, E, rows)
     xs = moelib.dispatch(x, r)
-    with jax.named_scope("moe_experts"):
+    with scope("moe_experts"):
         gm = lambda a, wt: moelib.grouped_matmul(a, wt, r.group_sizes,
                                                  cfg.dtype)
         h = jax.nn.silu(gm(xs, p["experts_gate"])) * gm(xs, p["experts_up"])
@@ -250,7 +262,7 @@ def moe_aux(routed):
 def sequence_loss(logits, labels):
     """Mean cross-entropy of ``logits [..., T, V]`` against ``labels
     [..., T]`` over ``T``, in float32."""
-    with jax.named_scope("lm_head_loss"):
+    with scope("lm_head_loss"), scope("head_softmax"):
         logits = logits.astype(_F32)
         lse = jax.nn.logsumexp(logits, axis=-1)
         picked = jnp.take_along_axis(logits, labels[..., None], -1)[..., 0]

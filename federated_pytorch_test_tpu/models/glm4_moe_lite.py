@@ -73,6 +73,7 @@ from federated_pytorch_test_tpu.models.decoder import (
     sigmoid_expert_layer as expert_layer,
     sigmoid_moe_leaves,
 )
+from federated_pytorch_test_tpu.obs.scopes import scope
 from federated_pytorch_test_tpu.ops.flash_attention import plan as attn_plan
 
 
@@ -224,29 +225,35 @@ def decoder_layer(cfg: Glm4MoeLite, pm, pf, x, outer: str = ""):
     # sequence by sequence, as in models/qwen3_next.py
     @jax.checkpoint
     def mix(xt):
-        with jax.named_scope("mla_attn"):
-            return latent_attention(cfg, pm, rms_norm(xt, pm["norm"], eps),
-                                    outer)
+        with scope("mla_attn"):
+            with scope("sublayer_norm"):
+                xn = rms_norm(xt, pm["norm"], eps)
+            return latent_attention(cfg, pm, xn, outer)
 
     @jax.checkpoint
     def ffn(h):
         # tokens are independent here: one batch of B * T
-        flat = rms_norm(h, pf["norm"], eps).reshape(B * T, H)
+        with scope("sublayer_norm"):
+            flat = rms_norm(h, pf["norm"], eps).reshape(B * T, H)
         if "router" in pf:
             y, r = expert_layer(cfg, pf, flat)
             return y, routing_counts(r)
         return dense_mlp(cfg, pf, flat), None
 
-    h = x + jax.lax.map(mix, x)
-    y, counts = ffn(h)
-    return h + y.reshape(B, T, H), counts
+    with scope("sublayer_mixer"):
+        h = x + jax.lax.map(mix, x)
+    with scope("sublayer_ffn"):
+        y, counts = ffn(h)
+        return h + y.reshape(B, T, H), counts
 
 
 def head_logits(cfg: Glm4MoeLite, p, x, norm):
     """``x [..., H]`` through the norm ``norm`` and the model's head."""
-    with jax.named_scope("lm_head_loss"):
-        return _mm(cfg, rms_norm(x, norm, cfg.rms_norm_eps),
-                   p["head"]["kernel"])
+    with scope("lm_head_loss"):
+        with scope("head_norm"):
+            xn = rms_norm(x, norm, cfg.rms_norm_eps)
+        with scope("head_product"):
+            return _mm(cfg, xn, p["head"]["kernel"])
 
 
 def mtp_layer(cfg: Glm4MoeLite, p, x, nxt):
@@ -257,11 +264,12 @@ def mtp_layer(cfg: Glm4MoeLite, p, x, nxt):
 
     @jax.checkpoint
     def merge(h, t):
-        both = jnp.concatenate([
-            rms_norm(p["embed"]["embedding"][t], pm["enorm"], eps),
-            rms_norm(h, pm["hnorm"], eps)], -1)
-        return _mm(cfg, both.reshape(-1, both.shape[-1]),
-                   pm["eh_proj"]).reshape(h.shape)
+        with scope("mtp_merge"):
+            both = jnp.concatenate([
+                rms_norm(p["embed"]["embedding"][t], pm["enorm"], eps),
+                rms_norm(h, pm["hnorm"], eps)], -1)
+            return _mm(cfg, both.reshape(-1, both.shape[-1]),
+                       pm["eh_proj"]).reshape(h.shape)
 
     return decoder_layer(cfg, pm, p["mtp_moe"], merge(x, nxt), "mtp/")
 
@@ -270,13 +278,16 @@ def forward(cfg: Glm4MoeLite, p, ids, labels=None):
     """``ids [B, T]`` -> ``(logits [B, T, V], aux)``, or with ``labels``
     ``(loss per sequence [B], aux)``."""
     routed = []          # each expert layer's routing_counts
-    x = p["embed"]["embedding"][ids]
+    with scope("embed"):
+        x = p["embed"]["embedding"][ids]
     for i, kind in enumerate(cfg.layer_kinds()):
         x, counts = decoder_layer(cfg, p[f"layer{i}_mixer"],
                                   p[f"layer{i}_{kind}"], x)
         routed += [counts] if counts is not None else []
 
-    aux = lambda **more: {**moe_aux(routed), **more}
+    def aux(**more):
+        with scope("step_stats"):
+            return {**moe_aux(routed), **more}
 
     if labels is None:
         return head_logits(cfg, p, x, p["head"]["norm"]), aux()
@@ -285,7 +296,7 @@ def forward(cfg: Glm4MoeLite, p, ids, labels=None):
     loss = jax.lax.map(one, (x, labels))
     mtp = jnp.zeros_like(loss)
     if cfg.num_nextn_predict_layers:
-        with jax.named_scope("mtp"):
+        with scope("mtp"):
             T = ids.shape[1]
             # position i holds t_{i+1} = labels[i] and predicts
             # t_{i+2} = labels[i + 1]; the last position has no target
@@ -297,7 +308,7 @@ def forward(cfg: Glm4MoeLite, p, ids, labels=None):
             @jax.checkpoint
             def one_mtp(a):
                 logits = head_logits(cfg, p, a[0], p["mtp_moe"]["head_norm"])
-                with jax.named_scope("lm_head_loss"):
+                with scope("lm_head_loss"), scope("head_softmax"):
                     lse = jax.nn.logsumexp(logits, axis=-1)
                     picked = jnp.take_along_axis(
                         logits, a[1][:, None], -1)[:, 0]

@@ -66,6 +66,7 @@ from federated_pytorch_test_tpu.models.decoder import (  # noqa: F401
     sequence_loss,
     weighted_mean,
 )
+from federated_pytorch_test_tpu.obs.scopes import scope
 from federated_pytorch_test_tpu.ops import moe as moelib
 from federated_pytorch_test_tpu.ops.flash_attention import (
     causal_attention,
@@ -248,20 +249,22 @@ def gated_attention(cfg: Qwen3Next, p, x):
     """``x [T, H]`` (already normed) -> ``[T, H]``."""
     T = x.shape[0]
     nq, nkv, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
-    qg = _mm(cfg, x, p["q_proj"]).reshape(T, nq, 2 * d)
-    q, gate = qg[..., :d], qg[..., d:]
-    k = _mm(cfg, x, p["k_proj"]).reshape(T, nkv, d)
-    v = _mm(cfg, x, p["v_proj"]).reshape(T, nkv, d)
-    q = rms_norm(q, p["q_norm"], cfg.rms_norm_eps)
-    k = rms_norm(k, p["k_norm"], cfg.rms_norm_eps)
-    cos, sin = rope_tables(T, int(d * cfg.partial_rotary_factor),
-                           cfg.rope_theta)
-    q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
-    q = q.reshape(T, nkv, nq // nkv, d) * (1.0 / math.sqrt(d))
-    o = causal_attention(q, k, v, dtype=cfg.dtype,
-                         block=cfg.attn_block).reshape(T, nq, d)
-    o = o * jax.nn.sigmoid(gate)
-    return _mm(cfg, o.reshape(T, nq * d), p["o_proj"])
+    with scope("attn_proj_in"):
+        qg = _mm(cfg, x, p["q_proj"]).reshape(T, nq, 2 * d)
+        q, gate = qg[..., :d], qg[..., d:]
+        k = _mm(cfg, x, p["k_proj"]).reshape(T, nkv, d)
+        v = _mm(cfg, x, p["v_proj"]).reshape(T, nkv, d)
+    with scope("attn_norm_rope"):
+        q = rms_norm(q, p["q_norm"], cfg.rms_norm_eps)
+        k = rms_norm(k, p["k_norm"], cfg.rms_norm_eps)
+        cos, sin = rope_tables(T, int(d * cfg.partial_rotary_factor),
+                               cfg.rope_theta)
+        q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+        q = q.reshape(T, nkv, nq // nkv, d) * (1.0 / math.sqrt(d))
+    o = causal_attention(q, k, v, dtype=cfg.dtype, block=cfg.attn_block)
+    with scope("attn_proj_out"):
+        o = o.reshape(T, nq, d) * jax.nn.sigmoid(gate)
+        return _mm(cfg, o.reshape(T, nq * d), p["o_proj"])
 
 
 def gated_delta_net(cfg: Qwen3Next, p, x):
@@ -270,46 +273,52 @@ def gated_delta_net(cfg: Qwen3Next, p, x):
     nk, nv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
     dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
     conv_dim = 2 * nk * dk + nv * dv
-    qkvz = _mm(cfg, x, p["in_proj_qkvz"])
-    qkv, z = qkvz[:, :conv_dim], qkvz[:, conv_dim:].reshape(T, nv, dv)
-    # b, a feed a sigmoid and the state decay: float32 products
-    ba = jnp.dot(x, p["in_proj_ba"], precision=jax.lax.Precision.HIGHEST)
-    b, a = ba[:, :nv], ba[:, nv:]
-    # causal depthwise convolution, kernel taps oldest first, then SiLU
-    kw = cfg.linear_conv_kernel_dim
-    padded = jnp.pad(qkv, ((kw - 1, 0), (0, 0)))
-    qkv = jax.nn.silu(sum(padded[j:j + T] * p["conv"][j]
-                          for j in range(kw)))
-    q = qkv[:, :nk * dk].reshape(T, nk, dk)
-    k = qkv[:, nk * dk:2 * nk * dk].reshape(T, nk, dk)
-    v = qkv[:, 2 * nk * dk:].reshape(T, nv, dv)
-    unit = lambda t: t * jax.lax.rsqrt(
-        jnp.sum(t * t, -1, keepdims=True) + 1e-6)
-    q, k = unit(q) * (1.0 / math.sqrt(dk)), unit(k)
-    # each key head serves nv / nk value heads
-    q, k = (jnp.repeat(t, nv // nk, axis=1) for t in (q, k))
-    beta = jax.nn.sigmoid(b)
-    g = -jnp.exp(p["A_log"]) * jax.nn.softplus(a + p["dt_bias"])
+    with scope("gdn_in_proj"):
+        qkvz = _mm(cfg, x, p["in_proj_qkvz"])
+        qkv, z = qkvz[:, :conv_dim], qkvz[:, conv_dim:].reshape(T, nv, dv)
+        # b, a feed a sigmoid and the state decay: float32 products
+        ba = jnp.dot(x, p["in_proj_ba"], precision=jax.lax.Precision.HIGHEST)
+        b, a = ba[:, :nv], ba[:, nv:]
+    with scope("gdn_conv"):
+        # causal depthwise convolution, kernel taps oldest first, then SiLU
+        kw = cfg.linear_conv_kernel_dim
+        padded = jnp.pad(qkv, ((kw - 1, 0), (0, 0)))
+        qkv = jax.nn.silu(sum(padded[j:j + T] * p["conv"][j]
+                              for j in range(kw)))
+    with scope("gdn_qk_prep"):
+        q = qkv[:, :nk * dk].reshape(T, nk, dk)
+        k = qkv[:, nk * dk:2 * nk * dk].reshape(T, nk, dk)
+        v = qkv[:, 2 * nk * dk:].reshape(T, nv, dv)
+        unit = lambda t: t * jax.lax.rsqrt(
+            jnp.sum(t * t, -1, keepdims=True) + 1e-6)
+        q, k = unit(q) * (1.0 / math.sqrt(dk)), unit(k)
+        # each key head serves nv / nk value heads
+        q, k = (jnp.repeat(t, nv // nk, axis=1) for t in (q, k))
+        beta = jax.nn.sigmoid(b)
+        g = -jnp.exp(p["A_log"]) * jax.nn.softplus(a + p["dt_bias"])
+    # the moves to heads-first have always been the scan's own
     heads = lambda t: jnp.moveaxis(t, 1, 0)
-    with jax.named_scope("gdn_scan"):
+    with scope("gdn_scan"):
         o = gated_delta_chunked(heads(q), heads(k), heads(v), heads(g),
                                 heads(beta), chunk=cfg.chunk,
                                 dtype=cfg.dtype)
-    o = jnp.moveaxis(o, 0, 1)                              # [T, nv, dv]
-    o = p["out_norm"] * o * jax.lax.rsqrt(
-        jnp.mean(o * o, -1, keepdims=True) + cfg.rms_norm_eps)
-    o = o * jax.nn.silu(z)
-    return _mm(cfg, o.reshape(T, nv * dv), p["out_proj"])
+    with scope("gdn_out_gate"):
+        o = jnp.moveaxis(o, 0, 1)                          # [T, nv, dv]
+        o = p["out_norm"] * o * jax.lax.rsqrt(
+            jnp.mean(o * o, -1, keepdims=True) + cfg.rms_norm_eps)
+        o = o * jax.nn.silu(z)
+    with scope("gdn_out_proj"):
+        return _mm(cfg, o.reshape(T, nv * dv), p["out_proj"])
 
 
 def expert_layer(cfg: Qwen3Next, p, x):
     """``x [T, H]`` (already normed) -> ``([T, H], routing)``."""
-    with jax.named_scope("moe_route"):
+    with scope("moe_route"), scope("route_scores"):
         logits = jnp.dot(x, p["router"], precision=jax.lax.Precision.HIGHEST)
         w, e = moelib.router_weights(logits, cfg.num_experts_per_tok,
                                      cfg.norm_topk_prob)
     y, r = held_experts(cfg, p, x, w, e, cfg.num_experts)
-    with jax.named_scope("moe_shared"):
+    with scope("moe_shared"):
         hs = jax.nn.silu(_mm(cfg, x, p["shared_gate_proj"])) \
             * _mm(cfg, x, p["shared_up"])
         gate = jax.nn.sigmoid(jnp.dot(x, p["shared_gate"],
@@ -332,30 +341,39 @@ def forward(cfg: Qwen3Next, p, ids, labels=None):
         # gigabyte, and only one sequence's are alive at a time
         @jax.checkpoint
         def mix(xt):
-            with jax.named_scope("gated_attn" if kind == "attn" else "gdn"):
-                return mixer(cfg, pm, rms_norm(xt, pm["norm"], eps))
+            with scope("gated_attn" if kind == "attn" else "gdn"):
+                with scope("sublayer_norm"):
+                    xn = rms_norm(xt, pm["norm"], eps)
+                return mixer(cfg, pm, xn)
 
         @jax.checkpoint
         def experts(h):
             # tokens are independent here: one batch of B * T
-            return expert_layer(cfg, pe, rms_norm(h, pe["norm"],
-                                                  eps).reshape(B * T, H))
+            with scope("sublayer_norm"):
+                hn = rms_norm(h, pe["norm"], eps).reshape(B * T, H)
+            return expert_layer(cfg, pe, hn)
 
-        h = x + jax.lax.map(mix, x)
-        y, r = experts(h)
-        return h + y.reshape(B, T, H), routing_counts(r)
+        with scope("sublayer_mixer"):
+            h = x + jax.lax.map(mix, x)
+        with scope("sublayer_ffn"):
+            y, r = experts(h)
+            return h + y.reshape(B, T, H), routing_counts(r)
 
-    x = p["embed"]["embedding"][ids]
+    with scope("embed"):
+        x = p["embed"]["embedding"][ids]
     routed = []
     for i, kind in enumerate(cfg.layer_kinds()):
         x, counts = layer(kind, p[f"layer{i}_mixer"], p[f"layer{i}_moe"], x)
         routed.append(counts)
-    aux = moe_aux(routed)
+    with scope("step_stats"):
+        aux = moe_aux(routed)
 
     def head(xt):
-        with jax.named_scope("lm_head_loss"):
-            return _mm(cfg, rms_norm(xt, p["head"]["norm"], eps),
-                       p["head"]["kernel"])
+        with scope("lm_head_loss"):
+            with scope("head_norm"):
+                xn = rms_norm(xt, p["head"]["norm"], eps)
+            with scope("head_product"):
+                return _mm(cfg, xn, p["head"]["kernel"])
 
     if labels is None:
         return head(x), aux

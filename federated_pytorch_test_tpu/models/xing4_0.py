@@ -76,6 +76,7 @@ from federated_pytorch_test_tpu.models.decoder import (
     yarn_inv_freq,
     yarn_softmax_scale,
 )
+from federated_pytorch_test_tpu.obs.scopes import scope
 from federated_pytorch_test_tpu.ops import hyper_connections as hc
 from federated_pytorch_test_tpu.ops.flash_attention import plan as attn_plan
 
@@ -280,11 +281,11 @@ def sub_layer(cfg: Xing4, p, f, X):
 
     @jax.checkpoint
     def run(X):
-        with jax.named_scope("mhc"):
+        with scope("mhc"):
             m = hyper_maps(cfg, p, X)
             u = hc.contract(m.pre, X)
         y, more = f(u)
-        with jax.named_scope("mhc"):
+        with scope("mhc"):
             return hc.expand(m.res, m.post, X, y), m.marginal_err, more
 
     return run(X)
@@ -296,48 +297,60 @@ def decoder_layer(cfg: Xing4, pm, pf, X):
     its leaves); ``-> (X, marginal error, routing counts or None)``."""
     eps = cfg.rms_norm_eps
     _, B, T, H = X.shape
-    scale, inv_freq = cfg.softmax_scale(), cfg.rope_inv_freq()
 
     def mix(u):
         # sequence by sequence: attention does not cross sequences
         def one(ut):
-            with jax.named_scope("mla_attn"):
-                return latent_attention(
-                    cfg, pm, rms_norm(ut, pm["norm"], eps), scale=scale,
-                    inv_freq=inv_freq)
+            with scope("mla_attn"):
+                with scope("sublayer_norm"):
+                    un = rms_norm(ut, pm["norm"], eps)
+                return latent_attention(cfg, pm, un, scale=scale,
+                                        inv_freq=inv_freq)
         return jax.lax.map(one, u), None
 
     def ffn(u):
         # tokens are independent here: one batch of B * T
-        flat = rms_norm(u, pf["norm"], eps).reshape(B * T, H)
+        with scope("sublayer_norm"):
+            flat = rms_norm(u, pf["norm"], eps).reshape(B * T, H)
         if "router" in pf:
             y, r = expert_layer(cfg, pf, flat)
             return y.reshape(B, T, H), routing_counts(r)
         return dense_mlp(cfg, pf, flat).reshape(B, T, H), None
 
-    X, err_m, _ = sub_layer(cfg, pm, mix, X)
-    X, err_f, counts = sub_layer(cfg, pf, ffn, X)
-    return X, jnp.maximum(err_m, err_f), counts
+    with scope("sublayer_mixer"):
+        scale, inv_freq = cfg.softmax_scale(), cfg.rope_inv_freq()
+        X, err_m, _ = sub_layer(cfg, pm, mix, X)
+    with scope("sublayer_ffn"):
+        X, err_f, counts = sub_layer(cfg, pf, ffn, X)
+    with scope("step_stats"):
+        return X, jnp.maximum(err_m, err_f), counts
 
 
 def forward(cfg: Xing4, p, ids, labels=None):
     """``ids [B, T]`` -> ``(logits [B, T, V], aux)``, or with ``labels``
     ``(loss per sequence [B], aux)``."""
     routed, err = [], _F32(0)
-    emb = p["embed"]["embedding"][ids]
-    X = jnp.broadcast_to(emb[None], (cfg.hc_mult,) + emb.shape)
+    with scope("embed"):
+        emb = p["embed"]["embedding"][ids]
+    with scope("hc_streams"):
+        X = jnp.broadcast_to(emb[None], (cfg.hc_mult,) + emb.shape)
     for i, kind in enumerate(cfg.layer_kinds()):
         X, e, counts = decoder_layer(cfg, p[f"layer{i}_mixer"],
                                      p[f"layer{i}_{kind}"], X)
-        err = jnp.maximum(err, e)
+        with scope("step_stats"):
+            err = jnp.maximum(err, e)
         routed += [counts] if counts is not None else []
-    x = jnp.sum(X, axis=0)
-    aux = {**moe_aux(routed), "mhc_marginal_err": err}
+    with scope("hc_streams"):
+        x = jnp.sum(X, axis=0)
+    with scope("step_stats"):
+        aux = {**moe_aux(routed), "mhc_marginal_err": err}
 
     def logits_of(a):
-        with jax.named_scope("lm_head_loss"):
-            return _mm(cfg, rms_norm(a, p["head"]["norm"], cfg.rms_norm_eps),
-                       p["head"]["kernel"])
+        with scope("lm_head_loss"):
+            with scope("head_norm"):
+                an = rms_norm(a, p["head"]["norm"], cfg.rms_norm_eps)
+            with scope("head_product"):
+                return _mm(cfg, an, p["head"]["kernel"])
 
     if labels is None:
         return logits_of(x), aux

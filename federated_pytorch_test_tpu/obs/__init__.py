@@ -8,6 +8,7 @@
 - :mod:`.health`   — streaming anomaly watchdog (``--health-action``).
 - :mod:`.compare`  — cross-run regression CLI (CI gate).
 - :mod:`.costs`    — per-jit-site compile and dispatch ledger.
+- :mod:`.scopes`   — the program's ``jax.named_scope`` names, one table.
 - :mod:`.clients`  — client-grain flight recorder: per-client ledgers,
   deterministic anomaly ranking, cohort rollups
   (``python -m federated_pytorch_test_tpu.obs.clients``).

@@ -15,7 +15,16 @@ that assembly at two points:
   moved across a dispatch the elapsed wall-seconds *are* the compile
   wall-seconds (plus O(100us) of dispatch overhead).  The same elapsed
   seconds of EVERY call add up to the window's ``dispatch_seconds``:
-  what the host spends enqueueing the round's programs.
+  what the host spends enqueueing the round's programs.  The slowest
+  single call of the window is kept with its site
+  (``dispatch_max_seconds``, ``dispatch_max_site``).
+- The same wrapper reads the jitted callable's ``_cache_size()`` before
+  and after the call.  A call after which it grew while the site's trace
+  counter stood still met a **new argument signature** (the same shapes
+  under another sharding, committedness or weak type): nothing is
+  retraced or compiled, but the dispatch leaves jax's C++ fast path, and
+  on a large program that holds the host for a second.  The window
+  counts them (``dispatch_new_signatures``).
 
 Per compile event the ledger records the site, wall-seconds, the two
 clock stamps and the site's cumulative trace count (1 == cold).  It asks
@@ -73,6 +82,11 @@ class RoundCosts(NamedTuple):
     # host seconds inside the window's instrumented jitted calls (the
     # timer's own t1 - t0: enqueue, plus trace + compile when it compiles)
     dispatch_seconds: float = 0.0
+    # calls that added an entry to their site's jit cache without a
+    # retrace: a new argument signature
+    new_signatures: int = 0
+    # the window's slowest single call: (site, seconds)
+    slowest: Tuple[str, float] = ("", 0.0)
 
 
 def round_cost_fields(costs: RoundCosts, t_start: float,
@@ -94,6 +108,11 @@ def round_cost_fields(costs: RoundCosts, t_start: float,
         # every instrumented call drained with this window, the ones of
         # the block switch before the round included
         out["dispatch_seconds"] = float(costs.dispatch_seconds)
+    if costs.slowest[1] > 0:
+        out["dispatch_max_site"], out["dispatch_max_seconds"] = (
+            costs.slowest[0], float(costs.slowest[1]))
+    if costs.new_signatures:
+        out["dispatch_new_signatures"] = int(costs.new_signatures)
     return out
 
 
@@ -109,6 +128,8 @@ class CostLedger:
         self._events: list = []  # pending (drained per round)
         self.all_events: list = []  # full run history (bench.py)
         self._dispatch_s = 0.0
+        self._new_signatures = 0
+        self._slowest: Tuple[str, float] = ("", 0.0)
 
     # ---------------------------------------------------------- wiring
 
@@ -129,10 +150,12 @@ class CostLedger:
         """Wrap the *jitted* callable with the compile-detecting timer."""
         marks = self._marks
         marks.setdefault(site, 0)
+        # a sanitized site is a plain wrapper and has no cache to read
+        cache_size = getattr(jfn, "_cache_size", lambda: 0)
 
         @functools.wraps(jfn)
         def timed(*args: Any, **kwargs: Any) -> Any:
-            n0 = marks.get(site, 0)
+            n0, c0 = marks.get(site, 0), cache_size()
             # Async dispatch: no block_until_ready on purpose — the
             # window must cover trace+compile (and, summed into
             # dispatch_seconds, the enqueue), NOT device execution.
@@ -144,7 +167,11 @@ class CostLedger:
                                   t_end=t1, trace_count=marks.get(site, 0))
                 self._events.append(ev)
                 self.all_events.append(ev)
+            elif cache_size() > c0:
+                self._new_signatures += 1
             self._dispatch_s += t1 - t0
+            if t1 - t0 > self._slowest[1]:
+                self._slowest = (site, t1 - t0)
             return out
 
         timed.__wrapped_jit__ = jfn  # the jitted fn itself, for tests
@@ -153,9 +180,13 @@ class CostLedger:
     def drain(self) -> RoundCosts:
         """Hand the pending window to the caller and reset it."""
         out = RoundCosts(events=tuple(self._events),
-                         dispatch_seconds=self._dispatch_s)
+                         dispatch_seconds=self._dispatch_s,
+                         new_signatures=self._new_signatures,
+                         slowest=self._slowest)
         self._events = []
         self._dispatch_s = 0.0
+        self._new_signatures = 0
+        self._slowest = ("", 0.0)
         return out
 
     # ------------------------------------------------------ aggregates

@@ -160,10 +160,18 @@ FIELDS: Dict[str, Any] = {
     # ledger drain, checkpoint, obs emission, log, on_round; plus the
     # switch where the block changed).  dispatch_seconds: host seconds
     # inside the instrumented jitted calls drained with the round
-    # (obs/costs.py; a compile is inside it), absent with cost_ledger off
+    # (obs/costs.py; a compile is inside it), absent with cost_ledger off.
+    # dispatch_max_seconds / dispatch_max_site: the slowest single one of
+    # those calls and its jit site.  dispatch_new_signatures: how many of
+    # them added an entry to their site's jit cache without a retrace
+    # (the same shapes under a new sharding or weak type: the dispatch
+    # leaves jax's C++ fast path); absent at 0
     "block_switch_seconds": (("round",), _NUM),
     "gap_seconds": (("round",), _NUM),
     "dispatch_seconds": (("round",), _NUM),
+    "dispatch_max_seconds": (("round",), _NUM),
+    "dispatch_max_site": (("round",), _STR),
+    "dispatch_new_signatures": (("round",), _INT),
     # bytes that block switch staged from host memory (same rounds as
     # block_switch_seconds): 0 but for a stateful compressor's fresh rows,
     # and 0 on a resumed segment's first round (the restore staged them)
@@ -372,6 +380,7 @@ ADVISORY_FIELDS = (
     "compile_seconds", "t_start", "t_end",
     # host timeline outside the round window
     "block_switch_seconds", "gap_seconds", "dispatch_seconds",
+    "dispatch_max_seconds", "dispatch_max_site", "dispatch_new_signatures",
     "block_switch_h2d_bytes",
     # which implementation this backend took for the recurrence and for
     # the attention core

@@ -1,0 +1,190 @@
+"""The program's ``jax.named_scope`` names, declared once.
+
+A scope writes its name into the ``op_name`` of every operation traced
+inside it (``jit(epoch_shard)/.../sublayer_mixer/.../gdn/gdn_conv/mul``)
+and adds no operation: the lowered program without locations is the same
+text with or without it (``tests/test_scope_names.py``).  A device trace
+carries that path with every executed instruction, so device time can be
+read by the names below; ``benchmarks/lib/scope_tree.py`` does, matching
+whole path segments.
+
+:data:`SCOPES` is the one table: the engine (``train/engine.py``,
+``train/lm_engine.py``, ``train/algorithms.py``), the three decoders
+(``models/decoder.py``, ``qwen3_next.py``, ``glm4_moe_lite.py``,
+``xing4_0.py``) and the ops they call open their scopes with
+:func:`scope`, which refuses a name that is not declared here.  A row is
+``(name, parents, programs, covers)``:
+
+- ``parents``: the declared scopes the name is opened directly inside
+  (``()``: at the top of its program).  A scope's *self time* is its
+  time less its children's, and the ``covers`` text says what that is
+  where a scope has children.
+- ``programs``: where it occurs: ``epoch`` (every cell's epoch program:
+  the engine's step), ``comm`` (the exchange program), ``decoder`` (all
+  three decoders) or a model's registered name.
+
+The fourteen names that stood before this table (:data:`KERNEL_SCOPES`)
+are what the benchmark's kernel readers match, by substring: no other
+name may hold one of them unless it is nested inside that scope
+(``gdn_conv`` inside ``gdn``).
+"""
+
+from __future__ import annotations
+
+from typing import NamedTuple, Tuple
+
+import jax
+
+__all__ = ["KERNEL_SCOPES", "NAMES", "SCOPES", "Scope", "scope"]
+
+
+class Scope(NamedTuple):
+    name: str
+    parents: Tuple[str, ...]
+    programs: Tuple[str, ...]
+    covers: str
+
+
+_DECODER = ("decoder",)
+_MIXERS = ("gdn", "gated_attn", "mla_attn")
+_FRAME = ("model_loss", "mtp")
+
+SCOPES: Tuple[Scope, ...] = (
+    # -- the engine's step (train/engine.py: adam_step, batch_loss) ------
+    Scope("step_prepare", (), ("epoch",),
+          "the step's key, prepare_batch, take_active"),
+    Scope("client_grad", (), ("epoch",),
+          "value_and_grad of one client's loss (self: where a trainer runs "
+          "its clients one after another, the loop's slices and stacks of "
+          "each client's arguments, and what the compiler hoists to it)"),
+    Scope("model_loss", ("client_grad",), ("epoch",),
+          "the trainer's model_loss, forward and backward: the whole model "
+          "(self: a classifier's layers; a decoder's are named below)"),
+    Scope("penalty", ("client_grad",), ("epoch",),
+          "get_trainable_values, algo.penalty, l1_l2, forward and backward"),
+    Scope("opt_update", (), ("epoch",),
+          "tx.update (Adam over the active leaves), apply_updates, "
+          "put_active"),
+    # -- the exchange (train/engine.py: comm_shard) ----------------------
+    Scope("exchange_flatten", (), ("comm",),
+          "the clients' active leaves as rows [K, N]; corruption, "
+          "compression, probes and guards where a run turns them on"),
+    Scope("exchange_update", (), ("comm",),
+          "algo.global_update: the z / dual update and the residual norms "
+          "(self), the Barzilai-Borwein rho update"),
+    Scope("exchange_reduce", ("exchange_update",), ("comm",),
+          "Algorithm._agg: the mean over the clients (psum over the mesh)"),
+    Scope("exchange_writeback", (), ("comm",),
+          "put_trainable_values of z into every client's leaves"),
+    # -- a decoder's frame -----------------------------------------------
+    Scope("embed", ("model_loss",), _DECODER,
+          "the embedding gather and its transposed scatter-add"),
+    Scope("hc_streams", ("model_loss",), ("xing4_0",),
+          "the embedding copied into the n streams; the streams' sum "
+          "before the head"),
+    Scope("sublayer_mixer", _FRAME, _DECODER,
+          "a whole mixer sub-layer (self: the residual add, the map over "
+          "sequences)"),
+    Scope("sublayer_ffn", _FRAME, _DECODER,
+          "a whole expert or dense sub-layer (self: the residual add, "
+          "routing_counts)"),
+    Scope("sublayer_norm", _MIXERS + ("sublayer_ffn",), _DECODER,
+          "the sub-layer's input RMS norm"),
+    Scope("step_stats", ("model_loss",), _DECODER,
+          "moe_aux, weighted_mean, the counters of LMTrainer.model_loss"),
+    Scope("mtp", ("model_loss",), ("glm4_moe_lite",),
+          "the multi-token-prediction layer, its head and loss term "
+          "(self: the targets' roll, the term's weight)"),
+    Scope("mtp_merge", ("mtp",), ("glm4_moe_lite",),
+          "[N(embedding of the next id) ; N(hidden)] through eh_proj"),
+    # -- mixers ----------------------------------------------------------
+    Scope("gdn", ("sublayer_mixer",), ("qwen3_next",),
+          "a Gated DeltaNet mixer (self: nothing)"),
+    Scope("gdn_in_proj", ("gdn",), ("qwen3_next",),
+          "in_proj_qkvz, in_proj_ba and their slices"),
+    Scope("gdn_conv", ("gdn",), ("qwen3_next",),
+          "the causal depthwise convolution and its SiLU"),
+    Scope("gdn_qk_prep", ("gdn",), ("qwen3_next",),
+          "the slices into q, k, v, unit norms, repeat, beta, g"),
+    Scope("gdn_scan", ("gdn",), ("qwen3_next",),
+          "the moves to heads-first; ops/gated_delta.py: the chunked delta "
+          "rule, kernels and chunk-local part"),
+    Scope("gdn_out_gate", ("gdn",), ("qwen3_next",),
+          "the move back, the output norm, the SiLU gate"),
+    Scope("gdn_out_proj", ("gdn",), ("qwen3_next",), "out_proj"),
+    Scope("gated_attn", ("sublayer_mixer",), ("qwen3_next",),
+          "a gated-attention mixer (self: the attention kernels, or the "
+          "XLA core)"),
+    Scope("mla_attn", ("sublayer_mixer",), ("glm4_moe_lite", "xing4_0"),
+          "a latent-attention mixer (self: nothing)"),
+    Scope("mla_core", ("mla_attn",), ("glm4_moe_lite", "xing4_0"),
+          "causal_attention of the latent mixer (self: the attention "
+          "kernels, or the XLA core)"),
+    Scope("attn_proj_in", ("gated_attn", "mla_attn"), _DECODER,
+          "the projections into the core (q, k, v; MLA: q_a, q_b, kv_a, "
+          "kv_b) and their slices"),
+    Scope("attn_norm_rope", ("gated_attn", "mla_attn"), _DECODER,
+          "the head norms (MLA: the latents' norms), rotary, the "
+          "softmax scale"),
+    Scope("attn_layout", ("gated_attn", "mla_core"), _DECODER,
+          "ops/flash_attention.py's wrapper: casts, padding, the "
+          "regrouping transposes before and after the kernels"),
+    Scope("attn_proj_out", ("gated_attn", "mla_attn"), _DECODER,
+          "o_proj, with the sigmoid gate where there is one"),
+    # -- feed-forward sub-layers -----------------------------------------
+    Scope("dense_mlp", ("sublayer_ffn",), ("glm4_moe_lite", "xing4_0"),
+          "the dense SwiGLU"),
+    Scope("moe_route", ("sublayer_ffn",), _DECODER,
+          "router to pair buffer and back (self: filled_rows)"),
+    Scope("route_scores", ("moe_route",), _DECODER,
+          "the router product, softmax or sigmoid, top_k"),
+    Scope("route_sort", ("moe_route",), _DECODER,
+          "route_local: the sort, bincount, indices, weights"),
+    Scope("pair_dispatch", ("moe_route",), _DECODER,
+          "ops/moe.py:dispatch's loop and its rule's"),
+    Scope("pair_combine", ("moe_route",), _DECODER,
+          "ops/moe.py:combine's loop and its rule's"),
+    Scope("pair_fill", ("pair_dispatch", "pair_combine"), _DECODER,
+          "the zero fills the four loops start from"),
+    Scope("moe_experts", ("sublayer_ffn",), _DECODER,
+          "the held experts (self: SiLU(gate) x up)"),
+    Scope("expert_cast", ("moe_experts",), _DECODER,
+          "operand()'s casts of rows, weights and cotangents"),
+    Scope("expert_products", ("moe_experts",), _DECODER,
+          "the grouped products (on a TPU the compiler's ragged-dot "
+          "kernels, which carry no path)"),
+    Scope("expert_mask", ("moe_experts",), _DECODER,
+          "_ragged's zeroing of the rows past the last group"),
+    Scope("moe_shared", ("sublayer_ffn",), _DECODER,
+          "the shared expert and its add"),
+    # -- hyper-connections (ops/hyper_connections.py) --------------------
+    Scope("mhc", ("sublayer_mixer", "sublayer_ffn"), ("xing4_0",),
+          "a sub-layer's hyper-connections (self: nothing)"),
+    Scope("mhc_maps", ("mhc",), ("xing4_0",),
+          "the maps: norm, projection, sigmoids, Sinkhorn"),
+    Scope("mhc_mix", ("mhc",), ("xing4_0",),
+          "contract and expand: the streams' mixing"),
+    # -- the head --------------------------------------------------------
+    Scope("lm_head_loss", _FRAME, _DECODER,
+          "final norm, head product, cross-entropy (self: nothing)"),
+    Scope("head_norm", ("lm_head_loss",), _DECODER, "the final RMS norm"),
+    Scope("head_product", ("lm_head_loss",), _DECODER,
+          "the head's matrix product and its casts"),
+    Scope("head_softmax", ("lm_head_loss",), _DECODER,
+          "logsumexp, the picked logit, the mean over the sequence"),
+)
+
+NAMES = frozenset(s.name for s in SCOPES)
+
+#: what the benchmark's kernel readers match by substring
+#: (``benchmarks/lib/scopes.py``, ``glm_work.py``, ``xing_work.py``)
+KERNEL_SCOPES = ("gdn", "gdn_scan", "gated_attn", "moe_route", "moe_experts",
+                 "moe_shared", "lm_head_loss", "dense_mlp", "mla_attn",
+                 "mla_core", "mtp", "mhc", "mhc_maps", "mhc_mix")
+
+
+def scope(name: str):
+    """``jax.named_scope(name)`` for a name of :data:`SCOPES`."""
+    if name not in NAMES:
+        raise ValueError(f"scope {name!r} is not declared in obs/scopes.py")
+    return jax.named_scope(name)

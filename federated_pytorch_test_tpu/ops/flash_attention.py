@@ -51,6 +51,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from federated_pytorch_test_tpu.obs import scopes
 from federated_pytorch_test_tpu.ops.moe import operand
 
 _F32 = jnp.float32
@@ -142,22 +143,26 @@ def causal_attention(q, k, v, *, dtype=jnp.bfloat16, block: int = 512,
     T, n_kv, rep, d = q.shape
     dv = v.shape[-1]
     p = plan(T, n_kv, rep, d, dtype, dv)
-    kc, vc = operand(k, dtype), operand(v, dtype)
+    with scopes.scope("attn_layout"):
+        kc, vc = operand(k, dtype), operand(v, dtype)
     if p["impl"] == "xla":
         return _blocks_against_all_keys(q, kc, vc, dtype, block)
     bq, nq = p["block_q"], T // p["block_q"]
-    qc = operand(q, dtype)
-    if p["pad_k"]:
-        qc, kc = (jnp.pad(a, ((0, 0),) * (a.ndim - 1) + ((0, p["pad_k"]),))
-                  for a in (qc, kc))
-        d += p["pad_k"]
-    # a group's heads side by side as the rows of one query block
-    qr = qc.reshape(nq, bq, n_kv, rep, d).transpose(
-        2, 0, 3, 1, 4).reshape(n_kv, nq, rep * bq, d)
+    with scopes.scope("attn_layout"):
+        qc = operand(q, dtype)
+        if p["pad_k"]:
+            qc, kc = (jnp.pad(a, ((0, 0),) * (a.ndim - 1)
+                              + ((0, p["pad_k"]),)) for a in (qc, kc))
+            d += p["pad_k"]
+        # a group's heads side by side as the rows of one query block
+        qr = qc.reshape(nq, bq, n_kv, rep, d).transpose(
+            2, 0, 3, 1, 4).reshape(n_kv, nq, rep * bq, d)
+        kt, vt = kc.transpose(1, 0, 2), vc.transpose(1, 0, 2)
     o = _attention(bq, p["block_k"], p["impl"] == "pallas_interpret", scope,
-                   qr, kc.transpose(1, 0, 2), vc.transpose(1, 0, 2))
-    return o.reshape(n_kv, nq, dv, rep, bq).transpose(1, 4, 0, 3, 2).reshape(
-        T, n_kv, rep, dv)
+                   qr, kt, vt)
+    with scopes.scope("attn_layout"):
+        return o.reshape(n_kv, nq, dv, rep, bq).transpose(
+            1, 4, 0, 3, 2).reshape(T, n_kv, rep, dv)
 
 
 def _blocks_against_all_keys(q, kc, vc, dtype, block):
@@ -167,8 +172,9 @@ def _blocks_against_all_keys(q, kc, vc, dtype, block):
     T, n_kv, rep, d = q.shape
     bq = min(block, T)
     pad = (-T) % bq
-    qp = jnp.pad(q, ((0, pad), (0, 0), (0, 0), (0, 0))).reshape(
-        -1, bq, n_kv, rep, d)
+    with scopes.scope("attn_layout"):
+        qp = jnp.pad(q, ((0, pad), (0, 0), (0, 0), (0, 0))).reshape(
+            -1, bq, n_kv, rep, d)
     pos_k = jnp.arange(T)
 
     @jax.checkpoint
@@ -182,8 +188,9 @@ def _blocks_against_all_keys(q, kc, vc, dtype, block):
                           preferred_element_type=_F32)
 
     starts = jnp.arange(qp.shape[0]) * bq
-    return lax.map(one, (qp, starts)).reshape(-1, n_kv, rep,
-                                              vc.shape[-1])[:T]
+    o = lax.map(one, (qp, starts))
+    with scopes.scope("attn_layout"):
+        return o.reshape(-1, n_kv, rep, vc.shape[-1])[:T]
 
 
 # ----------------------------------------------------------------------

@@ -52,6 +52,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from federated_pytorch_test_tpu.obs.scopes import scope
 from federated_pytorch_test_tpu.ops.moe import operand
 
 _HI = lax.Precision.HIGHEST
@@ -131,7 +132,7 @@ def _unit_lower_inverse_fwd(a):
 def _unit_lower_inverse_bwd(inv, d_inv):
     """``-T^T dT T^T`` for any cotangent ``dT``, full or triangular (the
     caller's mask on ``a`` transposes to the mask on this)."""
-    with jax.named_scope(_SCOPE):
+    with scope(_SCOPE):
         inv_t = jnp.swapaxes(inv, -1, -2)
         return (-_mm(inv_t, _mm(d_inv, inv_t)),)
 
@@ -371,7 +372,7 @@ def _recurrence_bwd(heads, interpret, res, do):
     # hold ``S * dS'`` summed over ``d_k``, and JAX's transpose of the
     # caller's broadcast sums them over ``d_v``
     outs = [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in res[:-1]]
-    with jax.named_scope(_SCOPE):
+    with scope(_SCOPE):
         return tuple(_chunk_call(_scan_grad_kernel, heads, interpret,
                                  (*res, do), outs, lambda n: N - 1 - n))
 

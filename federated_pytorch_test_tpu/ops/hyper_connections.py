@@ -32,6 +32,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from federated_pytorch_test_tpu.obs.scopes import scope
+
 _F32 = jnp.float32
 
 
@@ -65,7 +67,7 @@ def maps(x, leaves: Dict[str, jnp.ndarray], *, iters: int, eps: float,
     ``b_res [n, n]``."""
     n, C = x.shape[0], x.shape[-1]
     tokens = x.shape[1:-1]
-    with jax.named_scope("mhc_maps"):
+    with scope("mhc_maps"):
         x = x.astype(_F32)
         # rms over all n C entries of a token; it scales the projections
         # (v phi = r * (vec(X) phi)), so v itself is never written
@@ -91,7 +93,7 @@ def maps(x, leaves: Dict[str, jnp.ndarray], *, iters: int, eps: float,
 def contract(h_pre, x):
     """``H_pre X``: the sub-layer's input ``[..., C]`` from the streams
     ``x [n, ..., C]`` and ``h_pre [n, ...]``."""
-    with jax.named_scope("mhc_mix"):
+    with scope("mhc_mix"):
         return jnp.sum(h_pre[..., None] * x, axis=0)
 
 
@@ -99,7 +101,7 @@ def expand(h_res, h_post, x, y):
     """``H_res X + H_post^T y``: the next streams ``[n, ..., C]`` from
     the streams ``x``, the sub-layer's output ``y [..., C]``, ``h_res [n,
     n, ...]`` and ``h_post [n, ...]``."""
-    with jax.named_scope("mhc_mix"):
+    with scope("mhc_mix"):
         n = x.shape[0]
         return jnp.stack([
             sum(h_res[j, i][..., None] * x[i] for i in range(n))

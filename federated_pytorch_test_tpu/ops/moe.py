@@ -24,8 +24,10 @@ both transposes, are loops over chunks of ``CHUNK`` rows whose trip
 count is ``ceil(n / CHUNK)``: data, read on the device, because the
 pair count is known only once the router has run.  Nothing
 differentiates through a loop (each function is one ``jax.custom_vjp``
-whose rule is such a loop; its ops carry the call's ``moe_route`` scope
-in their paths, as JAX's own transposes do).  What they promise about
+whose rule is such a loop; its ops carry the call's scopes in their
+paths, ``moe_route`` > ``pair_dispatch`` / ``pair_combine``, as JAX's
+own transposes do, and the zeros a loop starts from ``pair_fill``
+below that: ``obs/scopes.py``).  What they promise about
 the rows from ``n`` on: ``dispatch`` and the cotangent of ``combine``
 hold exact zeros there, and nothing reads them in ``combine``'s operand
 or in ``dispatch``'s cotangent (a NaN there reaches no output).  A full
@@ -42,6 +44,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.custom_batching import sequential_vmap
+
+from federated_pytorch_test_tpu.obs.scopes import scope
 
 
 class Routing(NamedTuple):
@@ -125,20 +129,26 @@ def _ragged(x, w, group_sizes):
     # other: rows past the last group hold whatever the buffer held
     # (seen on the v5e: a gradient 1e9 times too large), so they are
     # zeroed here, as the CPU's lowering leaves them
-    y = lax.ragged_dot(x, w, group_sizes, preferred_element_type=jnp.float32)
-    return jnp.where(_grouped_rows(x.shape[0], group_sizes), y, 0.0)
+    with scope("expert_products"):
+        y = lax.ragged_dot(x, w, group_sizes,
+                           preferred_element_type=jnp.float32)
+    with scope("expert_mask"):
+        return jnp.where(_grouped_rows(x.shape[0], group_sizes), y, 0.0)
 
 
 def _ragged_vjp(x, w, group_sizes, dy):
     """``(dx [rows, k], dw [groups, k, n])`` in float32: ``dy`` against
     each row's expert, and each expert's rows against their ``dy``."""
-    dx = _ragged(dy, jnp.swapaxes(w, 1, 2), group_sizes)
-    dw = lax.ragged_dot_general(
-        x, dy, group_sizes,
-        lax.RaggedDotDimensionNumbers(
-            dot_dimension_numbers=(((0,), (0,)), ((), ())),
-            lhs_ragged_dimensions=[0], rhs_group_dimensions=[]),
-        preferred_element_type=jnp.float32)
+    with scope("expert_products"):
+        wt = jnp.swapaxes(w, 1, 2)
+    dx = _ragged(dy, wt, group_sizes)
+    with scope("expert_products"):
+        dw = lax.ragged_dot_general(
+            x, dy, group_sizes,
+            lax.RaggedDotDimensionNumbers(
+                dot_dimension_numbers=(((0,), (0,)), ((), ())),
+                lhs_ragged_dimensions=[0], rhs_group_dimensions=[]),
+            preferred_element_type=jnp.float32)
     return dx, dw
 
 
@@ -156,19 +166,20 @@ def grouped_matmul(x, w, group_sizes, dtype=jnp.bfloat16):
     last group read 0.  ``x [rows, k]`` and ``w [groups, k, n]`` are
     float32 and multiplied in ``dtype``; the result and both cotangents
     are float32 (an expert's weight gradient is summed in float32)."""
-    return _ragged_seq(operand(x, dtype), operand(w, dtype), group_sizes)
+    return _gm_fwd(x, w, group_sizes, dtype)[0]
 
 
 def _gm_fwd(x, w, group_sizes, dtype):
-    xc = operand(x, dtype)
-    return (_ragged_seq(xc, operand(w, dtype), group_sizes),
-            (xc, w, group_sizes))
+    with scope("expert_cast"):
+        xc, wc = operand(x, dtype), operand(w, dtype)
+    return _ragged_seq(xc, wc, group_sizes), (xc, w, group_sizes)
 
 
 def _gm_bwd(dtype, res, dy):
     xc, w, group_sizes = res
-    dx, dw = _ragged_vjp_seq(xc, operand(w, dtype), group_sizes,
-                             operand(dy, dtype))
+    with scope("expert_cast"):
+        wc, dyc = operand(w, dtype), operand(dy, dtype)
+    dx, dw = _ragged_vjp_seq(xc, wc, group_sizes, dyc)
     return dx, dw, None
 
 
@@ -205,6 +216,12 @@ def _over_chunks(token, n, step, init):
     return lax.fori_loop(0, (n + c - 1) // c, body, init)
 
 
+def _fill(shape, dtype):
+    """The zeros a loop starts from, under a scope of their own."""
+    with scope("pair_fill"):
+        return jnp.zeros(shape, dtype)
+
+
 def _rows_at(a, lo, c):
     return lax.dynamic_slice(a, (lo, 0), (c, a.shape[1]))
 
@@ -230,7 +247,7 @@ def _dispatch(T, x, token, n):
         return lax.dynamic_update_slice(xs, _masked(filled, x[t]), (lo, 0))
 
     return _over_chunks(token, n, step,
-                        jnp.zeros((token.shape[0], x.shape[1]), x.dtype))
+                        _fill((token.shape[0], x.shape[1]), x.dtype))
 
 
 def _dispatch_fwd(T, x, token, n):
@@ -243,7 +260,7 @@ def _dispatch_bwd(T, res, dxs):
     def step(dx, lo, t, fresh, filled):
         return dx.at[t].add(_masked(fresh, _rows_at(dxs, lo, t.shape[0])))
 
-    dx = _over_chunks(token, n, step, jnp.zeros((T, dxs.shape[1]), dxs.dtype))
+    dx = _over_chunks(token, n, step, _fill((T, dxs.shape[1]), dxs.dtype))
     return dx, None, None
 
 
@@ -256,7 +273,7 @@ def _combine(T, ys, weight, token, n):
         return y.at[t].add(_weighted(_weights_at(weight, lo, fresh),
                                      _rows_at(ys, lo, t.shape[0])))
 
-    return _over_chunks(token, n, step, jnp.zeros((T, ys.shape[1]), ys.dtype))
+    return _over_chunks(token, n, step, _fill((T, ys.shape[1]), ys.dtype))
 
 
 def _combine_fwd(T, ys, weight, token, n):
@@ -273,8 +290,9 @@ def _combine_bwd(T, res, dy):
         return (lax.dynamic_update_slice(dys, _weighted(w, g), (lo, 0)),
                 lax.dynamic_update_slice(dw, at_w, (lo,)))
 
-    dys, dw = _over_chunks(token, n, step, (jnp.zeros_like(ys),
-                                            jnp.zeros_like(weight)))
+    with scope("pair_fill"):
+        init = jnp.zeros_like(ys), jnp.zeros_like(weight)
+    dys, dw = _over_chunks(token, n, step, init)
     return dys, dw, None, None
 
 
@@ -286,8 +304,10 @@ def dispatch(x, routing: Routing):
     buffer, exact zeros from :func:`filled_rows` on.  Its transpose adds
     the filled rows' cotangents into their tokens and reads no other
     row."""
-    with jax.named_scope("moe_route"):
-        return _dispatch(x.shape[0], x, routing.token, filled_rows(routing))
+    with scope("moe_route"):
+        n = filled_rows(routing)
+        with scope("pair_dispatch"):
+            return _dispatch(x.shape[0], x, routing.token, n)
 
 
 def combine(ys, routing: Routing, T: int):
@@ -296,6 +316,7 @@ def combine(ys, routing: Routing, T: int):
     :func:`filled_rows` on is read.  Its transpose gives ``weight[i] *
     dy[token[i]]`` in the filled rows of ``ys``' cotangent and exact
     zeros in the rest, and ``sum(ys[i] * dy[token[i]])`` to the weights."""
-    with jax.named_scope("moe_route"):
-        return _combine(T, ys, routing.weight, routing.token,
-                        filled_rows(routing))
+    with scope("moe_route"):
+        n = filled_rows(routing)
+        with scope("pair_combine"):
+            return _combine(T, ys, routing.weight, routing.token, n)

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from federated_pytorch_test_tpu.obs.scopes import scope
 from federated_pytorch_test_tpu.parallel.comm import federated_mean, federated_sum
 from federated_pytorch_test_tpu.parallel.mesh import CLIENT_AXIS
 
@@ -90,9 +91,10 @@ class Algorithm:
     @staticmethod
     def _agg(stack, w, K, mean_fn):
         """The one chokepoint every strategy averages through."""
-        if mean_fn is None:
-            return _active_mean(stack, w, K)
-        return mean_fn(stack, w)
+        with scope("exchange_reduce"):
+            if mean_fn is None:
+                return _active_mean(stack, w, K)
+            return mean_fn(stack, w)
 
 
 class NoConsensus(Algorithm):

@@ -43,6 +43,7 @@ from federated_pytorch_test_tpu.data.cifar10 import FederatedCifar10
 from federated_pytorch_test_tpu.models.base import BlockModule
 from federated_pytorch_test_tpu.obs import device_memory_stats
 from federated_pytorch_test_tpu.obs.costs import CostLedger, round_cost_fields
+from federated_pytorch_test_tpu.obs.scopes import scope
 from federated_pytorch_test_tpu.optim.lbfgs import LBFGSNew
 from federated_pytorch_test_tpu.parallel.mesh import (
     CLIENT_AXIS,
@@ -548,11 +549,13 @@ class BlockwiseFederatedTrainer(RoundKernel):
         K = cfg.K
 
         def batch_loss(p, bs, xb, yb, wb, rng, z, y, rho):
-            loss, new_bs = model_loss(p, bs, xb, yb, wb, rng)
-            xflat = codec.get_trainable_values(p, order, mask)
-            loss = loss + algo.penalty(xflat, z, y, rho)
-            if reg_on:
-                loss = loss + l1_l2(xflat, lam1, lam2)
+            with scope("model_loss"):
+                loss, new_bs = model_loss(p, bs, xb, yb, wb, rng)
+            with scope("penalty"):
+                xflat = codec.get_trainable_values(p, order, mask)
+                loss = loss + algo.penalty(xflat, z, y, rho)
+                if reg_on:
+                    loss = loss + l1_l2(xflat, lam1, lam2)
             return loss, new_bs
 
         def active_loss(act, p, *rest):
@@ -573,12 +576,15 @@ class BlockwiseFederatedTrainer(RoundKernel):
         def adam_step(carry, batch):
             p, bs, os = carry
             xb_raw, yb, wb, rng, z, y, rho, norm = batch
-            xb = prepare_batch(xb_raw, norm)
-            act = take_active(p)
-            (loss, new_bs), g = grad_fn(act, p, bs, xb, yb, wb, rng, z, y,
-                                        rho)
-            updates, os = tx.update(g, os, act)
-            p = put_active(p, optax.apply_updates(act, updates))
+            with scope("step_prepare"):
+                xb = prepare_batch(xb_raw, norm)
+                act = take_active(p)
+            with scope("client_grad"):
+                (loss, new_bs), g = grad_fn(act, p, bs, xb, yb, wb, rng, z,
+                                            y, rho)
+            with scope("opt_update"):
+                updates, os = tx.update(g, os, act)
+                p = put_active(p, optax.apply_updates(act, updates))
             return (p, new_bs, os), loss
 
         def lbfgs_step(carry, batch):
@@ -605,7 +611,8 @@ class BlockwiseFederatedTrainer(RoundKernel):
             steps = xb_u8.shape[0]
             def step(carry, batch):
                 xb_u8, yb, wb, i = batch
-                rng = jax.random.fold_in(key, i)
+                with scope("step_prepare"):
+                    rng = jax.random.fold_in(key, i)
                 return local_step(carry, (xb_u8, yb, wb, rng, z, y, rho, norm))
             (p, bs, os), losses = lax.scan(
                 step, (p, bs, os), (xb_u8, yb, wb, jnp.arange(steps)))
@@ -702,118 +709,121 @@ class BlockwiseFederatedTrainer(RoundKernel):
 
         def comm_shard(state: ClientState, z, y, rho, x0, yhat0, active,
                        corrupt, gbound, scratch=None, mode=None):
-            x = jax.vmap(lambda p: codec.get_trainable_values(p, order, mask))(
-                state.params
-            )
-            if has_corrupt:
-                # fault injection happens at the encode(x_k - z) boundary:
-                # the wire delta is poisoned BEFORE compression, exactly
-                # where a faulty client corrupts a real deployment — the
-                # compressor (and its EF residual) sees the poisoned delta.
-                # active/CLIENT_AXIS feed the collective modes (innerprod/
-                # collude) their cross-client honest/colluder means; the
-                # elementwise modes ignore both.
-                x = z[None, :] + apply_corruption(
-                    x - z[None, :], corrupt, corrupt_mode, corrupt_scale,
-                    w=active, axis_name=CLIENT_AXIS)
-            comp_state = state.comp
-            round_mean = mean_fn
-            if compressed:
-                # uplink-compress the update delta d_k = x_k - z; the
-                # "server" sees only x̂_k = z + decode(payload): every
-                # algorithm update below (mean / duals / BB) runs on the
-                # reconstructions, exactly what a wire-compressed
-                # deployment computes
-                from federated_pytorch_test_tpu.parallel.comm import (
-                    decode_stack,
+            with scope("exchange_flatten"):
+                x = jax.vmap(lambda p: codec.get_trainable_values(p, order, mask))(
+                    state.params
                 )
-                payload, comp_new = jax.vmap(compressor.encode)(
-                    x - z[None, :], comp_state)
-                if fused_sparse:
-                    # the k-sized payloads go over the wire themselves
-                    # (all_gather of {idx, val}, one scatter-add per
-                    # device) — the aggregate never ships dense
-                    from federated_pytorch_test_tpu.ops.packed_reduce \
-                        import make_sparse_fused_mean
-                    round_mean = make_sparse_fused_mean(payload, z, K)
-                x = z[None, :] + decode_stack(payload, compressor, N,
-                                              scratch=scratch)
-                if partial:
-                    # stragglers' PRNG/residual state stays bit-untouched
-                    comp_new = _sel(active, comp_new, comp_state)
-                comp_state = comp_new
-            cl_nrm = None
-            if client_probe:
-                # ledger probe: raw pre-guard per-client ||x_k - z|| on the
-                # exact folded tensors (post-corruption, post-decode) — a
-                # NaN/inf delta stays visible here even though the guard
-                # below rewrites the row to z
-                cl_nrm = per_client_norms(x, z)
-            w = active
-            if guard_on:
-                # update guards: every incoming delta must be finite and
-                # within the round's norm bound; offenders are masked out
-                # exactly like non-participants.  NaN hygiene throughout:
-                # where-selects only — 0 * NaN is NaN, masks must never be
-                # multiplied into possibly-corrupt rows.
-                d = x - z[None, :]
-                finite = jax.vmap(lambda v: jnp.all(jnp.isfinite(v)))(d)
-                nrm = jax.vmap(jnp.linalg.norm)(
-                    jnp.where(finite[:, None], d, 0.0))
-                okf = (finite & (nrm <= gbound)).astype(jnp.float32)
-                w = active * okf
-                n_ok = lax.psum(jnp.sum(w), CLIENT_AXIS)
-                n_trip = lax.psum(jnp.sum(active * (1.0 - okf)), CLIENT_AXIS)
-                norm_mean = lax.psum(jnp.sum(w * nrm), CLIENT_AXIS) \
-                    / jnp.maximum(n_ok, 1.0)
-                # rejected rows are neutralised to z so no non-finite value
-                # can reach the aggregation, the BB history, or a psum
-                x = jnp.where(okf[:, None] > 0, x, z[None, :])
-                if compressed and comp_state is not None:
-                    # quarantine/EF interplay: a rejected round's residual
-                    # was computed from the rejected delta (non-finite for
-                    # nan/inf corruption) and must NOT be applied when the
-                    # client rejoins — reset it, keep stream state
-                    rst = jax.vmap(compressor.reset_state)(comp_state)
-                    comp_state = _sel(1.0 - active * (1.0 - okf),
-                                      comp_state, rst)
-            if mode == "bb_store":        # nadmm == 0 (consensus_multi.py:243-246)
-                x0 = x
-            elif mode == "bb":            # nadmm % T == 0 (:247-278)
-                rho, x0, yhat0 = bb_rho_update(
-                    x, z, y, rho, x0, yhat0,
-                    BBConfig(cfg.bb_period_T, cfg.bb_alphacorrmin,
-                             cfg.bb_epsilon, cfg.bb_rhomax),
-                    self.D,
-                )
-            znew, ynew, diag = algo.global_update(
-                x, z, y, rho, K, w=w if partial else None,
-                mean_fn=round_mean)
-            if guard_on:
-                # all-rejected round degrades gracefully: z carries over
-                # (ynew is already a no-op — every ydelta is masked by w)
-                znew = jnp.where(n_ok > 0, znew, z)
-                diag["guard_trips"] = n_trip
-                diag["guard_norm_mean"] = norm_mean
-                diag["n_ok"] = n_ok
-            cl_dist = None
-            if client_probe:
-                # ledger probe: post-fold ||x_k - z_new|| (guard-neutralised
-                # rows measure z -> z_new, i.e. how far the round moved)
-                cl_dist = per_client_norms(x, znew)
-            params = state.params
-            if algo.writeback:
-                wrote = jax.vmap(
-                    lambda p: codec.put_trainable_values(p, order, mask, znew)
-                )(params)
-                # partial FedAvg: only the round's participants receive z;
-                # stragglers stay stale until next sampled (standard
-                # partial-participation semantics).  Guard-rejected clients
-                # do NOT receive z either (w, not active): the server has
-                # no reason to trust the return channel of a client whose
-                # uplink just failed validation; quarantine keeps them out
-                # until they re-qualify.
-                params = _sel(w, wrote, params) if partial else wrote
+                if has_corrupt:
+                    # fault injection happens at the encode(x_k - z) boundary:
+                    # the wire delta is poisoned BEFORE compression, exactly
+                    # where a faulty client corrupts a real deployment — the
+                    # compressor (and its EF residual) sees the poisoned delta.
+                    # active/CLIENT_AXIS feed the collective modes (innerprod/
+                    # collude) their cross-client honest/colluder means; the
+                    # elementwise modes ignore both.
+                    x = z[None, :] + apply_corruption(
+                        x - z[None, :], corrupt, corrupt_mode, corrupt_scale,
+                        w=active, axis_name=CLIENT_AXIS)
+                comp_state = state.comp
+                round_mean = mean_fn
+                if compressed:
+                    # uplink-compress the update delta d_k = x_k - z; the
+                    # "server" sees only x̂_k = z + decode(payload): every
+                    # algorithm update below (mean / duals / BB) runs on the
+                    # reconstructions, exactly what a wire-compressed
+                    # deployment computes
+                    from federated_pytorch_test_tpu.parallel.comm import (
+                        decode_stack,
+                    )
+                    payload, comp_new = jax.vmap(compressor.encode)(
+                        x - z[None, :], comp_state)
+                    if fused_sparse:
+                        # the k-sized payloads go over the wire themselves
+                        # (all_gather of {idx, val}, one scatter-add per
+                        # device) — the aggregate never ships dense
+                        from federated_pytorch_test_tpu.ops.packed_reduce \
+                            import make_sparse_fused_mean
+                        round_mean = make_sparse_fused_mean(payload, z, K)
+                    x = z[None, :] + decode_stack(payload, compressor, N,
+                                                  scratch=scratch)
+                    if partial:
+                        # stragglers' PRNG/residual state stays bit-untouched
+                        comp_new = _sel(active, comp_new, comp_state)
+                    comp_state = comp_new
+                cl_nrm = None
+                if client_probe:
+                    # ledger probe: raw pre-guard per-client ||x_k - z|| on the
+                    # exact folded tensors (post-corruption, post-decode) — a
+                    # NaN/inf delta stays visible here even though the guard
+                    # below rewrites the row to z
+                    cl_nrm = per_client_norms(x, z)
+                w = active
+                if guard_on:
+                    # update guards: every incoming delta must be finite and
+                    # within the round's norm bound; offenders are masked out
+                    # exactly like non-participants.  NaN hygiene throughout:
+                    # where-selects only — 0 * NaN is NaN, masks must never be
+                    # multiplied into possibly-corrupt rows.
+                    d = x - z[None, :]
+                    finite = jax.vmap(lambda v: jnp.all(jnp.isfinite(v)))(d)
+                    nrm = jax.vmap(jnp.linalg.norm)(
+                        jnp.where(finite[:, None], d, 0.0))
+                    okf = (finite & (nrm <= gbound)).astype(jnp.float32)
+                    w = active * okf
+                    n_ok = lax.psum(jnp.sum(w), CLIENT_AXIS)
+                    n_trip = lax.psum(jnp.sum(active * (1.0 - okf)), CLIENT_AXIS)
+                    norm_mean = lax.psum(jnp.sum(w * nrm), CLIENT_AXIS) \
+                        / jnp.maximum(n_ok, 1.0)
+                    # rejected rows are neutralised to z so no non-finite value
+                    # can reach the aggregation, the BB history, or a psum
+                    x = jnp.where(okf[:, None] > 0, x, z[None, :])
+                    if compressed and comp_state is not None:
+                        # quarantine/EF interplay: a rejected round's residual
+                        # was computed from the rejected delta (non-finite for
+                        # nan/inf corruption) and must NOT be applied when the
+                        # client rejoins — reset it, keep stream state
+                        rst = jax.vmap(compressor.reset_state)(comp_state)
+                        comp_state = _sel(1.0 - active * (1.0 - okf),
+                                          comp_state, rst)
+            with scope("exchange_update"):
+                if mode == "bb_store":  # nadmm == 0 (consensus_multi.py:243-246)
+                    x0 = x
+                elif mode == "bb":      # nadmm % T == 0 (:247-278)
+                    rho, x0, yhat0 = bb_rho_update(
+                        x, z, y, rho, x0, yhat0,
+                        BBConfig(cfg.bb_period_T, cfg.bb_alphacorrmin,
+                                 cfg.bb_epsilon, cfg.bb_rhomax),
+                        self.D,
+                    )
+                znew, ynew, diag = algo.global_update(
+                    x, z, y, rho, K, w=w if partial else None,
+                    mean_fn=round_mean)
+                if guard_on:
+                    # all-rejected round degrades gracefully: z carries over
+                    # (ynew is already a no-op — every ydelta is masked by w)
+                    znew = jnp.where(n_ok > 0, znew, z)
+                    diag["guard_trips"] = n_trip
+                    diag["guard_norm_mean"] = norm_mean
+                    diag["n_ok"] = n_ok
+                cl_dist = None
+                if client_probe:
+                    # ledger probe: post-fold ||x_k - z_new|| (guard-neutralised
+                    # rows measure z -> z_new, i.e. how far the round moved)
+                    cl_dist = per_client_norms(x, znew)
+            with scope("exchange_writeback"):
+                params = state.params
+                if algo.writeback:
+                    wrote = jax.vmap(
+                        lambda p: codec.put_trainable_values(p, order, mask, znew)
+                    )(params)
+                    # partial FedAvg: only the round's participants receive z;
+                    # stragglers stay stale until next sampled (standard
+                    # partial-participation semantics).  Guard-rejected clients
+                    # do NOT receive z either (w, not active): the server has
+                    # no reason to trust the return channel of a client whose
+                    # uplink just failed validation; quarantine keeps them out
+                    # until they re-qualify.
+                    params = _sel(w, wrote, params) if partial else wrote
             if partial:
                 diag["n_active"] = lax.psum(jnp.sum(active), CLIENT_AXIS)
             out_state = ClientState(params, state.batch_stats,

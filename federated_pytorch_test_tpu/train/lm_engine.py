@@ -32,6 +32,7 @@ import numpy as np
 from jax.custom_batching import sequential_vmap
 
 from federated_pytorch_test_tpu.models.decoder import weighted_mean
+from federated_pytorch_test_tpu.obs.scopes import scope
 from federated_pytorch_test_tpu.parallel.mesh import (
     client_sharding,
     fetch,
@@ -103,19 +104,20 @@ class LMTrainer(BlockwiseFederatedTrainer):
 
     def model_loss(self, p, bs, xb, yb, wb, rng):
         per_seq, aux = self.model.apply({"params": p}, xb, yb)
-        new = {"steps": bs["steps"] + 1,
-               "moe_pairs_local": bs["moe_pairs_local"]
-               + aux["moe_pairs_local"],
-               "moe_dropped": bs["moe_dropped"] + aux["moe_dropped"],
-               "moe_rows": bs["moe_rows"] + aux["moe_rows"],
-               "moe_load_sum": bs["moe_load_sum"]
-               + aux["moe_load_max_over_mean"],
-               "mtp_loss_sum": bs["mtp_loss_sum"] + (
-                   weighted_mean(aux["mtp_loss"], wb) if "mtp_loss" in aux
-                   else 0.0),
-               "mhc_err_sum": bs["mhc_err_sum"]
-               + aux.get("mhc_marginal_err", 0.0)}
-        return weighted_mean(per_seq, wb), new
+        with scope("step_stats"):
+            new = {"steps": bs["steps"] + 1,
+                   "moe_pairs_local": bs["moe_pairs_local"]
+                   + aux["moe_pairs_local"],
+                   "moe_dropped": bs["moe_dropped"] + aux["moe_dropped"],
+                   "moe_rows": bs["moe_rows"] + aux["moe_rows"],
+                   "moe_load_sum": bs["moe_load_sum"]
+                   + aux["moe_load_max_over_mean"],
+                   "mtp_loss_sum": bs["mtp_loss_sum"] + (
+                       weighted_mean(aux["mtp_loss"], wb)
+                       if "mtp_loss" in aux else 0.0),
+                   "mhc_err_sum": bs["mhc_err_sum"]
+                   + aux.get("mhc_marginal_err", 0.0)}
+            return weighted_mean(per_seq, wb), new
 
     def eval_batch_metric(self, p, bs, xb, yb, wb):
         per_seq, _ = self.model.apply({"params": p}, xb, yb)

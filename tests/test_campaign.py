@@ -116,6 +116,7 @@ def det_view(rec):
     # block visit's first round: it stamps a switch
     return {k: v for k, v in rec.items()
             if isinstance(v, (int, float)) and not k.endswith("_seconds")
+            and not k.startswith("dispatch_")
             and k != "block_switch_h2d_bytes"}
 
 

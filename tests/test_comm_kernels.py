@@ -443,6 +443,7 @@ def _strip(rec):
     # the trajectory contract covers everything else, bitwise
     return {k: v for k, v in rec.items()
             if isinstance(v, (int, float)) and not k.endswith("_seconds")
+            and not k.startswith("dispatch_")
             and k != "block_switch_h2d_bytes"}
 
 

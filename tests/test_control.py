@@ -897,7 +897,8 @@ class TestCPCSupervised:
         # process re-compiles, so its wall-clock fields differ
         strip = lambda h: [
             {k: v for k, v in r.items()
-             if not k.endswith("_seconds")} for r in h]
+             if not k.endswith("_seconds")
+                and not k.startswith("dispatch_")} for r in h]
         _, want = make().run(Nloop=1, Nadmm=2, log=lambda m: None)
 
         ck = str(tmp_path / "cpc_sup_ck")

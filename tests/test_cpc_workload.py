@@ -180,7 +180,8 @@ class TestCPCMidrunResume:
                               lbfgs_history=3, lbfgs_max_iter=1, Niter=1)
 
         strip = lambda h: [{k: v for k, v in r.items()
-                            if not k.endswith("_seconds")} for r in h]
+                            if not k.endswith("_seconds")
+                               and not k.startswith("dispatch_")} for r in h]
         ck = str(tmp_path / "cpc_midrun")
 
         # uninterrupted reference trajectory: 4 blocks x Nadmm=2 rounds
@@ -286,6 +287,7 @@ class TestCPCTrainer:
             _, hist = t.run(Nloop=1, Nadmm=1, log=lambda m: None,
                             prefetch=prefetch)
             return [{k: v for k, v in h.items()
-                     if not k.endswith("_seconds")} for h in hist]
+                     if not k.endswith("_seconds")
+                        and not k.startswith("dispatch_")} for h in hist]
 
         assert run(True) == run(False)

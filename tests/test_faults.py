@@ -992,7 +992,8 @@ class TestCPCKernelParity:
                   robust_agg="median")
         strip = lambda h: [
             {k: v for k, v in r.items()
-             if not k.endswith("_seconds")} for r in h]
+             if not k.endswith("_seconds")
+                and not k.startswith("dispatch_")} for r in h]
         _, (_, want) = run_cpc(make_src(), Nadmm=2, **kw)
         ck = str(tmp_path / "cpc_async_ck")
 

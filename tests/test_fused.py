@@ -124,6 +124,7 @@ def strip(rec):
     # switch
     return {k: v for k, v in rec.items()
             if isinstance(v, (int, float)) and not k.endswith("_seconds")
+            and not k.startswith("dispatch_")
             and k != "block_switch_h2d_bytes"}
 
 

@@ -162,6 +162,9 @@ DECLARED = {
     "block_switch_seconds": (("round",), 0.03, "soon", True),
     "gap_seconds": (("round",), 0.04, "soon", True),
     "dispatch_seconds": (("round",), 0.002, "soon", True),
+    "dispatch_max_seconds": (("round",), 0.0015, "soon", True),
+    "dispatch_max_site": (("round",), "train_epoch[blk=1]", 1, True),
+    "dispatch_new_signatures": (("round",), 1, 0.5, True),
     "block_switch_h2d_bytes": (("round",), 0, "none", True),
     "tokens": (("round",), 65536, "many", False),
     "block_kind": (("round",), "gdn", 1, False),

@@ -312,6 +312,111 @@ class TestLedgerJit:
 
 
 # ----------------------------------------------------------------------
+# new argument signatures and the window's slowest call
+
+
+def _one_device_shardings():
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.asarray(jax.devices()[:1]), ("clients",))
+    return NamedSharding(mesh, P("clients")), NamedSharding(mesh, P())
+
+
+def _second_sharding(f):
+    """The same array under the two specs a one-device mesh has: what
+    ``init_state`` stages and what every program returns."""
+    by_clients, replicated = _one_device_shardings()
+    x = jnp.ones((8, 4))
+    f(jax.device_put(x, by_clients))
+    return lambda: f(jax.device_put(x, replicated))
+
+
+def _second_weak_type(f):
+    f(1.0)
+    return lambda: f(jnp.asarray(1.0))
+
+
+class TestNewSignatures:
+    @pytest.mark.parametrize("second", [_second_sharding, _second_weak_type])
+    def test_a_second_signature_is_counted_and_is_no_compile(self, second):
+        led = CostLedger()
+        again = second(_instrumented(led, "s", lambda x: x * 2.0))
+        cold = led.drain()
+        assert len(cold.events) == 1 and cold.new_signatures == 0
+        again()
+        met = led.drain()
+        assert met.events == () and met.new_signatures == 1
+        assert len(led.all_events) == 1
+        fields = round_cost_fields(met, t_start=0.0, seconds=1e9)
+        assert fields["dispatch_new_signatures"] == 1
+        assert "compile_seconds" not in fields
+        validate_record(round_record(**fields))
+        again()                                 # the signature is known now
+        assert led.drain().new_signatures == 0
+
+    @pytest.mark.parametrize("calls", [
+        pytest.param([(4,)], id="cold"),
+        pytest.param([(4,), (5,)], id="retrace"),
+        pytest.param([(4,), (4,)], id="warm"),
+    ])
+    def test_a_compile_is_no_new_signature(self, calls):
+        led = CostLedger()
+        f = _instrumented(led, "s", lambda x: x + 1.0)
+        for shape in calls:
+            f(jnp.ones(shape))
+        assert led.drain().new_signatures == 0
+
+    def test_a_site_without_a_cache_counts_none(self):
+        """A sanitized site is a plain wrapper: no ``_cache_size``."""
+        led = CostLedger()
+        jfn = jax.jit(led.mark(lambda x: x * x, "p"))
+        f = led.instrument(lambda *a: jfn(*a), "p")
+        by_clients, replicated = _one_device_shardings()
+        for sh in (by_clients, replicated):
+            f(jax.device_put(jnp.ones((8, 4)), sh))
+        assert led.drain().new_signatures == 0
+
+    def test_slowest_call_and_its_site(self):
+        led = CostLedger()
+        f = _instrumented(led, "fast", lambda x: x * 3.0)
+        g = _instrumented(led, "slow", lambda x: x - 3.0)
+        x = jnp.ones((16,))
+        f(x)
+        led.drain()
+        for _ in range(3):
+            f(x)
+        g(x)                                    # compiles: the slowest
+        rc = led.drain()
+        assert rc.slowest[0] == "slow"
+        assert rc.slowest[1] == rc.events[0].seconds
+        assert 0 < rc.slowest[1] <= rc.dispatch_seconds
+        fields = round_cost_fields(rc, t_start=0.0, seconds=1e9)
+        assert fields["dispatch_max_site"] == "slow"
+        assert fields["dispatch_max_seconds"] == rc.slowest[1]
+        validate_record(round_record(**fields))
+
+    def test_both_reset_on_drain_and_are_omitted_at_zero(self):
+        led = CostLedger()
+        again = _second_sharding(_instrumented(led, "s", lambda x: x * 2.0))
+        again()
+        assert led.drain().new_signatures == 1
+        empty = led.drain()
+        assert empty.new_signatures == 0 and empty.slowest == ("", 0.0)
+        assert round_cost_fields(empty, t_start=0.0, seconds=1.0) == {}
+        again()
+        warm = round_cost_fields(led.drain(), t_start=0.0, seconds=1.0)
+        assert set(warm) == {"dispatch_seconds", "dispatch_max_seconds",
+                             "dispatch_max_site"}
+
+    @pytest.mark.parametrize("field,bad", [
+        ("dispatch_new_signatures", 1.5), ("dispatch_max_seconds", "slow"),
+        ("dispatch_max_site", 3)])
+    def test_the_fields_are_typed(self, field, bad):
+        with pytest.raises(SchemaError, match=field):
+            validate_record(round_record(**{field: bad}))
+
+
+# ----------------------------------------------------------------------
 # engine integration: one real FedAvg run, shared by the assertions
 
 
@@ -334,6 +439,27 @@ class TestEngineIntegration:
         # the cold round(s) must show nonzero in-window compile seconds
         assert any(r.get("compile_seconds", 0) > 0 for r in hist)
         assert all(r["dispatch_seconds"] > 0 for r in hist)
+        # the slowest call of each round and its site; written with the
+        # recorder on (here) and off (TestBitwiseIdentity)
+        assert all(0 < r["dispatch_max_seconds"] <= r["dispatch_seconds"]
+                   for r in hist)
+        assert all(r["dispatch_max_site"].startswith(
+            ("train_epoch[", "comm[", "block_vars[")) for r in hist)
+
+    def test_a_one_device_mesh_meets_a_second_signature(self, data):
+        """A block's first round compiles; on a one-device mesh its
+        second meets the epoch program's second argument signature (the
+        state comes back from the round's programs under another spec
+        than ``init_state``'s): nothing compiles, the counter says so."""
+        t = BlockwiseFederatedTrainer(
+            TinyNet(), small_cfg(num_devices=1, obs_sinks="none"), data,
+            FedAvg())
+        _, hist = t.run(log=lambda m: None)
+        assert [r.get("dispatch_new_signatures", 0) for r in hist] == [
+            0 if "compile_seconds" in r else 1 for r in hist]
+        assert sum("compile_seconds" in r for r in hist) == 2
+        assert all(r["dispatch_max_site"].startswith("train_epoch[")
+                   for r in hist)
 
     def test_compile_records_emitted_and_valid(self, cost_run):
         t, _, _, _ = cost_run
@@ -384,6 +510,10 @@ class TestBitwiseIdentity:
                         jax.tree_util.tree_leaves(p_dark)):
             np.testing.assert_array_equal(a, b)
         assert [r["loss"] for r in h_on] == [r["loss"] for r in h_off]
+        # the dispatch fields come from the ledger, recorder on or off
+        _, h_dark = run(cost_ledger=True, obs_sinks="none")
+        assert all("dispatch_max_site" in r for r in h_dark)
+        assert not any(k.startswith("dispatch_") for r in h_off for k in r)
 
 
 # ----------------------------------------------------------------------

@@ -124,7 +124,8 @@ def param_leaves(state):
 def det_view(rec):
     # wall-clock fields legitimately differ between processes
     return {k: v for k, v in rec.items()
-            if isinstance(v, (int, float)) and not k.endswith("_seconds")}
+            if isinstance(v, (int, float)) and not k.endswith("_seconds")
+            and not k.startswith("dispatch_")}
 
 
 def pure_fields(rec):
