@@ -23,10 +23,13 @@ is compared with.  Layer ``l``'s two sub-layers are latent attention
 ``intermediate_size`` where ``l < first_k_dense_replace``, else the
 expert layer.  Matrix products run in ``dtype`` (bfloat16 on the chip)
 with float32 sums; parameters, norms, the router, the loss and
-everything of the hyper-connections are float32.  Each sub-layer, its
+everything of the hyper-connections are float32 (on a TPU their passes
+over the streams are Pallas kernels, ``ops/hyper_connections.py:plan``;
+``mhc_impl`` says which path a shape takes).  Each sub-layer, its
 maps and mixing with it, is rematerialised in the backward pass
 (``jax.checkpoint``): what a sub-layer boundary keeps is the ``n``
-streams.  (Run sequence by sequence instead, the compiler's count of
+streams, and the rematerialised forward stops at the sub-layer's
+input (``expand`` is not run again).  (Run sequence by sequence instead, the compiler's count of
 the largest program's temporaries rose from 8.2 to 9.7 GiB.)
 
 The streams are held as ``[n, B, T, C]`` (why the stream axis leads:
@@ -181,10 +184,17 @@ class Xing4(BlockModule):
                          self.qk_head_dim, self.dtype,
                          self.v_head_dim)["impl"]
 
+    def mhc_impl(self, tokens: int) -> str:
+        """What runs the hyper-connections' passes over the streams of
+        ``tokens`` tokens here ("pallas" | "pallas_interpret" | "xla":
+        ``ops/hyper_connections.py:plan``)."""
+        return hc.plan(self.hc_mult, tokens, self.hidden_size)["impl"]
+
     def impl_fields(self, tokens: int) -> Dict[str, str]:
         """The round record's fields that name this backend's
         implementations for sequences of ``tokens``."""
-        return {"attn_impl": self.attn_impl(tokens)}
+        return {"attn_impl": self.attn_impl(tokens),
+                "mhc_impl": self.mhc_impl(tokens)}
 
     # -- rotary tables and the softmax scale -----------------------------
     def rope_inv_freq(self):
@@ -262,10 +272,11 @@ class Xing4(BlockModule):
         return forward(self, p, ids, labels)
 
 
-def hyper_maps(cfg: Xing4, p, X) -> hc.Maps:
-    """The maps of the streams ``X [n, ..., C]`` under the sub-layer
-    whose block leaves are ``p``."""
-    return hc.maps(
+def hyper_pre(cfg: Xing4, p, X):
+    """``(H_pre X, the maps, X handed through)`` of the streams ``X [n,
+    ..., C]`` under the sub-layer whose block leaves are ``p``
+    (``ops/hyper_connections.py:pre``)."""
+    return hc.pre(
         X, {k[3:]: v for k, v in p.items() if k.startswith("hc_")},
         iters=cfg.hc_sinkhorn_iters, eps=cfg.hc_eps,
         clamp=(cfg.mhc_h_res_clamp_min, cfg.mhc_h_res_clamp_max),
@@ -282,8 +293,7 @@ def sub_layer(cfg: Xing4, p, f, X):
     @jax.checkpoint
     def run(X):
         with scope("mhc"):
-            m = hyper_maps(cfg, p, X)
-            u = hc.contract(m.pre, X)
+            u, m, X = hyper_pre(cfg, p, X)
         y, more = f(u)
         with scope("mhc"):
             return hc.expand(m.res, m.post, X, y), m.marginal_err, more

@@ -208,6 +208,9 @@ FIELDS: Dict[str, Any] = {
     "gdn_scan_impl": (("round",), _STR),
     # the same for the attention core (ops/flash_attention.py: plan)
     "attn_impl": (("round",), _STR),
+    # the same for the hyper-connections' passes over the streams
+    # (ops/hyper_connections.py: plan)
+    "mhc_impl": (("round",), _STR),
     # fault / guard counters
     "guard_trips":  (("round",), _NUM),
     "guard_norm_mean": (("round",), _NUM),
@@ -382,9 +385,9 @@ ADVISORY_FIELDS = (
     "block_switch_seconds", "gap_seconds", "dispatch_seconds",
     "dispatch_max_seconds", "dispatch_max_site", "dispatch_new_signatures",
     "block_switch_h2d_bytes",
-    # which implementation this backend took for the recurrence and for
-    # the attention core
-    "gdn_scan_impl", "attn_impl",
+    # which implementation this backend took for the recurrence, for the
+    # attention core and for the hyper-connections
+    "gdn_scan_impl", "attn_impl", "mhc_impl",
     # serving-plane latency/throughput telemetry
     "serve_p50_ms", "serve_p99_ms", "serve_qps", "swap_gap_seconds",
     "serve_accuracy", "drift_score", "forced_refresh",

@@ -161,9 +161,12 @@ SCOPES: Tuple[Scope, ...] = (
     Scope("mhc", ("sublayer_mixer", "sublayer_ffn"), ("xing4_0",),
           "a sub-layer's hyper-connections (self: nothing)"),
     Scope("mhc_maps", ("mhc",), ("xing4_0",),
-          "the maps: norm, projection, sigmoids, Sinkhorn"),
+          "hyper_connections.pre: norm, projection, sigmoids, Sinkhorn "
+          "(on a TPU one kernel each way, which holds H_pre X and ALL "
+          "of the streams' cotangent too)"),
     Scope("mhc_mix", ("mhc",), ("xing4_0",),
-          "contract and expand: the streams' mixing"),
+          "contract and expand: the streams' mixing (on a TPU expand's "
+          "kernel and its transpose's)"),
     # -- the head --------------------------------------------------------
     Scope("lm_head_loss", _FRAME, _DECODER,
           "final norm, head product, cross-entropy (self: nothing)"),

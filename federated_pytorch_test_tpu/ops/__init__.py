@@ -19,10 +19,14 @@ so every op runs identically on CPU/interpret mode.  Currently:
     a backward kernel under one ``custom_vjp`` that keep the score tiles
     in VMEM and skip the masked half
     (``flash_attention.force_attn_impl`` for tests).
-  * ``hyper_connections`` — no kernel either: the maps, the Sinkhorn
-    projection and the stream mixing of manifold-constrained
-    hyper-connections in float32 ``jax.numpy``, laid out for the TPU
-    (streams stream-major, the maps' tokens along the lanes).
+  * ``hyper_connections.pre`` / ``expand`` — the two halves of a
+    sub-layer under manifold-constrained hyper-connections (streams
+    stream-major, the maps' tokens along the lanes): on a TPU each is one
+    ``custom_vjp`` whose passes over the float32 streams are kernels that
+    read them once and write their result once (norm, projection,
+    ``H_pre X``; the mixing; both transposes, the streams' cotangent
+    written once); the sigmoids and the Sinkhorn projection stay
+    ``jax.numpy`` (``hyper_connections.force_mhc_impl`` for tests).
   * ``moe`` — no kernel of its own: the two router rules
     (``router_weights``, ``sigmoid_router_weights``) and the held
     experts' sorted pairs and grouped products (``jax.lax.ragged_dot``,

@@ -283,8 +283,12 @@ class TestEpochPrefetch:
         with the staging worker thread on and off must be bit-identical
         (engine._stage_epoch).  device_data=False pins the HOST staging
         path — device mode has no worker thread."""
+        # advisory fields name the machine's path (which dispatch was the
+        # slowest: ``dispatch_max_site``), not the trajectory
+        from federated_pytorch_test_tpu.obs.schema import ADVISORY_FIELDS
         strip = lambda h: [{k: v for k, v in r.items()
-                            if not k.endswith("seconds")} for r in h]
+                            if not k.endswith("seconds")
+                            and k not in ADVISORY_FIELDS} for r in h]
 
         def run(prefetch):
             t = BlockwiseFederatedTrainer(

@@ -4,8 +4,9 @@ token-by-token recurrence and against the ``lax.scan`` path, the backward
 kernel line by line against ``jax.vjp`` of the XLA step, the rule that
 picks the path, the round field that reports it, and a compile of both
 kernels at the published widths for a described v5e, and of the attention
-kernel pair (``ops/flash_attention.py``) beside them: one file holds the
-topology fixture, because only one test process may load the TPU's
+kernel pair (``ops/flash_attention.py``) and the hyper-connections'
+stream kernels (``ops/hyper_connections.py``) beside them: one file holds
+the topology fixture, because only one test process may load the TPU's
 library.
 """
 
@@ -26,6 +27,7 @@ from federated_pytorch_test_tpu.data.tokens import FederatedTokens  # noqa: E402
 from federated_pytorch_test_tpu.models import get_model  # noqa: E402
 from federated_pytorch_test_tpu.ops import flash_attention as fa  # noqa: E402
 from federated_pytorch_test_tpu.ops import gated_delta as gd  # noqa: E402
+from federated_pytorch_test_tpu.ops import hyper_connections as hc  # noqa: E402
 from federated_pytorch_test_tpu.train import (  # noqa: E402
     FedAvg,
     FederatedConfig,
@@ -435,3 +437,31 @@ def test_attention_kernels_compile_for_a_v5e_at_the_published_widths(
         f = jax.grad(lambda *a, f=f: jnp.sum(f(*a)), argnums=(0, 1, 2))
     text = compiled_for(f, *ops)
     assert text.count("tpu_custom_call") == (1 if what == "forward" else 2)
+
+
+@pytest.mark.parametrize("what", ["forward", "forward_and_backward"])
+def test_stream_kernels_compile_for_a_v5e_at_the_published_widths(one_chip,
+                                                                  what):
+    """A sub-layer of Xing4.0's hyper-connections at 4 streams of 3,584
+    over 2 x 2,048 tokens with ``plan``'s tile: three double-buffered
+    stream tiles beside two stacks of ``phi`` in 96 MiB of VMEM, lane
+    rolls, masked lane sums, scalars in SMEM.  Forward: ``pre`` and
+    ``expand``; backward: ``pre`` again and the two rules' kernels
+    (``expand`` is not run again)."""
+    n, C, lead = 4, 3584, (2, 2048)
+    sh = lambda *s: jax.ShapeDtypeStruct(s, F32, sharding=one_chip)
+    leaves = {"phi_pre": sh(n * C, n), "phi_post": sh(n * C, n),
+              "phi_res": sh(n * C, n * n), "a_pre": sh(1), "a_post": sh(1),
+              "a_res": sh(1), "b_pre": sh(n), "b_post": sh(n),
+              "b_res": sh(n, n)}
+
+    def f(leaves, x):
+        with hc.force_mhc_impl("pallas"):
+            assert hc.plan(n, 4096, C)["impl"] == "pallas"
+            u, m, xt = hc.pre(x, leaves, iters=20, eps=1e-6)
+            return hc.expand(m.res, m.post, xt, jnp.tanh(u))
+
+    if what != "forward":
+        f = jax.grad(lambda *a, f=f: jnp.sum(f(*a)), argnums=(0, 1))
+    text = compiled_for(f, leaves, sh(n, *lead, C))
+    assert text.count("tpu_custom_call") == (2 if what == "forward" else 3)

@@ -175,6 +175,7 @@ DECLARED = {
     "mtp_loss": (("round",), 9.87, "high", False),
     "gdn_scan_impl": (("round",), "pallas", 1, True),
     "attn_impl": (("round",), "xla", 0, True),
+    "mhc_impl": (("round",), "pallas_interpret", 2, True),
 }
 
 

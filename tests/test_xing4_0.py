@@ -31,6 +31,9 @@ from federated_pytorch_test_tpu.models.decoder import weighted_mean  # noqa: E40
 from federated_pytorch_test_tpu.ops.flash_attention import (  # noqa: E402
     force_attn_impl,
 )
+from federated_pytorch_test_tpu.ops.hyper_connections import (  # noqa: E402
+    force_mhc_impl,
+)
 from federated_pytorch_test_tpu.train import (  # noqa: E402
     FedAvg,
     FederatedConfig,
@@ -373,7 +376,8 @@ def test_model_through_the_attention_kernels_matches_the_xla_path():
 
     def run(impl):
         with force_attn_impl(impl), jax.default_matmul_precision("highest"):
-            assert model.impl_fields(384) == {"attn_impl": impl}
+            assert model.impl_fields(384) == {"attn_impl": impl,
+                                              "mhc_impl": "xla"}
             logits, _ = model.apply({"params": params}, x)
             grads = jax.grad(lambda p: weighted_mean(
                 model.apply({"params": p}, x, y)[0]))(params)
@@ -381,6 +385,62 @@ def test_model_through_the_attention_kernels_matches_the_xla_path():
 
     (logits, grads), (want, want_grads) = run("pallas_interpret"), run("xla")
     assert rel(logits, want) < 2e-5
+    for path, g, w in zip(paths, grads, want_grads):
+        assert rel(g, w) < 2e-4, path
+
+
+# ----------------------------------------------------------------------
+# the hyper-connections as kernels (interpret mode) against jax.numpy
+# ----------------------------------------------------------------------
+@pytest.mark.parametrize("force,hidden,impl", [
+    (None, 128, "xla"),                      # the CPU
+    ("pallas_interpret", 128, "pallas_interpret"),
+    ("pallas_interpret", 32, "xla"),         # a width the kernels refuse
+    ("xla", 128, "xla"),
+])
+def test_impl_fields_say_what_runs_the_hyper_connections(force, hidden,
+                                                         impl):
+    model = tiny_model(hidden_size=hidden)
+    ask = lambda: model.impl_fields(T)["mhc_impl"]
+    if force is None:
+        assert ask() == impl
+    else:
+        with force_mhc_impl(force):
+            assert ask() == impl == model.mhc_impl(T)
+
+
+def test_model_through_the_stream_kernels_matches_the_jax_numpy_path():
+    """Streams of 128: ``plan()`` sends every sub-layer's ``pre`` and
+    ``expand`` to the kernels (two sequences of 40 tokens: one ragged
+    tile), forward and backward through ``jax.checkpoint``; the logits
+    and the gradients of layer 0's MLP block and layer 1's mixer block,
+    hyper-connection leaves among them, against the ``jax.numpy`` lines
+    differentiated by JAX.  (Not the first sub-layer, whose streams are
+    equal, nor the last, whose ``H_res`` cannot move the streams' sum:
+    their ``phi_res`` gradients are rounding noise on either path.)"""
+    model = tiny_model(hidden_size=128, layers=2)
+    ids = jax.random.randint(jax.random.PRNGKey(1), (2, T + 1), 0, 64)
+    x, y = ids[:, :-1], ids[:, 1:]
+    params, _ = model.init_variables(jax.random.PRNGKey(0), x[:, :8])
+    paths = [q for b in (2, 3) for q in model.param_order()[
+        model.train_order_block_ids()[b][0]:
+        model.train_order_block_ids()[b][1] + 1]]
+
+    def run(impl):
+        with force_mhc_impl(impl), jax.default_matmul_precision("highest"):
+            assert model.mhc_impl(2 * T) == impl
+            (logits, aux) = model.apply({"params": params}, x)
+            grads = jax.grad(lambda p: weighted_mean(
+                model.apply({"params": p}, x, y)[0]))(params)
+        return logits, aux["mhc_marginal_err"], \
+            [get_by_path(grads, path) for path in paths]
+
+    (logits, err, grads), (want, want_err, want_grads) = \
+        run("pallas_interpret"), run("xla")
+    assert rel(logits, want) < 2e-5
+    # a few units in float32's last place of a row or column sum
+    assert 0.0 < float(err) < 1e-5 and 0.0 < float(want_err) < 1e-5
+    assert any("hc_phi_res" in q for q in paths)
     for path, g, w in zip(paths, grads, want_grads):
         assert rel(g, w) < 2e-4, path
 
@@ -456,6 +516,7 @@ def test_two_fedavg_rounds_of_lm_trainer_match_the_round_reference():
         assert rec["loss"] == pytest.approx(w["loss"], rel=1e-5)
         assert rec["block_kind"] == "mla" and rec["moe_dropped"] == 0
         assert rec["tokens"] == 2 * 2 * 24 and rec["attn_impl"] == "xla"
+        assert rec["mhc_impl"] == "xla"
         assert rec["mtp_loss"] == 0.0 and "gdn_scan_impl" not in rec
         assert 0.0 < rec["mhc_marginal_err"] < 1e-5
         for path, leaf, ref_leaves in zip(paths, got, zip(*w["x"])):
