@@ -1,12 +1,13 @@
 """What the decoders share (``models/qwen3_next.py``,
-``models/glm4_moe_lite.py``, ``models/xing4_0.py``): a block's leaves,
-the rotary tables (plain or YaRN's), the loss helpers, the part of an
-expert layer that follows the router on a chip that holds a share of the
-experts (sort, grouped products, scatter), and what the two decoders of
-the DeepSeek-V3 line share: the plain RMS norm, multi-head latent
-attention, the dense SwiGLU, the expert layer under the sigmoid rule
-with its ungated shared expert, and their leaves.  Each decoder keeps
-its own layer and residual path.
+``models/glm4_moe_lite.py``, ``models/xing4_0.py``, ``models/zaya.py``):
+a block's leaves, the rotary tables (plain or YaRN's), the loss helpers,
+the head of a model whose embedding is also its head's matrix, the part
+of an expert layer that follows the router on a chip that holds a share
+of the experts (sort, grouped products, scatter), and what the two
+decoders of the DeepSeek-V3 line share: the plain RMS norm, multi-head
+latent attention, the dense SwiGLU, the expert layer under the sigmoid
+rule with its ungated shared expert, and their leaves.  Each decoder
+keeps its own layer and residual path.
 """
 
 from __future__ import annotations
@@ -257,6 +258,21 @@ def moe_aux(routed):
             "moe_load_max_over_mean": functools.reduce(jnp.maximum, load,
                                                        _F32(0)),
             "moe_rows": sum(rows, jnp.int32(0))}
+
+
+def tied_head_logits(cfg, x, norm, embedding):
+    """``x [..., H]`` through the final norm ``norm [H]`` and the head of
+    a model with a tied embedding: ``N(x) Emb^T`` over the rows
+    ``embedding [V, H]`` holds, the matrix contracted along its width as
+    it lies (no transposed copy is asked for)."""
+    with scope("lm_head_loss"):
+        with scope("head_norm"):
+            xn = rms_norm(x, norm, cfg.rms_norm_eps)
+        with scope("head_product"):
+            return jax.lax.dot_general(
+                _op(xn, cfg.dtype), _op(embedding, cfg.dtype),
+                (((xn.ndim - 1,), (1,)), ((), ())),
+                preferred_element_type=_F32)
 
 
 def sequence_loss(logits, labels):

@@ -193,7 +193,14 @@ FIELDS: Dict[str, Any] = {
     # the model has no such layer).  mhc_marginal_err: the largest
     # |row sum - 1| or |column sum - 1| of a step's hyper-connection
     # mixing matrices, mean over the round's steps (0.0 for a model
-    # without streams)
+    # without streams).  moe_top1_weight_mean: the mean routing weight of
+    # the pairs that found a row, over layers, steps, clients, of a model
+    # that reports the weights' sum (at one expert a token: the chosen
+    # expert's probability; 1 / experts says the router does not tell
+    # tokens apart; 0.0 where the model reports none).
+    # router_state_rms: the RMS of the state a model's routers hand from
+    # layer to layer, after the last layer, mean over the round's steps
+    # (0.0 for a model whose routers keep none)
     "tokens":       (("round",), _INT),
     "block_kind":   (("round",), _STR),
     "moe_pairs_local": (("round",), _INT),
@@ -202,6 +209,8 @@ FIELDS: Dict[str, Any] = {
     "moe_fill_share": (("round",), _NUM),
     "mtp_loss":     (("round",), _NUM),
     "mhc_marginal_err": (("round",), _NUM),
+    "moe_top1_weight_mean": (("round",), _NUM),
+    "router_state_rms": (("round",), _NUM),
     # what ran the delta rule's chunk recurrence (ops/gated_delta.py:
     # plan): pallas | pallas_interpret | xla.  Names the machine's path,
     # not the trajectory, hence advisory

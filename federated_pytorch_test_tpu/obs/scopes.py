@@ -9,9 +9,9 @@ read by the names below; ``benchmarks/lib/scope_tree.py`` does, matching
 whole path segments.
 
 :data:`SCOPES` is the one table: the engine (``train/engine.py``,
-``train/lm_engine.py``, ``train/algorithms.py``), the three decoders
+``train/lm_engine.py``, ``train/algorithms.py``), the four decoders
 (``models/decoder.py``, ``qwen3_next.py``, ``glm4_moe_lite.py``,
-``xing4_0.py``) and the ops they call open their scopes with
+``xing4_0.py``, ``zaya.py``) and the ops they call open their scopes with
 :func:`scope`, which refuses a name that is not declared here.  A row is
 ``(name, parents, programs, covers)``:
 
@@ -21,12 +21,12 @@ whole path segments.
   where a scope has children.
 - ``programs``: where it occurs: ``epoch`` (every cell's epoch program:
   the engine's step), ``comm`` (the exchange program), ``decoder`` (all
-  three decoders) or a model's registered name.
+  four decoders) or a model's registered name.
 
-The fourteen names that stood before this table (:data:`KERNEL_SCOPES`)
-are what the benchmark's kernel readers match, by substring: no other
-name may hold one of them unless it is nested inside that scope
-(``gdn_conv`` inside ``gdn``).
+The names of :data:`KERNEL_SCOPES` are what the benchmark's kernel
+readers match: the fourteen that stood before this table by substring,
+``cca_core`` as a whole segment.  No other name may hold one of them
+unless it is nested inside that scope (``gdn_conv`` inside ``gdn``).
 """
 
 from __future__ import annotations
@@ -46,7 +46,9 @@ class Scope(NamedTuple):
 
 
 _DECODER = ("decoder",)
-_MIXERS = ("gdn", "gated_attn", "mla_attn")
+_MIXERS = ("gdn", "gated_attn", "mla_attn", "cca_attn")
+_ATTN = ("gated_attn", "mla_attn", "cca_attn")
+_SHARED_EXPERT = ("qwen3_next", "glm4_moe_lite", "xing4_0")
 _FRAME = ("model_loss", "mtp")
 
 SCOPES: Tuple[Scope, ...] = (
@@ -120,24 +122,40 @@ SCOPES: Tuple[Scope, ...] = (
     Scope("mla_core", ("mla_attn",), ("glm4_moe_lite", "xing4_0"),
           "causal_attention of the latent mixer (self: the attention "
           "kernels, or the XLA core)"),
-    Scope("attn_proj_in", ("gated_attn", "mla_attn"), _DECODER,
+    Scope("cca_attn", ("sublayer_mixer",), ("zaya",),
+          "a compressed-convolutional-attention mixer (self: nothing)"),
+    Scope("cca_mix", ("cca_attn",), ("zaya",),
+          "what compression adds before the core: the value shift, the "
+          "depthwise and the per-head causal convolution of q and k, "
+          "the q/k means added back"),
+    Scope("cca_core", ("cca_attn",), ("zaya",),
+          "causal_attention inside the compressed space (self: the "
+          "attention kernels, or the XLA core)"),
+    Scope("attn_proj_in", _ATTN, _DECODER,
           "the projections into the core (q, k, v; MLA: q_a, q_b, kv_a, "
-          "kv_b) and their slices"),
-    Scope("attn_norm_rope", ("gated_attn", "mla_attn"), _DECODER,
-          "the head norms (MLA: the latents' norms), rotary, the "
-          "softmax scale"),
-    Scope("attn_layout", ("gated_attn", "mla_core"), _DECODER,
+          "kv_b; CCA: q, k, v1, v2 down to the compressed widths) and "
+          "their slices"),
+    Scope("attn_norm_rope", _ATTN, _DECODER,
+          "the head norms (MLA: the latents' norms; CCA: the unit-sphere "
+          "norm and the key temperature), rotary, the softmax scale"),
+    Scope("attn_layout", ("gated_attn", "mla_core", "cca_core"), _DECODER,
           "ops/flash_attention.py's wrapper: casts, padding, the "
           "regrouping transposes before and after the kernels"),
-    Scope("attn_proj_out", ("gated_attn", "mla_attn"), _DECODER,
+    Scope("attn_proj_out", _ATTN, _DECODER,
           "o_proj, with the sigmoid gate where there is one"),
+    Scope("res_scale", ("sublayer_mixer", "sublayer_ffn"), ("zaya",),
+          "the scaled residual merge (s_r h + b_r) + (s_o F + b_o)"),
     # -- feed-forward sub-layers -----------------------------------------
     Scope("dense_mlp", ("sublayer_ffn",), ("glm4_moe_lite", "xing4_0"),
           "the dense SwiGLU"),
     Scope("moe_route", ("sublayer_ffn",), _DECODER,
           "router to pair buffer and back (self: filled_rows)"),
+    Scope("route_mlp", ("moe_route",), ("zaya",),
+          "the router that is an MLP with a state: the down-projection, "
+          "the previous layer's state added, its norm, three products"),
     Scope("route_scores", ("moe_route",), _DECODER,
-          "the router product, softmax or sigmoid, top_k"),
+          "the router product (where the router is one matrix), softmax "
+          "or sigmoid, the bias, top_k"),
     Scope("route_sort", ("moe_route",), _DECODER,
           "route_local: the sort, bincount, indices, weights"),
     Scope("pair_dispatch", ("moe_route",), _DECODER,
@@ -155,7 +173,7 @@ SCOPES: Tuple[Scope, ...] = (
           "kernels, which carry no path)"),
     Scope("expert_mask", ("moe_experts",), _DECODER,
           "_ragged's zeroing of the rows past the last group"),
-    Scope("moe_shared", ("sublayer_ffn",), _DECODER,
+    Scope("moe_shared", ("sublayer_ffn",), _SHARED_EXPERT,
           "the shared expert and its add"),
     # -- hyper-connections (ops/hyper_connections.py) --------------------
     Scope("mhc", ("sublayer_mixer", "sublayer_ffn"), ("xing4_0",),
@@ -179,11 +197,12 @@ SCOPES: Tuple[Scope, ...] = (
 
 NAMES = frozenset(s.name for s in SCOPES)
 
-#: what the benchmark's kernel readers match by substring
-#: (``benchmarks/lib/scopes.py``, ``glm_work.py``, ``xing_work.py``)
+#: what the benchmark's kernel readers match: by substring
+#: (``benchmarks/lib/scopes.py``, ``glm_work.py``, ``xing_work.py``), and
+#: ``cca_core`` as a segment of the scope tree (``zaya_work.py``)
 KERNEL_SCOPES = ("gdn", "gdn_scan", "gated_attn", "moe_route", "moe_experts",
                  "moe_shared", "lm_head_loss", "dense_mlp", "mla_attn",
-                 "mla_core", "mtp", "mhc", "mhc_maps", "mhc_mix")
+                 "mla_core", "mtp", "mhc", "mhc_maps", "mhc_mix", "cca_core")
 
 
 def scope(name: str):

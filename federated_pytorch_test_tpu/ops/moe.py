@@ -2,7 +2,9 @@
 
 The chip routes over ALL ``n_experts`` (the router keeps its published
 width; :func:`router_weights` is the softmax rule,
-:func:`sigmoid_router_weights` the sigmoid rule with a selection bias),
+:func:`sigmoid_router_weights` the sigmoid rule with a selection bias,
+:func:`softmax_bias_router_weights` the softmax rule with a balancing
+bias and weights that are not renormalised),
 keeps each token's top-k weights as they are, and computes only the terms
 of the experts ``[first, first + held)`` it holds.  What the
 absent experts would add is left out; on one chip there is no exchange.
@@ -83,6 +85,19 @@ def sigmoid_router_weights(logits: jnp.ndarray, bias: jnp.ndarray, top_k: int,
     if renormalise:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
     return w * scale, e.astype(jnp.int32)
+
+
+def softmax_bias_router_weights(logits: jnp.ndarray, bias: jnp.ndarray,
+                                top_k: int):
+    """``(weights [T, k] f32, experts [T, k] int32)``: softmax over all
+    experts in float32; the top-k are chosen by probability + ``bias
+    [n_experts]`` (a balancing buffer that no gradient reaches), their
+    weights are the probabilities WITHOUT the bias and NOT renormalised:
+    at ``k = 1`` a renormalised weight is the constant 1 and the router
+    would get no gradient."""
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    _, e = lax.top_k(probs + lax.stop_gradient(bias), top_k)
+    return jnp.take_along_axis(probs, e, axis=-1), e.astype(jnp.int32)
 
 
 def route_local(weights, experts, first: int, held: int, rows: int) -> Routing:

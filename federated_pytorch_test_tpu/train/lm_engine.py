@@ -12,8 +12,13 @@ and ``round_fields`` turns them into the round record's ``tokens``,
 the rows of the steps' pair buffers: how much of what ``ops/moe.py``
 sizes for the worst traffic its loops visit; 0 for a model without
 experts), ``mtp_loss`` (the summed multi-token-prediction term
-of a model that has such a layer, else 0) and ``mhc_marginal_err`` (the
+of a model that has such a layer, else 0), ``mhc_marginal_err`` (the
 worst marginal error of a model's hyper-connection mixing matrices,
+averaged over the round's steps, else 0), ``moe_top1_weight_mean`` (the
+mean routing weight of the local pairs of a model that reports their
+sum, ``aux["moe_weight_sum"]``: at one expert a token, the chosen
+expert's probability; else 0) and ``router_state_rms`` (the RMS of the
+state a model's routers hand from layer to layer, after the last layer,
 averaged over the round's steps, else 0), and adds the model's own
 ``impl_fields``: which implementations its shapes take on this backend
 (``attn_impl``, ``gdn_scan_impl``).  The trainer names no model: a
@@ -45,14 +50,17 @@ from federated_pytorch_test_tpu.train.engine import (
 
 #: the counters kept per client, all sums over local steps
 _COUNTERS = ("steps", "moe_pairs_local", "moe_dropped", "moe_rows",
-             "moe_load_sum", "mtp_loss_sum", "mhc_err_sum")
-_FLOAT_COUNTERS = ("moe_load_sum", "mtp_loss_sum", "mhc_err_sum")
+             "moe_load_sum", "mtp_loss_sum", "mhc_err_sum",
+             "moe_weight_sum", "router_rms_sum")
+_FLOAT_COUNTERS = ("moe_load_sum", "mtp_loss_sum", "mhc_err_sum",
+                   "moe_weight_sum", "router_rms_sum")
 
 
 class LMTrainer(BlockwiseFederatedTrainer):
     """Federated next-token training of a ``BlockModule`` whose
     ``__call__(ids)`` returns ``(logits, aux)`` (``models/qwen3_next.py``,
-    ``models/glm4_moe_lite.py``, ``models/xing4_0.py``).
+    ``models/glm4_moe_lite.py``, ``models/xing4_0.py``,
+    ``models/zaya.py``).
     No L1/L2 term on any block; evaluation is the mean test loss."""
 
     obs_engine = "lm"
@@ -116,7 +124,11 @@ class LMTrainer(BlockwiseFederatedTrainer):
                        weighted_mean(aux["mtp_loss"], wb)
                        if "mtp_loss" in aux else 0.0),
                    "mhc_err_sum": bs["mhc_err_sum"]
-                   + aux.get("mhc_marginal_err", 0.0)}
+                   + aux.get("mhc_marginal_err", 0.0),
+                   "moe_weight_sum": bs["moe_weight_sum"]
+                   + aux.get("moe_weight_sum", 0.0),
+                   "router_rms_sum": bs["router_rms_sum"]
+                   + aux.get("router_state_rms", 0.0)}
             return weighted_mean(per_seq, wb), new
 
     def eval_batch_metric(self, p, bs, xb, yb, wb):
@@ -150,6 +162,9 @@ class LMTrainer(BlockwiseFederatedTrainer):
                 / max(d["moe_rows"], 1.0),
                 "mtp_loss": d["mtp_loss_sum"],
                 "mhc_marginal_err": d["mhc_err_sum"] / steps,
+                "moe_top1_weight_mean": d["moe_weight_sum"] / max(
+                    d["moe_pairs_local"] - d["moe_dropped"], 1.0),
+                "router_state_rms": d["router_rms_sum"] / steps,
                 **self.model.impl_fields(self.data.tokens_per_sample)}
 
     def _block_index(self, ci: int) -> int:

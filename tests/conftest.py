@@ -22,6 +22,8 @@ if not _USE_TPU:
         os.environ["XLA_FLAGS"] = (
             flags + " --xla_force_host_platform_device_count=8").strip()
 
+_WORKER = os.environ.get("PYTEST_XDIST_WORKER")
+
 import jax  # noqa: E402
 
 if not _USE_TPU:
@@ -50,6 +52,23 @@ from federated_pytorch_test_tpu.utils.compile_cache import (  # noqa: E402
 )
 
 enable_persistent_compile_cache()
+if _WORKER:
+    # Under pytest-xdist nothing is written to the cache (no program takes
+    # an hour to compile).  jaxlib's CPU client dies of a segmentation
+    # fault, now and then, while it serialises an executable for the cache
+    # or loads one back (``compiler.py:_cache_write`` / ``_cache_read`` on
+    # the stack, the engine's eight-device programs below them; no lock
+    # or rename protects an entry either): on 2026-10-05 in each of four
+    # whole runs of PR 38's tree at ``-n 6``, with a directory a worker
+    # or not.  A dead worker costs every later test of its file, and a
+    # checkout that starts without a cache, as the driver's does, gains
+    # nothing from one: the whole run took 704 s without it and 810 s
+    # with it.  (What is left, on the same day and on the parent's tree
+    # too: the same fault inside the compile itself,
+    # ``backend_compile_and_load``, once or twice a run, whatever
+    # ``--xla_cpu_parallel_codegen_split_count`` says.)  Without xdist the
+    # floor stays where the helper puts it.
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 3600.0)
 
 
 def pytest_configure(config):

@@ -155,7 +155,7 @@ class TestSchema:
         validate_record(round_record(loss=float("nan")))
 
 
-#: the fields PRs 24-35 added: (kinds, a valid value, an ill-typed one,
+#: the fields PRs 24-38 added: (kinds, a valid value, an ill-typed one,
 #: advisory or core).  A new field is one more line here (and one in
 #: obs/schema.py: README "Observability", "how to add a field").
 DECLARED = {
@@ -173,6 +173,9 @@ DECLARED = {
     "moe_dropped": (("round",), 0, "none", False),
     "moe_fill_share": (("round",), 0.125, "an eighth", False),
     "mtp_loss": (("round",), 9.87, "high", False),
+    "mhc_marginal_err": (("round",), 3e-6, "small", False),
+    "moe_top1_weight_mean": (("round",), 0.11, "a ninth", False),
+    "router_state_rms": (("round",), 1.4, "grown", False),
     "gdn_scan_impl": (("round",), "pallas", 1, True),
     "attn_impl": (("round",), "xla", 0, True),
     "mhc_impl": (("round",), "pallas_interpret", 2, True),

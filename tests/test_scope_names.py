@@ -1,5 +1,5 @@
 """The program's scope table (``obs/scopes.py``) against the programs
-themselves: the epoch and exchange programs of the three decoders (at
+themselves: the epoch and exchange programs of the four decoders (at
 the tiny sizes of their benchmark tests) and of ResNet18 are lowered on
 the CPU and every operation's JAX path is read from
 ``lower().as_text(debug_info=True)``.
@@ -8,7 +8,7 @@ the CPU and every operation's JAX path is read from
   slices, the vmap's moves) lies under a name of the table, and inside
   ``model_loss`` under a deeper one;
 - names nest as the table says, every name occurs in the program it is
-  declared for, the fourteen names of the kernel readers still occur;
+  declared for, the fifteen names of the kernel readers still occur;
 - the names add no operation: the text without locations is the same
   with every ``jax.named_scope`` a no-op;
 - no new name holds a name the benchmark's three ``scope_of`` functions
@@ -50,6 +50,7 @@ PROGRAMS = {
     "glm4_moe_lite": ("test_glm_benchmark", "decoder",
                       ("decoder", "glm4_moe_lite")),
     "xing4_0": ("test_xing_benchmark", "decoder_hc", ("decoder", "xing4_0")),
+    "zaya": ("test_zaya_benchmark", "decoder_tied", ("decoder", "zaya")),
     "resnet18": (None, "classifier", ()),
 }
 PARENTS = {s.name: s.parents for s in SCOPES}
@@ -270,14 +271,17 @@ def test_every_name_of_the_program_occurs(lowered, which):
 
 def test_the_kernel_readers_names_still_occur(lowered):
     seen = set()
-    for which in ("qwen3_next", "glm4_moe_lite", "xing4_0"):
+    for which in ("qwen3_next", "glm4_moe_lite", "xing4_0", "zaya"):
         text = lowered(which)["epoch"].as_text(debug_info=True)
         for _, path, _ in op_paths(text):
             seen.update(raw_chain(path))
     assert set(KERNEL_SCOPES) <= seen
-    assert set(KERNEL_SCOPES) <= NAMES and len(KERNEL_SCOPES) == 14
+    assert set(KERNEL_SCOPES) <= NAMES and len(KERNEL_SCOPES) == 15
+    # the fourteen the three substring readers match, and the one core
+    # zaya_work.py reads from the scope tree's whole segments
     assert set(bench_scopes.SCOPES) | set(glm_work.SCOPES) \
-        | set(xing_work.SCOPES) | {"mtp", "mhc"} == set(KERNEL_SCOPES)
+        | set(xing_work.SCOPES) | {"mtp", "mhc", "cca_core"} \
+        == set(KERNEL_SCOPES)
 
 
 @pytest.mark.parametrize("which", list(PROGRAMS))

@@ -79,6 +79,32 @@ def op(name, start, dur, category="loop fusion"):
      (), "backward"),
     ("jit(f)/vmap(jvp(penalty))/mul:", ("client_grad", "penalty"),
      "forward"),
+    # the fourth decoder: what compression adds around the core, the
+    # core's backward rule under both its names, the router that is an
+    # MLP, the scaled merge under either sub-layer
+    (GRAD + "jvp(model_loss)/Zaya/sublayer_mixer/while/body/closed_call/"
+     "checkpoint/cca_attn/cca_mix/htd,hde->hte/dot_general:",
+     ("client_grad", "model_loss", "sublayer_mixer", "cca_attn", "cca_mix"),
+     "forward"),
+    (GRAD + "transpose(jvp(model_loss))/Zaya/sublayer_mixer/"
+     "jvp(model_loss)/Zaya/sublayer_mixer/while/body/closed_call/checkpoint/"
+     "cca_attn/cca_core/cca_attn/cca_core/pallas_call:",
+     ("client_grad", "model_loss", "sublayer_mixer", "cca_attn", "cca_core"),
+     "backward"),
+    (GRAD + "transpose(jvp(model_loss))/Zaya/sublayer_ffn/"
+     "jvp(model_loss)/Zaya/sublayer_ffn/checkpoint/rematted_computation/"
+     "moe_route/route_mlp/dot_general:",
+     ("client_grad", "model_loss", "sublayer_ffn", "moe_route", "route_mlp"),
+     "remat"),
+    (GRAD + "jvp(model_loss)/Zaya/sublayer_ffn/checkpoint/res_scale/mul:",
+     ("client_grad", "model_loss", "sublayer_ffn", "res_scale"), "forward"),
+    # lifted out of the map over sequences: put back under the mixer
+    (GRAD + "res_scale/mul:",
+     ("client_grad", "model_loss", "sublayer_mixer", "res_scale"),
+     "forward"),
+    (GRAD + "cca_core/attn_layout/convert_element_type:",
+     ("client_grad", "model_loss", "sublayer_mixer", "cca_attn", "cca_core",
+      "attn_layout"), "forward"),
     ("", (), "forward"),
 ])
 def test_parse(path, chain, direction):
@@ -172,6 +198,51 @@ def test_tree_self_time_directions_buckets_and_the_rest():
     assert ns(st.scope_seconds(tree, "gdn_conv")) == 300
     assert ns(st.scope_seconds(tree, "gdn_conv", "remat")) == 50
     assert ns(st.scope_seconds(tree, "moe_experts")) == 100   # the kernels
+
+
+def test_tree_of_the_fourth_decoder_s_scopes_adds_up():
+    """A pass with the names PR 38 adds: every new scope is a node under
+    ``sublayer_mixer`` or ``sublayer_ffn``, the mixer's node holds its
+    parts, and the parts add up to the busy time."""
+    zaya = GRAD + "jvp(model_loss)/Zaya/"
+    mixer = zaya + "sublayer_mixer/while/body/closed_call/checkpoint/"
+    ffn = zaya + "sublayer_ffn/checkpoint/"
+    back = GRAD + ("transpose(jvp(model_loss))/Zaya/sublayer_mixer/"
+                   "jvp(model_loss)/Zaya/sublayer_mixer/while/body/"
+                   "closed_call/checkpoint/")
+    pass_ = [
+        (op("while.1", 0, 1000, "while"), STEP + "while:"),
+        (op("fusion.1", 0, 100), mixer + "cca_attn/attn_proj_in/dot_general:"),
+        (op("fusion.2", 100, 60), mixer + "cca_attn/cca_mix/mul:"),
+        (op("fusion.3", 160, 40), mixer + "cca_attn/attn_norm_rope/rsqrt:"),
+        (op("cca_core.4", 200, 150, "custom-call"),
+         mixer + "cca_attn/cca_core/pallas_call:"),
+        (op("cca_core.5", 350, 250, "custom-call"),
+         back + "cca_attn/cca_core/cca_attn/cca_core/pallas_call:"),
+        (op("fusion.6", 600, 30), mixer + "res_scale/add:"),
+        (op("fusion.7", 630, 70), ffn + "moe_route/route_mlp/dot_general:"),
+        (op("fusion.8", 700, 20), ffn + "moe_route/route_scores/reduce_max:"),
+        (op("fusion.9", 720, 30), ffn + "res_scale/add:"),
+        (op("ragged-dot-general.1", 750, 150, "custom-call"), ""),
+    ]
+    tree = st.tree_of(st.leaves(pass_), 0.0, 1000.0)
+    ns = lambda sec: round(sec * 1e9, 6)
+    total = lambda key: ns(sum(tree["nodes"][key]["s"]))
+    top = "client_grad/model_loss/"
+    assert total(top + "sublayer_mixer/cca_attn") == 600
+    assert total(top + "sublayer_mixer/cca_attn/cca_core") == 400
+    assert [ns(v) for v in tree["nodes"][
+        top + "sublayer_mixer/cca_attn/cca_core"]["s"]] == [150, 0, 250]
+    assert total(top + "sublayer_mixer/cca_attn/cca_mix") == 60
+    assert total(top + "sublayer_mixer/res_scale") == 30
+    assert total(top + "sublayer_ffn/res_scale") == 30
+    assert total(top + "sublayer_ffn/moe_route/route_mlp") == 70
+    assert ns(sum(tree["nodes"][top + "sublayer_mixer/cca_attn"]["self"])) \
+        == 0
+    assert ns(st.scope_seconds(tree, "res_scale")) == 60
+    assert ns(st.scope_seconds(tree, "cca_core", "backward")) == 250
+    assert ns(tree["busy_s"]) == 900 == ns(st.parts_s(tree))
+    assert ns(tree["unnamed_s"]) == 0
 
 
 def test_under_and_the_two_tables():
