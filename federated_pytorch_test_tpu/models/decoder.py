@@ -23,9 +23,12 @@ import jax.numpy as jnp
 from federated_pytorch_test_tpu.obs.scopes import scope
 from federated_pytorch_test_tpu.ops import moe as moelib
 from federated_pytorch_test_tpu.ops.flash_attention import causal_attention
+from federated_pytorch_test_tpu.ops import head_loss as headlib
 
 _F32 = jnp.float32
 _op = moelib.operand
+#: the round field ``head_impl`` of every decoder
+HEAD_IMPL = headlib.IMPL
 _ZEROS, _ONES = nn.initializers.zeros, nn.initializers.ones
 
 
@@ -268,11 +271,27 @@ def tied_head_logits(cfg, x, norm, embedding):
     with scope("lm_head_loss"):
         with scope("head_norm"):
             xn = rms_norm(x, norm, cfg.rms_norm_eps)
-        with scope("head_product"):
-            return jax.lax.dot_general(
-                _op(xn, cfg.dtype), _op(embedding, cfg.dtype),
-                (((xn.ndim - 1,), (1,)), ((), ())),
-                preferred_element_type=_F32)
+        return headlib.logits(xn, embedding, contract=1, dtype=cfg.dtype)
+
+
+def head_losses(cfg, norm, x, w, labels, token_weight=None, contract=0):
+    """The loss of each sequence ``[B]`` of ``x [B, T, H]`` against
+    ``labels [B, T]``: the model's final norm ``norm`` (``[T, H] -> [T,
+    H]``), then ``ops/head_loss.py:head_loss`` over the head's matrix
+    ``w`` (``[H, V]``; ``[V, H]`` with ``contract=1``), sequence by
+    sequence, so that one sequence's ``[T, V]`` float32 logits are
+    alive at a time.  Nothing is rematerialised: the op takes the
+    gradient of its inputs in the forward pass, and what the map keeps
+    for the backward is ``[T, H]`` a sequence (the norm's input, the
+    op's ``dx``) and, where ``w`` is being trained, ``w``'s shape."""
+    def one(a):
+        with scope("lm_head_loss"):
+            with scope("head_norm"):
+                xn = norm(a[0])
+            return headlib.head_loss(xn, w, a[1], token_weight,
+                                     contract=contract, dtype=cfg.dtype)
+
+    return jax.lax.map(one, (x, labels))
 
 
 def sequence_loss(logits, labels):

@@ -20,7 +20,9 @@ file is compared with.  Here latent attention runs in its expanded form
 form and a compressed cache are serving's), the matrix products run in
 ``dtype`` (bfloat16 on the chip) with float32 sums; parameters, norms,
 the router and the loss are float32; each sub-layer is rematerialised in
-the backward pass (``jax.checkpoint``), the mixers sequence by sequence.
+the backward pass (``jax.checkpoint``), the mixers sequence by sequence;
+both heads' losses take their inputs' gradient in the forward pass
+(``ops/head_loss.py``).
 
 The expert layer is told which experts it holds (``experts_held`` of
 ``n_routed_experts`` from ``ep_rank * experts_held``): it routes over all
@@ -57,6 +59,7 @@ import jax.numpy as jnp
 
 from federated_pytorch_test_tpu.models.base import BlockModule
 from federated_pytorch_test_tpu.models.decoder import (
+    HEAD_IMPL,
     _F32,
     _ONES,
     _Leaves,
@@ -64,12 +67,12 @@ from federated_pytorch_test_tpu.models.decoder import (
     _normal,
     dense_mlp,
     dense_mlp_leaves,
+    head_losses,
     latent_attention,
     mla_leaves,
     moe_aux,
     rms_norm,
     routing_counts,
-    sequence_loss,
     sigmoid_expert_layer as expert_layer,
     sigmoid_moe_leaves,
 )
@@ -167,7 +170,7 @@ class Glm4MoeLite(BlockModule):
     def impl_fields(self, tokens: int) -> Dict[str, str]:
         """The round record's fields that name this backend's
         implementations for sequences of ``tokens``."""
-        return {"attn_impl": self.attn_impl(tokens)}
+        return {"attn_impl": self.attn_impl(tokens), "head_impl": HEAD_IMPL}
 
     def _spec(self, name: str):
         H, s = self.hidden_size, _normal(self.init_scale)
@@ -291,9 +294,9 @@ def forward(cfg: Glm4MoeLite, p, ids, labels=None):
 
     if labels is None:
         return head_logits(cfg, p, x, p["head"]["norm"]), aux()
-    one = jax.checkpoint(lambda a: sequence_loss(
-        head_logits(cfg, p, a[0], p["head"]["norm"]), a[1]))
-    loss = jax.lax.map(one, (x, labels))
+    norm = lambda w: lambda a: rms_norm(a, w, cfg.rms_norm_eps)
+    loss = head_losses(cfg, norm(p["head"]["norm"]), x, p["head"]["kernel"],
+                       labels)
     mtp = jnp.zeros_like(loss)
     if cfg.num_nextn_predict_layers:
         with scope("mtp"):
@@ -303,17 +306,8 @@ def forward(cfg: Glm4MoeLite, p, ids, labels=None):
             z, counts = mtp_layer(cfg, p, x, labels)
             routed.append(counts)
             target = jnp.roll(labels, -1, axis=1)
-            seen = (jnp.arange(T) < T - 1).astype(_F32)
-
-            @jax.checkpoint
-            def one_mtp(a):
-                logits = head_logits(cfg, p, a[0], p["mtp_moe"]["head_norm"])
-                with scope("lm_head_loss"), scope("head_softmax"):
-                    lse = jax.nn.logsumexp(logits, axis=-1)
-                    picked = jnp.take_along_axis(
-                        logits, a[1][:, None], -1)[:, 0]
-                    return jnp.sum((lse - picked) * seen) / max(T - 1, 1)
-
-            mtp = jax.lax.map(one_mtp, (z, target))
+            weight = (jnp.arange(T) < T - 1).astype(_F32) / max(T - 1, 1)
+            mtp = head_losses(cfg, norm(p["mtp_moe"]["head_norm"]), z,
+                              p["head"]["kernel"], target, weight)
             loss = loss + cfg.mtp_loss_weight * mtp
     return loss, aux(mtp_loss=mtp)

@@ -14,7 +14,8 @@ file is compared with.  Here the matrix products run in ``dtype``
 (bfloat16 on the chip) with float32 sums; parameters, norms, the router,
 the gates, the state decay and the loss are float32; each sub-layer is
 rematerialised in the backward pass (``jax.checkpoint``), the mixers
-sequence by sequence.
+sequence by sequence; the head's loss takes its inputs' gradient in the
+forward pass (``ops/head_loss.py``).
 
 What this decoder shares with ``models/glm4_moe_lite.py`` (a block's
 leaves, the rotary tables, the loss helpers, the held experts' sort,
@@ -51,6 +52,7 @@ import jax.numpy as jnp
 
 from federated_pytorch_test_tpu.models.base import BlockModule
 from federated_pytorch_test_tpu.models.decoder import (  # noqa: F401
+    HEAD_IMPL,
     _F32,
     _ONES,
     _ZEROS,
@@ -58,12 +60,12 @@ from federated_pytorch_test_tpu.models.decoder import (  # noqa: F401
     _mm,
     _normal,
     apply_rope,
+    head_losses,
     held_experts,
     moe_aux,
     next_token_loss,
     rope_tables,
     routing_counts,
-    sequence_loss,
     weighted_mean,
 )
 from federated_pytorch_test_tpu.obs.scopes import scope
@@ -175,7 +177,7 @@ class Qwen3Next(BlockModule):
         """The round record's fields that name this backend's
         implementations for sequences of ``tokens``."""
         return {"gdn_scan_impl": self.gdn_scan_impl(tokens),
-                "attn_impl": self.attn_impl(tokens)}
+                "attn_impl": self.attn_impl(tokens), "head_impl": HEAD_IMPL}
 
     def _spec(self, name: str):
         H, s = self.hidden_size, _normal(self.init_scale)
@@ -368,14 +370,11 @@ def forward(cfg: Qwen3Next, p, ids, labels=None):
     with scope("step_stats"):
         aux = moe_aux(routed)
 
-    def head(xt):
+    norm = lambda xt: rms_norm(xt, p["head"]["norm"], eps)
+    if labels is None:
         with scope("lm_head_loss"):
             with scope("head_norm"):
-                xn = rms_norm(xt, p["head"]["norm"], eps)
+                xn = norm(x)
             with scope("head_product"):
-                return _mm(cfg, xn, p["head"]["kernel"])
-
-    if labels is None:
-        return head(x), aux
-    one = jax.checkpoint(lambda a: sequence_loss(head(a[0]), a[1]))
-    return jax.lax.map(one, (x, labels)), aux
+                return _mm(cfg, xn, p["head"]["kernel"]), aux
+    return head_losses(cfg, norm, x, p["head"]["kernel"], labels), aux

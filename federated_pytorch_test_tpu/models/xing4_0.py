@@ -60,6 +60,7 @@ import jax.numpy as jnp
 
 from federated_pytorch_test_tpu.models.base import BlockModule
 from federated_pytorch_test_tpu.models.decoder import (
+    HEAD_IMPL,
     _F32,
     _ONES,
     _ZEROS,
@@ -68,12 +69,12 @@ from federated_pytorch_test_tpu.models.decoder import (
     _normal,
     dense_mlp,
     dense_mlp_leaves,
+    head_losses,
     latent_attention,
     mla_leaves,
     moe_aux,
     rms_norm,
     routing_counts,
-    sequence_loss,
     sigmoid_expert_layer as expert_layer,
     sigmoid_moe_leaves,
     yarn_inv_freq,
@@ -194,7 +195,7 @@ class Xing4(BlockModule):
         """The round record's fields that name this backend's
         implementations for sequences of ``tokens``."""
         return {"attn_impl": self.attn_impl(tokens),
-                "mhc_impl": self.mhc_impl(tokens)}
+                "mhc_impl": self.mhc_impl(tokens), "head_impl": HEAD_IMPL}
 
     # -- rotary tables and the softmax scale -----------------------------
     def rope_inv_freq(self):
@@ -355,14 +356,11 @@ def forward(cfg: Xing4, p, ids, labels=None):
     with scope("step_stats"):
         aux = {**moe_aux(routed), "mhc_marginal_err": err}
 
-    def logits_of(a):
+    norm = lambda a: rms_norm(a, p["head"]["norm"], cfg.rms_norm_eps)
+    if labels is None:
         with scope("lm_head_loss"):
             with scope("head_norm"):
-                an = rms_norm(a, p["head"]["norm"], cfg.rms_norm_eps)
+                xn = norm(x)
             with scope("head_product"):
-                return _mm(cfg, an, p["head"]["kernel"])
-
-    if labels is None:
-        return logits_of(x), aux
-    one = jax.checkpoint(lambda a: sequence_loss(logits_of(a[0]), a[1]))
-    return jax.lax.map(one, (x, labels)), aux
+                return _mm(cfg, xn, p["head"]["kernel"]), aux
+    return head_losses(cfg, norm, x, p["head"]["kernel"], labels), aux

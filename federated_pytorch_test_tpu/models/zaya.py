@@ -29,8 +29,9 @@ compared with.  Matrix products run in ``dtype`` (bfloat16 on the chip)
 with float32 sums, the per-head convolution's among them; parameters,
 norms, the depthwise taps, the unit-sphere norm, the whole router (at
 ``HIGHEST``) and the loss are float32.  Each sub-layer is rematerialised
-in the backward pass (``jax.checkpoint``), the mixer and the head's loss
-sequence by sequence.
+in the backward pass (``jax.checkpoint``), the mixer sequence by
+sequence; the head's loss runs sequence by sequence and takes its
+inputs' gradient in the forward pass (``ops/head_loss.py``).
 
 The expert layer is told which experts it holds (``experts_held`` of
 ``num_experts`` from ``ep_rank * experts_held``): it routes over all of
@@ -72,6 +73,7 @@ import jax.numpy as jnp
 
 from federated_pytorch_test_tpu.models.base import BlockModule
 from federated_pytorch_test_tpu.models.decoder import (
+    HEAD_IMPL,
     _F32,
     _ONES,
     _Leaves,
@@ -79,12 +81,12 @@ from federated_pytorch_test_tpu.models.decoder import (
     _normal,
     _op,
     apply_rope,
+    head_losses,
     held_experts,
     moe_aux,
     rms_norm,
     rope_tables,
     routing_counts,
-    sequence_loss,
     tied_head_logits,
 )
 from federated_pytorch_test_tpu.obs.scopes import scope
@@ -177,7 +179,7 @@ class Zaya(BlockModule):
     def impl_fields(self, tokens: int) -> Dict[str, str]:
         """The round record's fields that name this backend's
         implementations for sequences of ``tokens``."""
-        return {"attn_impl": self.attn_impl(tokens)}
+        return {"attn_impl": self.attn_impl(tokens), "head_impl": HEAD_IMPL}
 
     def router_balance(self, params, ids):
         """``params``' balancing biases set by the load of ``ids [B,
@@ -464,9 +466,8 @@ def forward(cfg: Zaya, p, ids, labels=None):
     with scope("step_stats"):
         aux = {**moe_aux(routed), "moe_weight_sum": sum(weights, _F32(0)),
                "router_state_rms": jnp.sqrt(jnp.mean(state * state))}
-    logits_of = lambda a: tied_head_logits(cfg, a, p["final_norm"]["norm"],
-                                           emb)
+    norm = p["final_norm"]["norm"]
     if labels is None:
-        return logits_of(x), aux
-    one = jax.checkpoint(lambda a: sequence_loss(logits_of(a[0]), a[1]))
-    return jax.lax.map(one, (x, labels)), aux
+        return tied_head_logits(cfg, x, norm, emb), aux
+    return head_losses(cfg, lambda a: rms_norm(a, norm, cfg.rms_norm_eps),
+                       x, emb, labels, contract=1), aux

@@ -220,6 +220,8 @@ FIELDS: Dict[str, Any] = {
     # the same for the hyper-connections' passes over the streams
     # (ops/hyper_connections.py: plan)
     "mhc_impl": (("round",), _STR),
+    # the same for the head's loss (ops/head_loss.py: IMPL): fused
+    "head_impl": (("round",), _STR),
     # fault / guard counters
     "guard_trips":  (("round",), _NUM),
     "guard_norm_mean": (("round",), _NUM),
@@ -395,8 +397,8 @@ ADVISORY_FIELDS = (
     "dispatch_max_seconds", "dispatch_max_site", "dispatch_new_signatures",
     "block_switch_h2d_bytes",
     # which implementation this backend took for the recurrence, for the
-    # attention core and for the hyper-connections
-    "gdn_scan_impl", "attn_impl", "mhc_impl",
+    # attention core, for the hyper-connections and for the head's loss
+    "gdn_scan_impl", "attn_impl", "mhc_impl", "head_impl",
     # serving-plane latency/throughput telemetry
     "serve_p50_ms", "serve_p99_ms", "serve_qps", "swap_gap_seconds",
     "serve_accuracy", "drift_score", "forced_refresh",

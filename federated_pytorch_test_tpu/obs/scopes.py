@@ -190,9 +190,12 @@ SCOPES: Tuple[Scope, ...] = (
           "final norm, head product, cross-entropy (self: nothing)"),
     Scope("head_norm", ("lm_head_loss",), _DECODER, "the final RMS norm"),
     Scope("head_product", ("lm_head_loss",), _DECODER,
-          "the head's matrix product and its casts"),
+          "the head's matrix product and its casts; ops/head_loss.py's "
+          "forward rule's dx and dw products"),
     Scope("head_softmax", ("lm_head_loss",), _DECODER,
-          "logsumexp, the picked logit, the mean over the sequence"),
+          "logsumexp, the picked logit, the mean over the sequence; "
+          "(softmax - onehot) x token weight, and the backward rule's "
+          "scale"),
 )
 
 NAMES = frozenset(s.name for s in SCOPES)

@@ -27,6 +27,10 @@ so every op runs identically on CPU/interpret mode.  Currently:
     ``H_pre X``; the mixing; both transposes, the streams' cotangent
     written once); the sigmoids and the Sinkhorn projection stay
     ``jax.numpy`` (``hyper_connections.force_mhc_impl`` for tests).
+  * ``head_loss.head_loss`` — no kernel of its own: a decoder head's
+    cross-entropy under one ``custom_vjp`` whose forward rule takes the
+    gradient of its inputs from the logits it made, so the head's
+    product is never rematerialised; plain JAX on every backend.
   * ``moe`` — no kernel of its own: the two router rules
     (``router_weights``, ``sigmoid_router_weights``) and the held
     experts' sorted pairs and grouped products (``jax.lax.ragged_dot``,

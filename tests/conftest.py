@@ -6,6 +6,7 @@ client-mesh collectives (shard_map / pmean over the 'clients' axis) are
 exercised for real (SURVEY.md section 4's distributed-test strategy).
 """
 
+import gc
 import os
 
 # FEDTPU_TEST_TPU=1 keeps the hardware backend so the TPU-gated tests
@@ -53,22 +54,41 @@ from federated_pytorch_test_tpu.utils.compile_cache import (  # noqa: E402
 
 enable_persistent_compile_cache()
 if _WORKER:
-    # Under pytest-xdist nothing is written to the cache (no program takes
-    # an hour to compile).  jaxlib's CPU client dies of a segmentation
-    # fault, now and then, while it serialises an executable for the cache
-    # or loads one back (``compiler.py:_cache_write`` / ``_cache_read`` on
-    # the stack, the engine's eight-device programs below them; no lock
-    # or rename protects an entry either): on 2026-10-05 in each of four
-    # whole runs of PR 38's tree at ``-n 6``, with a directory a worker
-    # or not.  A dead worker costs every later test of its file, and a
-    # checkout that starts without a cache, as the driver's does, gains
-    # nothing from one: the whole run took 704 s without it and 810 s
-    # with it.  (What is left, on the same day and on the parent's tree
-    # too: the same fault inside the compile itself,
-    # ``backend_compile_and_load``, once or twice a run, whatever
-    # ``--xla_cpu_parallel_codegen_split_count`` says.)  Without xdist the
-    # floor stays where the helper puts it.
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 3600.0)
+    # Under pytest-xdist the persistent cache is off: nothing is read or
+    # written.  jaxlib's CPU client dies of a segmentation fault, now and
+    # then, while it serialises an executable for the cache or loads one
+    # back (``compiler.py:_cache_write`` / ``_cache_read`` on the stack;
+    # no lock or rename protects an entry either): on 2026-10-05 in each
+    # of four whole runs of PR 38's tree at ``-n 6``.  A dead worker costs
+    # every later test of its file, and a checkout that starts without a
+    # cache, as the driver's does, gains nothing from one (704 s without
+    # it, 810 s with it).  PR 38 raised the floor to an hour instead, and
+    # every test that starts a driver or ``benchmarks/run.py`` in its own
+    # process (``drivers/common.py:setup_runtime``, ``run.py:main``) put
+    # it back to a second: the driver's run of PR 38's tree lost a worker
+    # in ``_cache_write`` under ``test_xing_benchmark.py``.  The switch
+    # below is one no helper touches.  The faults inside the compile
+    # itself (``backend_compile_and_load``) were the memory maps
+    # (``pytest_runtest_teardown`` below); whether the cache's were too is
+    # not known.  Without xdist the cache stays where the helper puts it.
+    jax.config.update("jax_enable_compilation_cache", False)
+
+
+def pytest_runtest_teardown(item, nextitem):
+    # Between two test files, drop every compiled program.  An XLA:CPU
+    # executable keeps its code in memory maps of its own for as long as a
+    # jit cache holds it, and one process may hold 65,530 maps
+    # (``vm.max_map_count``): run in one process, ``test_engine.py``,
+    # ``test_fused.py``, ``test_golden_trajectories.py`` and
+    # ``test_glm4_moe_lite.py`` leave 63,983, and the next compile aborts
+    # in ``contiguous_section_memory_manager.cc`` ("allocateMappedMemory
+    # failed ... Cannot allocate memory") or dies of a segmentation fault.
+    # That is what killed a worker late in each of the driver's ``-n 6``
+    # runs, in whichever large compile came next (``test_zaya.py``,
+    # ``test_xing4_0.py``, the benchmark files' checks), PRs 38 and 39.
+    if nextitem is None or nextitem.module is not item.module:
+        jax.clear_caches()
+        gc.collect()
 
 
 def pytest_configure(config):

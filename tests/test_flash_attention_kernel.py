@@ -361,7 +361,9 @@ def test_fields_declare_attn_impl():
 #: widths, batch and sequence), recorded with ``lowered_hashes`` below
 #: under this file's own pytest set-up (``conftest.py``'s flags are part
 #: of a lowered module) on the tree of PR 35, which changed both on
-#: purpose (``ops/moe.py``: ``dispatch``, ``combine``).  They guard
+#: purpose (``ops/moe.py``: ``dispatch``, ``combine``), the two loss
+#: gradients again on the tree of PR 39, which changed both heads' loss
+#: on purpose (``ops/head_loss.py``; the forwards stayed).  They guard
 #: against a change of either decoder's lowered program that nobody
 #: meant: PR 34 let the key and value widths of the attention kernels
 #: differ and left all six as they were.  A PR that means to change
@@ -370,11 +372,11 @@ def test_fields_declare_attn_impl():
 #: ``xla`` the XLA path.
 RECORDED_LOWERED = {
     "qwen3_next/pallas_interpret/forward": "bb8ecf9576e0c454",
-    "qwen3_next/pallas_interpret/loss_grad": "b05e0afe81661cbe",
-    "qwen3_next/xla/loss_grad": "035ef89c272d43ac",
+    "qwen3_next/pallas_interpret/loss_grad": "d3177217dcf334b0",
+    "qwen3_next/xla/loss_grad": "e0a42e9fa622c653",
     "glm4_moe_lite/pallas_interpret/forward": "02fdb2bcc7564198",
-    "glm4_moe_lite/pallas_interpret/loss_grad": "b484b70509ea8654",
-    "glm4_moe_lite/xla/loss_grad": "0e47426a2299547d",
+    "glm4_moe_lite/pallas_interpret/loss_grad": "511f4d43533b55e6",
+    "glm4_moe_lite/xla/loss_grad": "57b72354aabdeff0",
 }
 
 

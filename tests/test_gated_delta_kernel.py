@@ -385,12 +385,13 @@ def compiled_for(f, *ops):
 
     # a compile for a described chip is written to the persistent cache
     # but cannot be read back without the chip
+    was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     try:
         return jax.jit(f).lower(*ops).compile().as_text()
     finally:
-        jax.config.update("jax_enable_compilation_cache", True)
+        jax.config.update("jax_enable_compilation_cache", was)
         compilation_cache.reset_cache()
 
 

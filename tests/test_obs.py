@@ -179,6 +179,7 @@ DECLARED = {
     "gdn_scan_impl": (("round",), "pallas", 1, True),
     "attn_impl": (("round",), "xla", 0, True),
     "mhc_impl": (("round",), "pallas_interpret", 2, True),
+    "head_impl": (("round",), "fused", 3, True),
 }
 
 

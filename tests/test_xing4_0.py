@@ -377,7 +377,8 @@ def test_model_through_the_attention_kernels_matches_the_xla_path():
     def run(impl):
         with force_attn_impl(impl), jax.default_matmul_precision("highest"):
             assert model.impl_fields(384) == {"attn_impl": impl,
-                                              "mhc_impl": "xla"}
+                                              "mhc_impl": "xla",
+                                              "head_impl": "fused"}
             logits, _ = model.apply({"params": params}, x)
             grads = jax.grad(lambda p: weighted_mean(
                 model.apply({"params": p}, x, y)[0]))(params)

@@ -134,69 +134,87 @@ def test_model_logits_and_loss_match_reference(setup):
         assert float(per_seq[b]) == pytest.approx(float(loss), rel=1e-5)
 
 
+def _block_paths(model, block):
+    lo, hi = model.train_order_block_ids()[block]
+    return model.param_order()[lo:hi + 1]
+
+
+def _router_paths(model):
+    return [f"layer1_moe/{leaf}" for leaf, _, _ in
+            model._spec("layer1_moe")[:zaya.ROUTER_LEAVES - 1]]
+
+
+@pytest.fixture(scope="module")
+def grads(setup):
+    """The first sequence's gradient of every leaf the tests below read,
+    the program's and the reference's, each ONE jitted program.  Op by
+    op the model's gradient is hundreds of small CPU compiles a test, and
+    a reference program for each set of leaves is as many large ones,
+    each holding memory maps until the file ends (``conftest.py``)."""
+    model, params, x, y = setup
+    paths = [p for b in (EMBED, CCA1, MOE1, NORM)
+             for p in _block_paths(model, b)] + _router_paths(model)
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(jax.grad(lambda p: weighted_mean(
+            model.apply({"params": p}, x[:1], y[:1])[0])))(params)
+    _, _, want = ref.loss_and_grad(REF_CFG, params, paths, x[0], y[0])
+    return got, dict(zip(paths, want))
+
+
 @pytest.mark.parametrize("block", [EMBED, CCA1, MOE1, NORM],
                          ids=["embed", "cca", "experts", "norm"])
-def test_block_gradient_matches_reference(setup, block):
-    model, params, x, y = setup
-    lo, hi = model.train_order_block_ids()[block]
-    paths = model.param_order()[lo:hi + 1]
-    with jax.default_matmul_precision("highest"):
-        grads = jax.grad(lambda p: weighted_mean(
-            model.apply({"params": p}, x[:1], y[:1])[0]))(params)
-    _, _, want = ref.loss_and_grad(REF_CFG, params, paths, x[0], y[0])
-    for path, w in zip(paths, want):
-        assert float(jnp.max(jnp.abs(w))) > 0, path
-        assert rel(get_by_path(grads, path), w) < 2e-4, path
+def test_block_gradient_matches_reference(setup, grads, block):
+    got, want = grads
+    for path in _block_paths(setup[0], block):
+        assert float(jnp.max(jnp.abs(want[path]))) > 0, path
+        assert rel(get_by_path(got, path), want[path]) < 2e-4, path
 
 
-def test_router_leaves_gradient_matches_reference(setup):
+def test_router_leaves_gradient_matches_reference(setup, grads):
     """The router lies in no block, yet an upstream block's gradient
     passes through it: its own gradient against the reference's, the
     state's scale of a later layer among it (layer 0's meets zeros)."""
-    model, params, x, y = setup
-    paths = [f"layer1_moe/{leaf}" for leaf, _, _ in
-             model._spec("layer1_moe")[:zaya.ROUTER_LEAVES - 1]]
+    got, want = grads
+    paths = _router_paths(setup[0])
     assert paths[2] == "layer1_moe/router_state_scale"
-    with jax.default_matmul_precision("highest"):
-        grads = jax.grad(lambda p: weighted_mean(
-            model.apply({"params": p}, x[:1], y[:1])[0]))(params)
-    _, _, want = ref.loss_and_grad(REF_CFG, params, paths, x[0], y[0])
-    for path, w in zip(paths, want):
-        assert float(jnp.max(jnp.abs(w))) > 0, path
-        assert rel(get_by_path(grads, path), w) < 2e-4, path
+    for path in paths:
+        assert float(jnp.max(jnp.abs(want[path]))) > 0, path
+        assert rel(get_by_path(got, path), want[path]) < 2e-4, path
     # the balancing bias is a buffer: no gradient reaches it
-    assert not np.any(np.asarray(grads["layer1_moe"]["router_bias"]))
-    assert not np.any(np.asarray(grads["layer0_moe"]["router_state_scale"]))
+    assert not np.any(np.asarray(got["layer1_moe"]["router_bias"]))
+    assert not np.any(np.asarray(got["layer0_moe"]["router_state_scale"]))
 
 
 def _loss_of_two_embeddings(model, params, ids, labels):
     """The model's loss with the gathered embedding and the head's
-    matrix as two arguments, composed from the module's own pieces."""
+    matrix as two arguments, composed from the module's own pieces: the
+    head's part through ``ops/head_loss.py``, as the cell runs it."""
+    norm = lambda a: decoder.rms_norm(a, params["final_norm"]["norm"],
+                                      model.rms_norm_eps)
+
     def loss(gathered, head):
         x, state = gathered[ids], None
         for i in range(model.layers):
             x, state, _, _ = zaya.decoder_layer(
                 model, params[f"layer{i}_mixer"], params[f"layer{i}_moe"], x,
                 state)
-        logits = decoder.tied_head_logits(
-            model, x, params["final_norm"]["norm"], head)
-        return jnp.mean(decoder.sequence_loss(logits, labels))
+        return jnp.mean(decoder.head_losses(model, norm, x, head, labels,
+                                            contract=1))
     return loss
 
 
-def test_the_tied_embedding_s_gradient_has_two_sources(setup):
+def test_the_tied_embedding_s_gradient_has_two_sources(setup, grads):
     model, params, x, y = setup
     emb = params["embed"]["embedding"]
+    got, want = grads
+    whole, want = got["embed"]["embedding"], want["embed/embedding"]
     with jax.default_matmul_precision("highest"):
-        whole = jax.grad(lambda p: weighted_mean(
-            model.apply({"params": p}, x[:1], y[:1])[0]))(params)[
-                "embed"]["embedding"]
         loss = _loss_of_two_embeddings(model, params, x[:1], y[:1])
-        assert float(loss(emb, emb)) == pytest.approx(float(weighted_mean(
-            model.apply({"params": params}, x[:1], y[:1])[0])), rel=1e-6)
-        gather_part, head_part = jax.grad(loss, argnums=(0, 1))(emb, emb)
-    _, _, (want,) = ref.loss_and_grad(REF_CFG, params, ["embed/embedding"],
-                                      x[0], y[0])
+        assert float(jax.jit(loss)(emb, emb)) == pytest.approx(float(
+            weighted_mean(jax.jit(lambda p: model.apply(
+                {"params": p}, x[:1], y[:1])[0])(params))), rel=1e-6)
+        gather_part, head_part = jax.jit(jax.grad(loss, argnums=(0, 1)))(
+            emb, emb)
     assert rel(whole, want) < 2e-4
     assert rel(gather_part + head_part, whole) < 1e-5
     # the gather reaches the rows the sequence holds, the head every row
@@ -467,11 +485,13 @@ def test_published_widths_give_the_issue_s_parameter_counts():
     # one query block of all four heads of a group: 1,024 rows a grid step
     from federated_pytorch_test_tpu.ops.flash_attention import plan
     with force_attn_impl("pallas_interpret"):
-        assert full.impl_fields(4096) == {"attn_impl": "pallas_interpret"}
+        assert full.impl_fields(4096) == {"attn_impl": "pallas_interpret",
+                                          "head_impl": "fused"}
         p = plan(4096, 2, 4, 128, jnp.bfloat16)
     assert (p["block_q"], p["pad_k"], p["impl"]) == (256, 0,
                                                      "pallas_interpret")
-    assert full.impl_fields(4096) == {"attn_impl": "xla"}      # the CPU
+    assert full.impl_fields(4096) == {"attn_impl": "xla",     # the CPU
+                                      "head_impl": "fused"}
 
 
 # ----------------------------------------------------------------------
@@ -492,10 +512,14 @@ def test_model_through_the_attention_kernels_matches_the_xla_path():
 
     def run(impl):
         with force_attn_impl(impl), jax.default_matmul_precision("highest"):
-            assert model.impl_fields(384) == {"attn_impl": impl}
-            logits, _ = model.apply({"params": params}, x)
-            grads = jax.grad(lambda p: weighted_mean(
-                model.apply({"params": p}, x, y)[0]))(params)
+            assert model.impl_fields(384) == {"attn_impl": impl,
+                                              "head_impl": "fused"}
+            # fresh functions under each implementation: one jitted
+            # program each, where op by op they are hundreds of compiles
+            logits, _ = jax.jit(lambda p: model.apply({"params": p}, x))(
+                params)
+            grads = jax.jit(jax.grad(lambda p: weighted_mean(
+                model.apply({"params": p}, x, y)[0])))(params)
         return logits, [get_by_path(grads, path) for path in paths]
 
     (logits, grads), (want, want_grads) = run("pallas_interpret"), run("xla")
