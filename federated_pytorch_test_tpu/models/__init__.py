@@ -18,6 +18,7 @@ from federated_pytorch_test_tpu.models.qwen3_next import Qwen3Next  # noqa: F401
 from federated_pytorch_test_tpu.models.glm4_moe_lite import Glm4MoeLite  # noqa: F401
 from federated_pytorch_test_tpu.models.xing4_0 import Xing4  # noqa: F401
 from federated_pytorch_test_tpu.models.zaya import Zaya  # noqa: F401
+from federated_pytorch_test_tpu.models.olmo_hybrid import OlmoHybrid  # noqa: F401
 
 MODEL_REGISTRY = {
     "net": Net,
@@ -34,6 +35,7 @@ MODEL_REGISTRY = {
     "glm4_moe_lite": Glm4MoeLite,
     "xing4_0": Xing4,
     "zaya": Zaya,
+    "olmo_hybrid": OlmoHybrid,
 }
 
 

@@ -1,6 +1,8 @@
 """What the decoders share (``models/qwen3_next.py``,
-``models/glm4_moe_lite.py``, ``models/xing4_0.py``, ``models/zaya.py``):
-a block's leaves, the rotary tables (plain or YaRN's), the loss helpers,
+``models/glm4_moe_lite.py``, ``models/xing4_0.py``, ``models/zaya.py``,
+``models/olmo_hybrid.py``): a block's leaves, the rotary tables (plain or
+YaRN's), the reordered merge that norms a sub-layer's output, the loss
+helpers,
 the head of a model whose embedding is also its head's matrix, the part
 of an expert layer that follows the router on a chip that holds a share
 of the experts (sort, grouped products, scatter), and what the two
@@ -103,6 +105,14 @@ def apply_rope(x, cos, sin):
 def rms_norm(x, w, eps):
     x = x.astype(_F32)
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w
+
+
+def post_norm_merge(h, y, w, eps):
+    """The reordered residual merge (OLMo 2, arXiv:2501.00656): a
+    sub-layer's output ``y`` normed, then added to its un-normed input
+    ``h``: ``h + N(y; w)``."""
+    with scope("post_norm"):
+        return h + rms_norm(y, w, eps)
 
 
 def latent_attention(cfg, p, x, outer: str = "", scale=None, inv_freq=None):

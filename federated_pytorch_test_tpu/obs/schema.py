@@ -200,7 +200,10 @@ FIELDS: Dict[str, Any] = {
     # tokens apart; 0.0 where the model reports none).
     # router_state_rms: the RMS of the state a model's routers hand from
     # layer to layer, after the last layer, mean over the round's steps
-    # (0.0 for a model whose routers keep none)
+    # (0.0 for a model whose routers keep none).  gdn_neg_beta_share: the
+    # share of (token, head) pairs of the Gated DeltaNet layers whose beta
+    # is above 1 (the transition's eigenvalue along k is negative), mean
+    # over the round's steps (0.0 for a model whose beta stays below 1)
     "tokens":       (("round",), _INT),
     "block_kind":   (("round",), _STR),
     "moe_pairs_local": (("round",), _INT),
@@ -211,6 +214,7 @@ FIELDS: Dict[str, Any] = {
     "mhc_marginal_err": (("round",), _NUM),
     "moe_top1_weight_mean": (("round",), _NUM),
     "router_state_rms": (("round",), _NUM),
+    "gdn_neg_beta_share": (("round",), _NUM),
     # what ran the delta rule's chunk recurrence (ops/gated_delta.py:
     # plan): pallas | pallas_interpret | xla.  Names the machine's path,
     # not the trajectory, hence advisory

@@ -9,10 +9,11 @@ read by the names below; ``benchmarks/lib/scope_tree.py`` does, matching
 whole path segments.
 
 :data:`SCOPES` is the one table: the engine (``train/engine.py``,
-``train/lm_engine.py``, ``train/algorithms.py``), the four decoders
+``train/lm_engine.py``, ``train/algorithms.py``), the five decoders
 (``models/decoder.py``, ``qwen3_next.py``, ``glm4_moe_lite.py``,
-``xing4_0.py``, ``zaya.py``) and the ops they call open their scopes with
-:func:`scope`, which refuses a name that is not declared here.  A row is
+``xing4_0.py``, ``zaya.py``, ``olmo_hybrid.py``) and the ops they call
+open their scopes with :func:`scope`, which refuses a name that is not
+declared here.  A row is
 ``(name, parents, programs, covers)``:
 
 - ``parents``: the declared scopes the name is opened directly inside
@@ -21,7 +22,7 @@ whole path segments.
   where a scope has children.
 - ``programs``: where it occurs: ``epoch`` (every cell's epoch program:
   the engine's step), ``comm`` (the exchange program), ``decoder`` (all
-  four decoders) or a model's registered name.
+  five decoders) or a model's registered name.
 
 The names of :data:`KERNEL_SCOPES` are what the benchmark's kernel
 readers match: the fourteen that stood before this table by substring,
@@ -46,8 +47,12 @@ class Scope(NamedTuple):
 
 
 _DECODER = ("decoder",)
+#: the decoders that norm a sub-layer's input, and those with expert
+#: layers: the four but the dense one, which norms outputs instead
+_PRE_NORM = _MOE = ("qwen3_next", "glm4_moe_lite", "xing4_0", "zaya")
+_GDN = ("qwen3_next", "olmo_hybrid")
 _MIXERS = ("gdn", "gated_attn", "mla_attn", "cca_attn")
-_ATTN = ("gated_attn", "mla_attn", "cca_attn")
+_ATTN = ("gated_attn", "mla_attn", "cca_attn", "mha_attn")
 _SHARED_EXPERT = ("qwen3_next", "glm4_moe_lite", "xing4_0")
 _FRAME = ("model_loss", "mtp")
 
@@ -90,30 +95,36 @@ SCOPES: Tuple[Scope, ...] = (
     Scope("sublayer_ffn", _FRAME, _DECODER,
           "a whole expert or dense sub-layer (self: the residual add, "
           "routing_counts)"),
-    Scope("sublayer_norm", _MIXERS + ("sublayer_ffn",), _DECODER,
+    Scope("sublayer_norm", _MIXERS + ("sublayer_ffn",), _PRE_NORM,
           "the sub-layer's input RMS norm"),
+    Scope("weight_cast", ("model_loss",), ("olmo_hybrid",),
+          "the layers' matrices cast to the products' dtype once a step"),
     Scope("step_stats", ("model_loss",), _DECODER,
-          "moe_aux, weighted_mean, the counters of LMTrainer.model_loss"),
+          "moe_aux, weighted_mean, the counters of LMTrainer.model_loss, "
+          "the mean of the Gated DeltaNet layers' beta shares"),
     Scope("mtp", ("model_loss",), ("glm4_moe_lite",),
           "the multi-token-prediction layer, its head and loss term "
           "(self: the targets' roll, the term's weight)"),
     Scope("mtp_merge", ("mtp",), ("glm4_moe_lite",),
           "[N(embedding of the next id) ; N(hidden)] through eh_proj"),
     # -- mixers ----------------------------------------------------------
-    Scope("gdn", ("sublayer_mixer",), ("qwen3_next",),
+    Scope("gdn", ("sublayer_mixer",), _GDN,
           "a Gated DeltaNet mixer (self: nothing)"),
-    Scope("gdn_in_proj", ("gdn",), ("qwen3_next",),
-          "in_proj_qkvz, in_proj_ba and their slices"),
-    Scope("gdn_conv", ("gdn",), ("qwen3_next",),
-          "the causal depthwise convolution and its SiLU"),
-    Scope("gdn_qk_prep", ("gdn",), ("qwen3_next",),
-          "the slices into q, k, v, unit norms, repeat, beta, g"),
-    Scope("gdn_scan", ("gdn",), ("qwen3_next",),
+    Scope("gdn_in_proj", ("gdn",), _GDN,
+          "the input projections and their slices (Qwen3-Next: "
+          "in_proj_qkvz, in_proj_ba; Olmo-Hybrid: q, k, v, the output "
+          "gate, a, b)"),
+    Scope("gdn_conv", ("gdn",), _GDN,
+          "the causal depthwise convolutions and their SiLU"),
+    Scope("gdn_qk_prep", ("gdn",), _GDN,
+          "the slices into q, k, v, unit norms, repeat, beta, g (and "
+          "the share of beta above 1)"),
+    Scope("gdn_scan", ("gdn",), _GDN,
           "the moves to heads-first; ops/gated_delta.py: the chunked delta "
           "rule, kernels and chunk-local part"),
-    Scope("gdn_out_gate", ("gdn",), ("qwen3_next",),
+    Scope("gdn_out_gate", ("gdn",), _GDN,
           "the move back, the output norm, the SiLU gate"),
-    Scope("gdn_out_proj", ("gdn",), ("qwen3_next",), "out_proj"),
+    Scope("gdn_out_proj", ("gdn",), _GDN, "out_proj"),
     Scope("gated_attn", ("sublayer_mixer",), ("qwen3_next",),
           "a gated-attention mixer (self: the attention kernels, or the "
           "XLA core)"),
@@ -122,6 +133,9 @@ SCOPES: Tuple[Scope, ...] = (
     Scope("mla_core", ("mla_attn",), ("glm4_moe_lite", "xing4_0"),
           "causal_attention of the latent mixer (self: the attention "
           "kernels, or the XLA core)"),
+    Scope("mha_attn", ("sublayer_mixer",), ("olmo_hybrid",),
+          "a multi-head attention mixer, QK-norm over the whole width, no "
+          "rotary (self: the attention kernels, or the XLA core)"),
     Scope("cca_attn", ("sublayer_mixer",), ("zaya",),
           "a compressed-convolutional-attention mixer (self: nothing)"),
     Scope("cca_mix", ("cca_attn",), ("zaya",),
@@ -137,41 +151,46 @@ SCOPES: Tuple[Scope, ...] = (
           "their slices"),
     Scope("attn_norm_rope", _ATTN, _DECODER,
           "the head norms (MLA: the latents' norms; CCA: the unit-sphere "
-          "norm and the key temperature), rotary, the softmax scale"),
-    Scope("attn_layout", ("gated_attn", "mla_core", "cca_core"), _DECODER,
+          "norm and the key temperature; MHA: q and k normed over the "
+          "whole width), rotary, the softmax scale"),
+    Scope("attn_layout", ("gated_attn", "mla_core", "cca_core", "mha_attn"),
+          _DECODER,
           "ops/flash_attention.py's wrapper: casts, padding, the "
           "regrouping transposes before and after the kernels"),
     Scope("attn_proj_out", _ATTN, _DECODER,
           "o_proj, with the sigmoid gate where there is one"),
     Scope("res_scale", ("sublayer_mixer", "sublayer_ffn"), ("zaya",),
           "the scaled residual merge (s_r h + b_r) + (s_o F + b_o)"),
+    Scope("post_norm", ("sublayer_mixer", "sublayer_ffn"), ("olmo_hybrid",),
+          "the reordered merge h + N(F): the sub-layer's output normed"),
     # -- feed-forward sub-layers -----------------------------------------
-    Scope("dense_mlp", ("sublayer_ffn",), ("glm4_moe_lite", "xing4_0"),
+    Scope("dense_mlp", ("sublayer_ffn",),
+          ("glm4_moe_lite", "xing4_0", "olmo_hybrid"),
           "the dense SwiGLU"),
-    Scope("moe_route", ("sublayer_ffn",), _DECODER,
+    Scope("moe_route", ("sublayer_ffn",), _MOE,
           "router to pair buffer and back (self: filled_rows)"),
     Scope("route_mlp", ("moe_route",), ("zaya",),
           "the router that is an MLP with a state: the down-projection, "
           "the previous layer's state added, its norm, three products"),
-    Scope("route_scores", ("moe_route",), _DECODER,
+    Scope("route_scores", ("moe_route",), _MOE,
           "the router product (where the router is one matrix), softmax "
           "or sigmoid, the bias, top_k"),
-    Scope("route_sort", ("moe_route",), _DECODER,
+    Scope("route_sort", ("moe_route",), _MOE,
           "route_local: the sort, bincount, indices, weights"),
-    Scope("pair_dispatch", ("moe_route",), _DECODER,
+    Scope("pair_dispatch", ("moe_route",), _MOE,
           "ops/moe.py:dispatch's loop and its rule's"),
-    Scope("pair_combine", ("moe_route",), _DECODER,
+    Scope("pair_combine", ("moe_route",), _MOE,
           "ops/moe.py:combine's loop and its rule's"),
-    Scope("pair_fill", ("pair_dispatch", "pair_combine"), _DECODER,
+    Scope("pair_fill", ("pair_dispatch", "pair_combine"), _MOE,
           "the zero fills the four loops start from"),
-    Scope("moe_experts", ("sublayer_ffn",), _DECODER,
+    Scope("moe_experts", ("sublayer_ffn",), _MOE,
           "the held experts (self: SiLU(gate) x up)"),
-    Scope("expert_cast", ("moe_experts",), _DECODER,
+    Scope("expert_cast", ("moe_experts",), _MOE,
           "operand()'s casts of rows, weights and cotangents"),
-    Scope("expert_products", ("moe_experts",), _DECODER,
+    Scope("expert_products", ("moe_experts",), _MOE,
           "the grouped products (on a TPU the compiler's ragged-dot "
           "kernels, which carry no path)"),
-    Scope("expert_mask", ("moe_experts",), _DECODER,
+    Scope("expert_mask", ("moe_experts",), _MOE,
           "_ragged's zeroing of the rows past the last group"),
     Scope("moe_shared", ("sublayer_ffn",), _SHARED_EXPERT,
           "the shared expert and its add"),

@@ -34,7 +34,9 @@ kernel) stays in VMEM scratch from one chunk to the next, where a
 keeps ``S`` at each chunk's start for the backward kernel, which
 recomputes ``v_new`` from it.  Which path runs is decided per call from
 what the call shows (:func:`plan`): the kernels on a TPU for a two- or
-four-byte ``dtype``, head widths that are multiples of 128, a chunk that
+four-byte ``dtype``, head widths that are multiples of 128 or fill three
+quarters of a lane tile (96-wide keys, 192-wide values: full-width blocks,
+no padding in HBM), a chunk that
 is a multiple of 8 and blocks that fit the VMEM budget; the ``lax.scan``
 everywhere else (the CPU, a one-byte ``dtype``, odd widths), with the
 same arithmetic.  Tests run the kernels on the CPU in interpret mode
@@ -216,6 +218,19 @@ def _step(dtype, S, x):
 # ----------------------------------------------------------------------
 # the recurrence as a Pallas kernel pair
 # ----------------------------------------------------------------------
+def _lanes(d: int) -> int:
+    """``d`` rounded up to whole 128-lane tiles, as Mosaic lays it out."""
+    return -(-d // _LANE) * _LANE
+
+
+def _takes_width(d: int) -> bool:
+    """A head width the kernels take: a multiple of 128, or a full-width
+    block of a multiple of 32 that fills at least three quarters of its
+    lane tiles (96, 192: Mosaic pads such a block to 128 / 256 lanes in
+    VMEM, and the padding's share is what the roofline share loses)."""
+    return d % _LANE == 0 or (d % 32 == 0 and 4 * d >= 3 * _lanes(d))
+
+
 def plan(H: int, N: int, chunk: int, dk: int, dv: int, dtype) -> dict:
     """What :func:`gated_delta_chunked` runs for the recurrence of ``H``
     heads over ``N`` chunks on the current backend, and what decided it:
@@ -230,9 +245,10 @@ def plan(H: int, N: int, chunk: int, dk: int, dv: int, dtype) -> dict:
         return dict(out, why="no TPU")
     if b not in (2, 4):
         return dict(out, why=f"{jnp.dtype(dtype).name} operands")
-    if dk % _LANE or dv % _LANE or chunk % _SUBLANE:
-        return dict(out, why="head widths no multiples of 128 or chunk "
-                             "no multiple of 8")
+    if not (_takes_width(dk) and _takes_width(dv)) or chunk % _SUBLANE:
+        return dict(out, why="head widths neither multiples of 128 nor "
+                             "three quarters of a lane tile, or chunk no "
+                             "multiple of 8")
     for heads in range(min(_HEADS, H), 0, -1):
         need = _grad_vmem_bytes(heads, chunk, dk, dv, b)
         if H % heads == 0 and need <= _VMEM_BUDGET:
@@ -247,7 +263,9 @@ def _grad_vmem_bytes(heads: int, C: int, dk: int, dv: int, b: int) -> int:
     ``k_out``, ``qk``, ``g_last``'s row) and out (``du``, ``dw``,
     ``dq_in``, ``dk_out``, ``dqk``, ``dg_last``'s row), double-buffered
     by the pipeline; ``dS`` in scratch; per head about four ``[d_k,
-    d_v]`` and six ``[C, d]`` float32 temporaries."""
+    d_v]`` and six ``[C, d]`` float32 temporaries; widths as laid out in
+    whole lane tiles."""
+    dk, dv = _lanes(dk), _lanes(dv)
     d = max(dk, dv)
     row = 4 * _SUBLANE * dv
     blocks_in = 2 * 4 * C * dv + 4 * dk * dv + b * (3 * C * dk + C * C) + row

@@ -19,12 +19,15 @@ mean routing weight of the local pairs of a model that reports their
 sum, ``aux["moe_weight_sum"]``: at one expert a token, the chosen
 expert's probability; else 0) and ``router_state_rms`` (the RMS of the
 state a model's routers hand from layer to layer, after the last layer,
-averaged over the round's steps, else 0), and adds the model's own
-``impl_fields``: which implementations its shapes take on this backend
-(``attn_impl``, ``gdn_scan_impl``).  The trainer names no model: a
+averaged over the round's steps, else 0) and ``gdn_neg_beta_share``
+(the share of (token, head) pairs of a model's Gated DeltaNet layers
+whose ``beta`` is above 1, averaged over the round's steps, else 0), and
+adds the model's own ``impl_fields``: which implementations its shapes
+take on this backend (``attn_impl``, ``gdn_scan_impl``).  The trainer names no model: a
 decoder is a ``BlockModule`` whose ``__call__(ids, labels)`` returns
 ``(loss per sequence, aux)`` and that has ``block_kinds()`` and
-``impl_fields(tokens)``.
+``impl_fields(tokens)``; its ``aux`` may leave out any counter (a dense
+model reports no routing), which then counts 0.
 """
 
 from __future__ import annotations
@@ -51,16 +54,16 @@ from federated_pytorch_test_tpu.train.engine import (
 #: the counters kept per client, all sums over local steps
 _COUNTERS = ("steps", "moe_pairs_local", "moe_dropped", "moe_rows",
              "moe_load_sum", "mtp_loss_sum", "mhc_err_sum",
-             "moe_weight_sum", "router_rms_sum")
+             "moe_weight_sum", "router_rms_sum", "neg_beta_sum")
 _FLOAT_COUNTERS = ("moe_load_sum", "mtp_loss_sum", "mhc_err_sum",
-                   "moe_weight_sum", "router_rms_sum")
+                   "moe_weight_sum", "router_rms_sum", "neg_beta_sum")
 
 
 class LMTrainer(BlockwiseFederatedTrainer):
     """Federated next-token training of a ``BlockModule`` whose
     ``__call__(ids)`` returns ``(logits, aux)`` (``models/qwen3_next.py``,
     ``models/glm4_moe_lite.py``, ``models/xing4_0.py``,
-    ``models/zaya.py``).
+    ``models/zaya.py``, ``models/olmo_hybrid.py``).
     No L1/L2 term on any block; evaluation is the mean test loss."""
 
     obs_engine = "lm"
@@ -115,11 +118,12 @@ class LMTrainer(BlockwiseFederatedTrainer):
         with scope("step_stats"):
             new = {"steps": bs["steps"] + 1,
                    "moe_pairs_local": bs["moe_pairs_local"]
-                   + aux["moe_pairs_local"],
-                   "moe_dropped": bs["moe_dropped"] + aux["moe_dropped"],
-                   "moe_rows": bs["moe_rows"] + aux["moe_rows"],
+                   + aux.get("moe_pairs_local", 0),
+                   "moe_dropped": bs["moe_dropped"]
+                   + aux.get("moe_dropped", 0),
+                   "moe_rows": bs["moe_rows"] + aux.get("moe_rows", 0),
                    "moe_load_sum": bs["moe_load_sum"]
-                   + aux["moe_load_max_over_mean"],
+                   + aux.get("moe_load_max_over_mean", 0.0),
                    "mtp_loss_sum": bs["mtp_loss_sum"] + (
                        weighted_mean(aux["mtp_loss"], wb)
                        if "mtp_loss" in aux else 0.0),
@@ -128,7 +132,9 @@ class LMTrainer(BlockwiseFederatedTrainer):
                    "moe_weight_sum": bs["moe_weight_sum"]
                    + aux.get("moe_weight_sum", 0.0),
                    "router_rms_sum": bs["router_rms_sum"]
-                   + aux.get("router_state_rms", 0.0)}
+                   + aux.get("router_state_rms", 0.0),
+                   "neg_beta_sum": bs["neg_beta_sum"]
+                   + aux.get("gdn_neg_beta_share", 0.0)}
             return weighted_mean(per_seq, wb), new
 
     def eval_batch_metric(self, p, bs, xb, yb, wb):
@@ -165,6 +171,7 @@ class LMTrainer(BlockwiseFederatedTrainer):
                 "moe_top1_weight_mean": d["moe_weight_sum"] / max(
                     d["moe_pairs_local"] - d["moe_dropped"], 1.0),
                 "router_state_rms": d["router_rms_sum"] / steps,
+                "gdn_neg_beta_share": d["neg_beta_sum"] / steps,
                 **self.model.impl_fields(self.data.tokens_per_sample)}
 
     def _block_index(self, ci: int) -> int:

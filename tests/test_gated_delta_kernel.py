@@ -262,6 +262,7 @@ def test_backward_kernel_matches_the_vjp_of_the_xla_step(step_vjp, i, dtype):
 @pytest.mark.parametrize("case,dk,dv,chunk,dtype,why", [
     ("one_byte_dtype", D, D, CHUNK, jnp.float8_e4m3fn, "float8_e4m3fn"),
     ("key_width_64", 64, D, CHUNK, BF16, "multiples of 128"),
+    ("key_width_160", 160, D, CHUNK, BF16, "multiples of 128"),
     ("value_width_64", D, 64, CHUNK, BF16, "multiples of 128"),
     ("chunk_12", D, D, 12, BF16, "multiple of 8"),
     ("over_the_budget", 1024, 1024, 512, F32, "exceed"),
@@ -301,6 +302,110 @@ def test_plan_takes_the_most_heads_that_divide_and_fit(H, heads):
     with gd.force_gdn_scan_impl("pallas"):
         wide = gd.plan(H, 64, CHUNK, D, D, F32)
     assert wide["vmem_bytes"] > p["vmem_bytes"] or wide["heads"] < heads
+
+
+# ----------------------------------------------------------------------
+# unequal widths, beta up to 2: 96-wide keys, 192-wide values
+# ----------------------------------------------------------------------
+DK, DV = 96, 192
+
+
+def neg_eigval_inputs(length, H=3, seed=5):
+    """Three heads (no multiple of 8) of 96-wide keys and 192-wide
+    values with ``beta = 2 sigmoid(.)``: transitions whose eigenvalue
+    along ``k`` lies in (-1, 1)."""
+    q, k, v, g, _ = delta_inputs(length, H=H, dk=DK, dv=DV, seed=seed)
+    beta = 2.0 * jax.nn.sigmoid(
+        2.0 * jax.random.normal(jax.random.PRNGKey(seed + 1), (H, length)))
+    return q, k, v, g, beta
+
+
+def test_plan_takes_96_wide_keys_and_192_wide_values():
+    """Native blocks: full-width in HBM, estimated as Mosaic lays them
+    out in VMEM (128 and 256 lanes); 30 heads go six to a step."""
+    with gd.force_gdn_scan_impl("pallas"):
+        p = gd.plan(30, 64, CHUNK, DK, DV, BF16)
+        assert (p["impl"], p["heads"]) == ("pallas", 6)
+        assert gd.plan(3, 2, CHUNK, DK, DV, F32)["heads"] == 3
+    assert p["vmem_bytes"] == gd._grad_vmem_bytes(6, CHUNK, 128, 256, 2)
+
+
+def test_plan_at_128_returns_what_it_returned_before():
+    with gd.force_gdn_scan_impl("pallas"):
+        assert gd.plan(32, 64, CHUNK, D, D, BF16) == {
+            "impl": "pallas", "heads": 8, "vmem_bytes": 8781824,
+            "vmem_budget": 12582912, "why": "fits"}
+        assert gd.plan(16, 64, CHUNK, D, D, F32) == {
+            "impl": "pallas", "heads": 8, "vmem_bytes": 10616832,
+            "vmem_budget": 12582912, "why": "fits"}
+
+
+@pytest.mark.parametrize("dtype", [F32, BF16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("length", [128, 100])
+def test_kernels_at_96_192_with_beta_up_to_2_match_the_scan(length, dtype):
+    args = neg_eigval_inputs(length)
+    assert float(jnp.max(args[4])) > 1.8 and float(jnp.min(args[4])) < 0.2
+    assert calls_a_kernel(functools.partial(kernels, dtype), *args)
+    got = kernels(dtype, *args)
+    want = jax.vmap(gd.gated_delta_stepwise)(*args)
+    assert got.shape == want.shape == (3, length, DV) and got.dtype == F32
+    assert rel(got, want) < TOL[dtype]
+    assert rel(got, chunked(dtype, *args)) < (1e-6 if dtype == F32 else 1e-2)
+
+
+@pytest.fixture(scope="module")
+def gradients_96_192():
+    args = neg_eigval_inputs(100)
+    loss = lambda f: lambda *a: jnp.sum(f(*a) ** 2)
+    grad = lambda f: jax.grad(loss(f), argnums=(0, 1, 2, 3, 4))(*args)
+    out = {"want": grad(jax.vmap(gd.gated_delta_stepwise))}
+    for dtype in (F32, BF16):
+        out[dtype] = (grad(functools.partial(kernels, dtype)),
+                      grad(functools.partial(chunked, dtype)))
+    return out
+
+
+@pytest.mark.parametrize("dtype", [F32, BF16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("i", range(5), ids=INPUTS)
+def test_kernels_at_96_192_gradient_matches_the_scan(gradients_96_192, i,
+                                                     dtype):
+    got, scan = gradients_96_192[dtype]
+    want = gradients_96_192["want"][i]
+    assert got[i].shape == want.shape
+    assert rel(got[i], want) < GRAD_TOL[dtype]
+    assert rel(got[i], want) < 2.0 * rel(scan[i], want) + 1e-5
+    # the kernels and the scan do the same arithmetic
+    assert rel(got[i], scan[i]) < (1e-5 if dtype == F32 else 2e-2)
+
+
+#: sha256 of the kernel pair's lowering (StableHLO; the kernels' bodies in
+#: interpret mode) at 128 / 128, as the tree before 96 / 192 was taken
+#: lowered them under this file's pytest set-up: what Qwen3-Next runs
+RECORDED_128 = {"forward": "8f9017ba6cc7b008", "gradient": "44b79d5daf973730",
+                "chunked_gradient": "11f86c437b4fef29"}
+
+
+def test_the_kernel_pair_lowers_at_128_as_before():
+    import hashlib
+
+    S = jax.ShapeDtypeStruct
+    H, N = 16, 4
+    ops = (S((H, N, CHUNK, D), F32), S((H, N, CHUNK, D), BF16),
+           S((H, N, CHUNK, CHUNK), BF16), S((H, N, CHUNK, D), BF16),
+           S((H, N, CHUNK, D), BF16), S((H, N, 1, D), F32))
+    sha = lambda f, *a: hashlib.sha256(
+        jax.jit(f).lower(*a).as_text().encode()).hexdigest()[:16]
+    f = functools.partial(gd._recurrence, 8, True)
+    args = tuple(S((2, 100, D), F32) for _ in range(3)) \
+        + (S((2, 100), F32), S((2, 100), F32))
+    with gd.force_gdn_scan_impl("pallas_interpret"):
+        loss = lambda *a: jnp.sum(chunked(BF16, *a) ** 2)
+        got = {"forward": sha(f, *ops),
+               "gradient": sha(jax.grad(lambda *a: jnp.sum(f(*a)),
+                                        argnums=tuple(range(6))), *ops),
+               "chunked_gradient": sha(jax.grad(loss, argnums=tuple(
+                   range(5))), *args)}
+    assert got == RECORDED_128
 
 
 # ----------------------------------------------------------------------
@@ -410,6 +515,25 @@ def test_kernels_compile_for_a_v5e_at_the_published_widths(one_chip, what):
     if what != "forward":
         f = jax.grad(lambda *a, f=f: jnp.sum(f(*a)), argnums=(0, 1, 2, 3, 4,
                                                               5))
+    text = compiled_for(f, *ops)
+    assert text.count("tpu_custom_call") == (1 if what == "forward" else 2)
+
+
+@pytest.mark.parametrize("what", ["forward", "forward_and_backward"])
+def test_kernels_compile_for_a_v5e_at_96_192(one_chip, what):
+    """Mosaic takes both kernels at Olmo-Hybrid's 30 heads of 96-wide keys
+    and 192-wide values as full-width blocks, six heads a step."""
+    H, N = 30, 64
+    with gd.force_gdn_scan_impl("pallas"):
+        heads = gd.plan(H, N, CHUNK, DK, DV, BF16)["heads"]
+    assert heads == 6
+    sh = lambda dt, *s: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    ops = (sh(F32, H, N, CHUNK, DV), sh(BF16, H, N, CHUNK, DK),
+           sh(BF16, H, N, CHUNK, CHUNK), sh(BF16, H, N, CHUNK, DK),
+           sh(BF16, H, N, CHUNK, DK), sh(F32, H, N, 1, DV))
+    f = functools.partial(gd._recurrence, heads, False)
+    if what != "forward":
+        f = jax.grad(lambda *a, f=f: jnp.sum(f(*a)), argnums=tuple(range(6)))
     text = compiled_for(f, *ops)
     assert text.count("tpu_custom_call") == (1 if what == "forward" else 2)
 

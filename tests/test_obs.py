@@ -176,6 +176,7 @@ DECLARED = {
     "mhc_marginal_err": (("round",), 3e-6, "small", False),
     "moe_top1_weight_mean": (("round",), 0.11, "a ninth", False),
     "router_state_rms": (("round",), 1.4, "grown", False),
+    "gdn_neg_beta_share": (("round",), 0.5, "half", False),
     "gdn_scan_impl": (("round",), "pallas", 1, True),
     "attn_impl": (("round",), "xla", 0, True),
     "mhc_impl": (("round",), "pallas_interpret", 2, True),

@@ -1,5 +1,5 @@
 """The program's scope table (``obs/scopes.py``) against the programs
-themselves: the epoch and exchange programs of the four decoders (at
+themselves: the epoch and exchange programs of the five decoders (at
 the tiny sizes of their benchmark tests) and of ResNet18 are lowered on
 the CPU and every operation's JAX path is read from
 ``lower().as_text(debug_info=True)``.
@@ -51,6 +51,8 @@ PROGRAMS = {
                       ("decoder", "glm4_moe_lite")),
     "xing4_0": ("test_xing_benchmark", "decoder_hc", ("decoder", "xing4_0")),
     "zaya": ("test_zaya_benchmark", "decoder_tied", ("decoder", "zaya")),
+    "olmo_hybrid": ("test_olmo_hybrid_benchmark", "decoder_dense",
+                    ("decoder", "olmo_hybrid")),
     "resnet18": (None, "classifier", ()),
 }
 PARENTS = {s.name: s.parents for s in SCOPES}

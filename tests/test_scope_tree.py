@@ -245,6 +245,55 @@ def test_tree_of_the_fourth_decoder_s_scopes_adds_up():
     assert ns(tree["unnamed_s"]) == 0
 
 
+def test_tree_of_the_dense_decoder_s_scopes_adds_up():
+    """A pass with the dense decoder's names: the attention mixer holds
+    its kernels as self time, the reordered merge is a node under either
+    sub-layer (also where the compiler lifted it out of the map over
+    sequences), the matrices' cast once a step is a node of the frame, and
+    the Gated DeltaNet mixer has the sibling's children."""
+    olmo = GRAD + "jvp(model_loss)/OlmoHybrid/"
+    mixer = olmo + "sublayer_mixer/while/body/closed_call/checkpoint/"
+    back = GRAD + ("transpose(jvp(model_loss))/OlmoHybrid/sublayer_mixer/"
+                   "jvp(model_loss)/OlmoHybrid/sublayer_mixer/while/body/"
+                   "closed_call/checkpoint/")
+    pass_ = [
+        (op("while.1", 0, 1000, "while"), STEP + "while:"),
+        (op("fusion.1", 0, 50), olmo + "weight_cast/convert_element_type:"),
+        (op("fusion.2", 50, 100), mixer + "gdn/gdn_in_proj/dot_general:"),
+        (op("gdn_scan.3", 150, 150, "custom-call"),
+         mixer + "gdn/gdn_scan/pallas_call:"),
+        (op("fusion.4", 300, 40), mixer + "post_norm/add:"),
+        (op("fusion.5", 340, 60), mixer + "mha_attn/attn_norm_rope/rsqrt:"),
+        (op("mha.6", 400, 100, "custom-call"),
+         mixer + "mha_attn/pallas_call:"),
+        (op("mha.7", 500, 200, "custom-call"),
+         back + "mha_attn/mha_attn/pallas_call:"),
+        (op("fusion.8", 700, 150), olmo + "sublayer_ffn/checkpoint/"
+         "dense_mlp/dot_general:"),
+        (op("fusion.9", 850, 30), olmo + "sublayer_ffn/checkpoint/"
+         "post_norm/add:"),
+        # lifted out of the map over sequences: put back under the mixer
+        (op("fusion.10", 880, 20), GRAD + "post_norm/add:"),
+    ]
+    tree = st.tree_of(st.leaves(pass_), 0.0, 1000.0)
+    ns = lambda sec: round(sec * 1e9, 6)
+    total = lambda key: ns(sum(tree["nodes"][key]["s"]))
+    top = "client_grad/model_loss/"
+    assert total(top + "weight_cast") == 50
+    assert total(top + "sublayer_mixer/gdn") == 250
+    assert total(top + "sublayer_mixer/gdn/gdn_scan") == 150
+    assert total(top + "sublayer_mixer/mha_attn") == 360
+    assert [ns(v) for v in tree["nodes"][
+        top + "sublayer_mixer/mha_attn"]["self"]] == [100, 0, 200]
+    assert total(top + "sublayer_mixer/post_norm") == 60
+    assert total(top + "sublayer_ffn/post_norm") == 30
+    assert total(top + "sublayer_ffn/dense_mlp") == 150
+    assert ns(st.scope_seconds(tree, "post_norm")) == 90
+    assert ns(st.scope_seconds(tree, "mha_attn", "backward")) == 200
+    assert ns(tree["busy_s"]) == 900 == ns(st.parts_s(tree))
+    assert ns(tree["unnamed_s"]) == 0
+
+
 def test_under_and_the_two_tables():
     found = st.leaves(pass_of_events())
     below = st.under(found, "sublayer_mixer")
