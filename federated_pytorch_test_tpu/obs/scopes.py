@@ -24,6 +24,8 @@ declared here.  A row is
   the engine's step), ``comm`` (the exchange program), ``decoder`` (all
   five decoders) or a model's registered name.
 
+The names of :data:`TPU_ONLY` are opened only around a kernel that runs
+on a TPU alone, and a program lowered on the CPU holds none of them.
 The names of :data:`KERNEL_SCOPES` are what the benchmark's kernel
 readers match: the fourteen that stood before this table by substring,
 ``cca_core`` as a whole segment.  No other name may hold one of them
@@ -36,7 +38,7 @@ from typing import NamedTuple, Tuple
 
 import jax
 
-__all__ = ["KERNEL_SCOPES", "NAMES", "SCOPES", "Scope", "scope"]
+__all__ = ["KERNEL_SCOPES", "NAMES", "SCOPES", "Scope", "TPU_ONLY", "scope"]
 
 
 class Scope(NamedTuple):
@@ -121,7 +123,11 @@ SCOPES: Tuple[Scope, ...] = (
           "the share of beta above 1)"),
     Scope("gdn_scan", ("gdn",), _GDN,
           "the moves to heads-first; ops/gated_delta.py: the chunked delta "
-          "rule, kernels and chunk-local part"),
+          "rule, kernels and chunk-local part (self: all of it but the "
+          "fused primal kernel)"),
+    Scope("gdn_fused", ("gdn_scan",), _GDN,
+          "the whole delta rule as one kernel where no gradient is asked "
+          "(on a TPU only): the decays' cumulative sum, the kernel"),
     Scope("gdn_out_gate", ("gdn",), _GDN,
           "the move back, the output norm, the SiLU gate"),
     Scope("gdn_out_proj", ("gdn",), _GDN, "out_proj"),
@@ -218,6 +224,10 @@ SCOPES: Tuple[Scope, ...] = (
 )
 
 NAMES = frozenset(s.name for s in SCOPES)
+
+#: opened only on a TPU's kernel path (``ops/gated_delta.py``: the fused
+#: primal kernel, which no width of the CPU's test configurations takes)
+TPU_ONLY = ("gdn_fused",)
 
 #: what the benchmark's kernel readers match: by substring
 #: (``benchmarks/lib/scopes.py``, ``glm_work.py``, ``xing_work.py``), and

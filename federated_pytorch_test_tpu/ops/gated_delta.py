@@ -41,6 +41,19 @@ is a multiple of 8 and blocks that fit the VMEM budget; the ``lax.scan``
 everywhere else (the CPU, a one-byte ``dtype``, odd widths), with the
 same arithmetic.  Tests run the kernels on the CPU in interpret mode
 (:func:`force_gdn_scan_impl`).
+
+On the kernel path, where no gradient is asked, the whole rule is one
+more Pallas kernel (:func:`_fused_kernel`): a grid step loads a chunk's
+``q``, ``k``, ``v`` and the rows of its cumulative decay and ``beta``,
+does the chunk-local part in VMEM in the XLA path's order and at its
+precisions (the ten inverse products at ``HIGHEST`` among them), then the
+recurrence step, and writes ``o`` alone.  It is the primal function of a
+``jax.custom_vjp`` whose forward rule is ``jax.vjp`` of the path above,
+so a gradient runs the XLA chunk-local part and the kernel pair as
+before; under ``jax.checkpoint`` the primal pass runs the fused kernel
+(``optimize_remat``: the forward rule's residuals would be thrown away)
+and the rematerialised forward the forward rule.  :func:`plan` says
+whether its blocks fit, and with how many heads a step.
 """
 
 from __future__ import annotations
@@ -67,7 +80,8 @@ _HEADS = 8                  # heads a grid step handles, at most
 _VMEM_BUDGET = 12 * 2**20
 # the program's scope for this module (models/qwen3_next.py opens it
 # around the forward call; a custom_vjp's backward rule is traced outside
-# it, so both rules open it again and a trace still finds their ops)
+# it, so the rules, and the fused primal that a checkpointed gradient
+# traces apart, open it again and a trace still finds their ops)
 _SCOPE = "gdn_scan"
 
 # None = by the backend; "pallas_interpret" stands in for a TPU in tests
@@ -115,13 +129,17 @@ def _unit_lower_inverse(a):
     products instead of ``C`` steps of forward substitution.  Its
     derivative is that of an inverse, ``dT = -T da T``, not the transpose
     of the product factor by factor."""
-    C = a.shape[-1]
-    eye = jnp.eye(C, dtype=a.dtype)
+    return _doubling(a, jnp.eye(a.shape[-1], dtype=a.dtype), _mm)
+
+
+def _doubling(a, eye, mm):
+    """``(I - a)(I + a^2)(I + a^4)...`` with the products ``mm``: here
+    and in the fused kernel, which has no ``jnp.eye``."""
     inv, power = eye - a, a
     span = 2
-    while span < C:
-        power = _mm(power, power)
-        inv = _mm(inv, eye + power)
+    while span < a.shape[-1]:
+        power = mm(power, power)
+        inv = mm(inv, eye + power)
         span *= 2
     return inv
 
@@ -155,7 +173,21 @@ def gated_delta_chunked(q, k, v, g, beta, *, chunk: int = 64,
     if pad:
         z = lambda a: jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2))
         q, k, v, g, beta = z(q), z(k), z(v), z(g), z(beta)
-    N = (T + pad) // chunk
+    p = plan(H, (T + pad) // chunk, chunk, dk, dv, dtype)
+    how = (p["impl"], p["heads"], chunk, dtype)
+    if p["fused_heads"]:
+        o = _delta_rule(p["fused_heads"], *how, q, k, v, g, beta)
+    else:
+        o = _chunked(*how, q, k, v, g, beta)
+    return o[:, :T]
+
+
+def _chunked(impl, heads, chunk, dtype, q, k, v, g, beta):
+    """The chunk-local part in XLA, then the recurrence (the ``lax.scan``
+    or the kernel pair) over ``T``, a multiple of ``chunk``."""
+    H, T, dk = k.shape
+    dv = v.shape[-1]
+    N = T // chunk
     f32 = lambda a: a.astype(jnp.float32)
     cut = lambda a: f32(a).reshape(H, N, chunk, *a.shape[2:])
     q, k, v, g, beta = cut(q), cut(k), cut(v), cut(g), cut(beta)
@@ -182,8 +214,7 @@ def gated_delta_chunked(q, k, v, g, beta, *, chunk: int = 64,
     k_out = k * jnp.exp(gc[..., -1:] - gc)[..., None]  # k_i exp(gc_C - gc_i)
     g_last = jnp.exp(gc[..., -1])                      # [H, N]
 
-    p = plan(H, N, chunk, dk, dv, dtype)
-    if p["impl"] == "xla":
+    if impl == "xla":
         first = lambda a: jnp.moveaxis(a, 1, 0)        # chunks lead
         S0 = jnp.zeros((H, dk, dv), jnp.float32)
         _, o = lax.scan(functools.partial(_step, dtype), S0,
@@ -192,11 +223,11 @@ def gated_delta_chunked(q, k, v, g, beta, *, chunk: int = 64,
     else:
         # the rounding `_step` applies to its operands, applied once
         cast = lambda a: operand(a, dtype)
-        o = _recurrence(p["heads"], p["impl"] == "pallas_interpret",
+        o = _recurrence(heads, impl == "pallas_interpret",
                         u, cast(w), cast(qk), cast(q_in), cast(k_out),
                         jnp.broadcast_to(g_last[..., None, None],
                                          (H, N, 1, dv)))
-    return o.reshape(H, N * chunk, dv)[:, :T]
+    return o.reshape(H, T, dv)
 
 
 def _ein(dtype, spec, a, b):
@@ -235,12 +266,17 @@ def plan(H: int, N: int, chunk: int, dk: int, dv: int, dtype) -> dict:
     """What :func:`gated_delta_chunked` runs for the recurrence of ``H``
     heads over ``N`` chunks on the current backend, and what decided it:
     ``impl`` ("pallas" | "pallas_interpret" | "xla"), ``heads`` a grid
-    step handles and the backward kernel's VMEM estimate for them."""
+    step handles and the backward kernel's VMEM estimate for them; and
+    for the whole rule where no gradient is asked, ``fused_heads`` a
+    step of the fused kernel handles (0: the primal pass runs the
+    chunk-local part and the kernel pair too), its own VMEM estimate
+    and ``fused_why``."""
     b = jnp.dtype(dtype).itemsize
     backend = _FORCE_IMPL or ("pallas" if jax.default_backend() == "tpu"
                               else "xla")
     out = {"impl": "xla", "heads": 0, "vmem_bytes": 0,
-           "vmem_budget": _VMEM_BUDGET}
+           "vmem_budget": _VMEM_BUDGET, "fused_heads": 0,
+           "fused_vmem_bytes": 0, "fused_why": "no kernel pair"}
     if backend == "xla":
         return dict(out, why="no TPU")
     if b not in (2, 4):
@@ -249,12 +285,21 @@ def plan(H: int, N: int, chunk: int, dk: int, dv: int, dtype) -> dict:
         return dict(out, why="head widths neither multiples of 128 nor "
                              "three quarters of a lane tile, or chunk no "
                              "multiple of 8")
+    exceed = "one head's blocks exceed the VMEM budget"
     for heads in range(min(_HEADS, H), 0, -1):
         need = _grad_vmem_bytes(heads, chunk, dk, dv, b)
         if H % heads == 0 and need <= _VMEM_BUDGET:
-            return dict(out, impl=backend, heads=heads, vmem_bytes=need,
-                        why="fits")
-    return dict(out, why="one head's blocks exceed the VMEM budget")
+            out = dict(out, impl=backend, heads=heads, vmem_bytes=need,
+                       why="fits", fused_why=exceed)
+            break
+    else:
+        return dict(out, why=exceed)
+    for heads in range(min(_HEADS, H), 0, -1):
+        need = _fused_vmem_bytes(heads, chunk, dk, dv)
+        if H % heads == 0 and need <= _VMEM_BUDGET:
+            return dict(out, fused_heads=heads, fused_vmem_bytes=need,
+                        fused_why="fits")
+    return out
 
 
 def _grad_vmem_bytes(heads: int, C: int, dk: int, dv: int, b: int) -> int:
@@ -272,6 +317,22 @@ def _grad_vmem_bytes(heads: int, C: int, dk: int, dv: int, b: int) -> int:
     blocks_out = 4 * C * dv + b * (3 * C * dk + C * C) + row
     return heads * (2 * (blocks_in + blocks_out) + 4 * dk * dv
                     + 4 * (4 * dk * dv + 6 * C * d))
+
+
+def _fused_vmem_bytes(heads: int, C: int, dk: int, dv: int) -> int:
+    """VMEM estimate for ``_fused_kernel``: per head the blocks in (``q``,
+    ``k``, ``v`` float32, the rows of the cumulative decay and ``beta``,
+    ``g_last``'s row) and out (``o``), double-buffered by the pipeline;
+    ``S`` in scratch; per head about eight ``[C, C]`` float32 temporaries
+    (decays, ``a``, the inverse and its factors, ``q k^T``), ten ``[C,
+    d]`` and two ``[d_k, d_v]``; widths as laid out in whole lane tiles,
+    a row in whole sublane tiles."""
+    dk, dv, c = _lanes(dk), _lanes(dv), _lanes(C)
+    d = max(dk, dv)
+    blocks_in = 4 * (2 * C * dk + C * dv + _SUBLANE * (c + dv))
+    blocks_out = 4 * C * dv
+    return heads * (2 * (blocks_in + blocks_out) + 4 * dk * dv
+                    + 4 * (8 * C * c + 10 * C * d + 2 * dk * dv))
 
 
 def _bmm(spec, a, b):
@@ -396,3 +457,115 @@ def _recurrence_bwd(heads, interpret, res, do):
 
 
 _recurrence.defvjp(_recurrence_fwd, _recurrence_bwd)
+
+
+# ----------------------------------------------------------------------
+# the whole rule as one kernel, where no gradient is asked
+# ----------------------------------------------------------------------
+def _hi(spec, a, b):
+    """Product of float32 blocks at the highest precision, as ``_mm``."""
+    return jnp.einsum(spec, a, b, precision=_HI, preferred_element_type=_F32)
+
+
+def _fused_kernel(dtype, q_ref, k_ref, v_ref, r_ref, gl_ref, o_ref, s_ref):
+    """One chunk of a grid step's heads, the chunk-local part and the
+    recurrence step in VMEM: ``q, k [heads, C, d_k]``, ``v [heads, C,
+    d_v]``, the chunk's cumulative decay and ``beta`` as the rows of
+    ``r_ref [heads, 2, C]``, and ``g_last`` as the lane-dense row ``gl_ref
+    [heads, 1, d_v]`` that :func:`_scan_kernel` reads -> ``o_ref [heads,
+    C, d_v]``; ``s_ref [heads, d_k, d_v]`` float32 carries ``S`` along
+    the chunk axis.  The arithmetic of :func:`_chunked`, then of
+    :func:`_scan_kernel`, in their order and at their precisions."""
+    C = q_ref.shape[1]
+
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        s_ref[...] = jnp.zeros_like(s_ref)
+
+    q, k, v = (r[...].astype(_F32) for r in (q_ref, k_ref, v_ref))
+    i = lax.broadcasted_iota(jnp.int32, (1, C, C), 1)
+    j = lax.broadcasted_iota(jnp.int32, (1, C, C), 2)
+    tri, strict, eye = i >= j, i > j, i == j
+    # a row [heads, 1, C] as a column [heads, C, 1]: one term a sum, exact
+    # (a column block would fill a 128-lane tile of HBM per value)
+    col = lambda row: jnp.sum(jnp.where(eye, row, 0.0), axis=-1,
+                              keepdims=True)
+    gr = r_ref[:, 0:1, :]
+    gc, beta = col(gr), col(r_ref[:, 1:2, :])
+    last = gc[:, C - 1:, :]                            # [heads, 1, 1]
+    decay = jnp.where(tri, jnp.exp(jnp.where(tri, gc - gr, 0.0)), 0.0)
+    kb = k * beta
+    a = jnp.where(strict, _hi("hid,hjd->hij", kb, k) * decay, 0.0)
+    inv = _doubling(a, eye.astype(_F32),
+                    functools.partial(_hi, "hij,hjk->hik"))
+    u = _hi("hij,hjd->hid", inv, v * beta)
+    w = _hi("hij,hjd->hid", inv, kb * jnp.exp(gc))
+    qk = jnp.where(tri, _bmm("hid,hjd->hij", q.astype(dtype),
+                             k.astype(dtype)) * decay, 0.0)
+    q_in, k_out = q * jnp.exp(gc), k * jnp.exp(last - gc)
+
+    S = s_ref[...]
+    Sb = S.astype(dtype)
+    vb = (u - _bmm("hid,hde->hie", w.astype(dtype), Sb)).astype(dtype)
+    o_ref[...] = (_bmm("hid,hde->hie", q_in.astype(dtype), Sb)
+                  + _bmm("hij,hje->hie", qk.astype(dtype), vb))
+    s_ref[...] = S * gl_ref[...] + _bmm("hid,hie->hde", k_out.astype(dtype),
+                                        vb)
+
+
+def _fused(heads, interpret, chunk, dtype, q, k, v, g, beta):
+    """``q, k [H, T, d_k]``, ``v [H, T, d_v]``, ``g, beta [H, T]``, ``T``
+    a multiple of ``chunk`` -> ``o [H, T, d_v]`` float32 through
+    :func:`_fused_kernel` over the grid ``(H / heads, T / chunk)``; only
+    the cumulative sum of ``g`` within each chunk, and ``g_last``'s row
+    (Mosaic broadcasts along sublanes or lanes, not both at once), are
+    XLA's."""
+    H, T, dk = k.shape
+    dv = v.shape[-1]
+    N = T // chunk
+    cut = lambda a: a.astype(_F32).reshape(H, N, chunk)
+    gc = jnp.cumsum(cut(g), axis=-1)
+    tile = lambda d: pl.BlockSpec((heads, chunk, d), lambda h, n: (h, n, 0))
+    rows = lambda r, c: pl.BlockSpec((heads, None, r, c),
+                                     lambda h, n: (h, n, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_fused_kernel, dtype),
+        grid=(H // heads, N),
+        in_specs=[tile(dk), tile(dk), tile(dv), rows(2, chunk), rows(1, dv)],
+        out_specs=tile(dv),
+        out_shape=jax.ShapeDtypeStruct((H, T, dv), _F32),
+        scratch_shapes=[pltpu.VMEM((heads, dk, dv), _F32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(q, k, v, jnp.stack([gc, cut(beta)], axis=2),
+      jnp.broadcast_to(jnp.exp(gc[..., -1])[..., None, None], (H, N, 1, dv)))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4))
+def _delta_rule(fused, impl, heads, chunk, dtype, q, k, v, g, beta):
+    """The kernel path over ``T``, a multiple of ``chunk``: where no
+    gradient is asked (the primal pass under ``jax.checkpoint``) one
+    kernel, ``fused`` heads a step, that writes ``o`` alone; under a
+    gradient :func:`_chunked` as it is, whose ``jax.vjp`` closure is the
+    residual, so the rematerialised forward and the backward pass run the
+    kernel pair and the inverse's rule.  Under ``jax.checkpoint`` the
+    primal pass runs this function in place of the forward rule, whose
+    residuals it would throw away (``optimize_remat``), traced outside the
+    caller's scope: it opens ``gdn_scan`` again."""
+    with scope(_SCOPE), scope("gdn_fused"):
+        return _fused(fused, impl == "pallas_interpret", chunk, dtype,
+                      q, k, v, g, beta)
+
+
+def _delta_rule_fwd(fused, impl, heads, chunk, dtype, *ins):
+    return jax.vjp(functools.partial(_chunked, impl, heads, chunk, dtype),
+                   *ins)
+
+
+def _delta_rule_bwd(fused, impl, heads, chunk, dtype, vjp, do):
+    with scope(_SCOPE):
+        return vjp(do)
+
+
+_delta_rule.defvjp(_delta_rule_fwd, _delta_rule_bwd, optimize_remat=True)

@@ -363,16 +363,19 @@ def test_fields_declare_attn_impl():
 #: of a lowered module) on the tree of PR 35, which changed both on
 #: purpose (``ops/moe.py``: ``dispatch``, ``combine``), the two loss
 #: gradients again on the tree of PR 39, which changed both heads' loss
-#: on purpose (``ops/head_loss.py``; the forwards stayed).  They guard
-#: against a change of either decoder's lowered program that nobody
+#: on purpose (``ops/head_loss.py``; the forwards stayed), and
+#: Qwen3-Next's kernel path on the tree that fused the delta rule's primal
+#: pass into one kernel (``ops/gated_delta.py``: its forward holds that
+#: kernel, its gradient's primal pass too; its XLA path and GLM's
+#: programs stayed).  They guard against a change of either decoder's lowered program that nobody
 #: meant: PR 34 let the key and value widths of the attention kernels
 #: differ and left all six as they were.  A PR that means to change
 #: either model's program records them again and says so.
 #: ``pallas_interpret`` lowers the kernels' own bodies on the CPU,
 #: ``xla`` the XLA path.
 RECORDED_LOWERED = {
-    "qwen3_next/pallas_interpret/forward": "bb8ecf9576e0c454",
-    "qwen3_next/pallas_interpret/loss_grad": "d3177217dcf334b0",
+    "qwen3_next/pallas_interpret/forward": "679c79e1acdbeba3",
+    "qwen3_next/pallas_interpret/loss_grad": "f30bee64af6092fe",
     "qwen3_next/xla/loss_grad": "e0a42e9fa622c653",
     "glm4_moe_lite/pallas_interpret/forward": "02fdb2bcc7564198",
     "glm4_moe_lite/pallas_interpret/loss_grad": "511f4d43533b55e6",

@@ -1,8 +1,10 @@
 """The delta rule's chunk recurrence as a Pallas kernel pair
 (``ops/gated_delta.py``), on the CPU in interpret mode: against the
 token-by-token recurrence and against the ``lax.scan`` path, the backward
-kernel line by line against ``jax.vjp`` of the XLA step, the rule that
-picks the path, the round field that reports it, and a compile of both
+kernel line by line against ``jax.vjp`` of the XLA step, the fused
+primal kernel (the whole rule where no gradient is asked) against the
+path it replaces and where a checkpointed gradient runs it, the rule that
+picks the path, the round field that reports it, and a compile of the
 kernels at the published widths for a described v5e, and of the attention
 kernel pair (``ops/flash_attention.py``) and the hyper-connections'
 stream kernels (``ops/hyper_connections.py``) beside them: one file holds
@@ -122,6 +124,178 @@ def test_forward_without_a_gradient_writes_no_states():
     assert fwd.count("pallas_call") == 1 and both.count("pallas_call") == 2
     states = "f32[2,2,128,128]"       # S at each chunk's start
     assert states not in fwd and states in both
+
+
+# ----------------------------------------------------------------------
+# the whole rule as one kernel where no gradient is asked
+# ----------------------------------------------------------------------
+def strong_decay_inputs(length, dk, dv, H, seed=11):
+    """``g`` down to -30 (decays that underflow across a chunk) and
+    ``beta`` up to 2, at the head widths of a published shape."""
+    q, k, v, g, _ = delta_inputs(length, H=H, dk=dk, dv=dv, seed=seed)
+    ks = jax.random.split(jax.random.PRNGKey(seed + 1), 2)
+    g = -30.0 * jax.random.uniform(ks[0], (H, length)) ** 3
+    beta = 2.0 * jax.nn.sigmoid(2.0 * jax.random.normal(ks[1], (H, length)))
+    return q, k, v, g, beta
+
+
+#: the published shapes at the heads a step of the fused kernel takes
+FUSED_SHAPES = {"128_128": (D, D, 8), "96_192": (96, 192, 6)}
+
+
+def todays_path(dtype, *args):
+    """The kernel path as it was before the fused kernel: the chunk-local
+    part in XLA, then the kernel pair (``_chunked``), padded alike."""
+    H, T = args[3].shape
+    pad = (-T) % CHUNK
+    z = lambda a: jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2))
+    with gd.force_gdn_scan_impl("pallas_interpret"):
+        p = gd.plan(H, (T + pad) // CHUNK, CHUNK, args[0].shape[-1],
+                    args[2].shape[-1], dtype)
+        return gd._chunked(p["impl"], p["heads"], CHUNK, dtype,
+                           *map(z, args))[:, :T]
+
+
+def kernel_outputs(jaxpr, in_remat=False):
+    """``[(in a rematerialised part, output shapes), ...]`` of every
+    ``pallas_call`` in ``jaxpr`` and the jaxprs of its equations: the
+    fused kernel writes ``[H, T, d_v]`` alone, the forward kernel ``[H,
+    N, C, d_v]`` (and ``S`` at each chunk's start), the backward kernel
+    six cotangents."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append((in_remat, tuple(tuple(a.shape)
+                                          for a in eqn.params["out_avals"])))
+        remat = in_remat or eqn.primitive.name == "remat2"
+        for p in eqn.params.values():
+            for sub in (p if isinstance(p, (tuple, list)) else (p,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if isinstance(sub, jax.extend.core.Jaxpr):
+                    found += kernel_outputs(sub, remat)
+    return found
+
+
+@pytest.mark.parametrize("dtype", [F32, BF16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("length", [128, 100])
+@pytest.mark.parametrize("shape", list(FUSED_SHAPES))
+def test_fused_primal_matches_todays_path(shape, length, dtype):
+    dk, dv, H = FUSED_SHAPES[shape]
+    args = strong_decay_inputs(length, dk, dv, H)
+    with gd.force_gdn_scan_impl("pallas_interpret"):
+        p = gd.plan(H, 2, CHUNK, dk, dv, dtype)
+        fused = jax.make_jaxpr(functools.partial(kernels, dtype))(*args)
+    assert (p["fused_heads"], p["fused_why"]) == (H, "fits")
+    assert [o for _, o in kernel_outputs(fused.jaxpr)] == [((H, 128, dv),)]
+    got, want = kernels(dtype, *args), todays_path(dtype, *args)
+    assert got.shape == want.shape == (H, length, dv) and got.dtype == F32
+    assert bool(jnp.all(jnp.isfinite(got)))
+    assert rel(got, want) < 1e-5
+    # and as close to the recurrence as today's path (decays that reach
+    # exp(-30) across a chunk cost the chunked form a little at float32)
+    step = jax.vmap(gd.gated_delta_stepwise)(*args)
+    assert rel(got, step) <= rel(want, step) + 1e-6
+    assert rel(got, step) < (1e-4 if dtype == F32 else 2e-2)
+
+
+@pytest.fixture(scope="module")
+def checkpointed_gradients():
+    """Per shape and dtype, gradients of all five inputs through
+    ``jax.checkpoint``, as the models take them: this tree's kernel path
+    and today's, at a length that needs padding (two heads: the heads a
+    step change the grid, not the arithmetic)."""
+    out = {}
+    for shape, (dk, dv, _) in FUSED_SHAPES.items():
+        args = strong_decay_inputs(100, dk, dv, 2)
+        for dtype in (F32, BF16):
+            def grad(f):
+                loss = lambda *a: jnp.sum(jax.checkpoint(f)(*a) ** 2)
+                return jax.grad(loss, argnums=(0, 1, 2, 3, 4))(*args)
+
+            out[shape, dtype] = (
+                grad(functools.partial(kernels, dtype)),
+                grad(functools.partial(todays_path, dtype)))
+    return out
+
+
+@pytest.mark.parametrize("dtype", [F32, BF16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("shape", list(FUSED_SHAPES))
+@pytest.mark.parametrize("i", range(5), ids=INPUTS)
+def test_checkpointed_gradient_is_todays(checkpointed_gradients, i, shape,
+                                         dtype):
+    got, want = checkpointed_gradients[shape, dtype]
+    assert got[i].shape == want[i].shape and got[i].dtype == want[i].dtype
+    assert rel(got[i], want[i]) < 1e-5
+
+
+@pytest.mark.parametrize("wrap", ["checkpoint", "map_of_checkpoint"])
+def test_primal_pass_of_a_checkpointed_gradient_runs_the_fused_kernel(wrap):
+    """Under ``jax.checkpoint`` the forward rule's residuals would be
+    thrown away, so the primal pass runs the fused kernel once
+    (``optimize_remat``); the rematerialised forward runs today's forward
+    kernel with states, and the backward today's backward kernel."""
+    H, T, N = 2, 128, 2
+    args = delta_inputs(T, H=H)
+    f = jax.checkpoint(functools.partial(kernels, BF16))
+    if wrap == "map_of_checkpoint":       # as the models call it
+        f = lambda *a, f=f: jax.lax.map(lambda x: f(*x), a)
+        args = tuple(a[None] for a in args)
+    loss = lambda *a: jnp.sum(f(*a) ** 2)
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))(*args)
+    found = kernel_outputs(jaxpr.jaxpr)
+    primal = [o for remat, o in found if not remat]
+    rest = [o for remat, o in found if remat]
+    assert primal == [((H, T, D),)]
+    rows = (H, N, CHUNK, D)
+    assert rest == [(rows, (H, N, D, D)),
+                    (rows, rows, (H, N, CHUNK, CHUNK), rows, rows,
+                     (H, N, 1, D))]
+    # and without jax.checkpoint the forward rule runs as before
+    plain = jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(
+        kernels(BF16, *a) ** 2), argnums=(0, 1, 2, 3, 4)))(
+            *delta_inputs(T, H=H))
+    assert [len(o) for _, o in kernel_outputs(plain.jaxpr)] == [2, 6]
+
+
+@pytest.mark.parametrize("shape,heads,need", [
+    ("128_128", 8, 8519680), ("96_192", 6, 10371072)])
+def test_plan_fuses_the_primal_at_the_published_shapes(shape, heads, need):
+    """Qwen3-Next's 32 heads of 128 / 128 go eight to a step of the fused
+    kernel, Olmo-Hybrid's 30 heads of 96 / 192 six (eight do not divide
+    30), each under the VMEM budget by the kernel's own estimate; the
+    kernel pair's answer is what it was."""
+    dk, dv, _ = FUSED_SHAPES[shape]
+    H = 32 if dk == D else 30
+    with gd.force_gdn_scan_impl("pallas"):
+        p = gd.plan(H, 64, CHUNK, dk, dv, BF16)
+    assert p["impl"] == "pallas" and p["why"] == "fits"
+    assert (p["fused_heads"], p["fused_vmem_bytes"], p["fused_why"]) == (
+        heads, need, "fits")
+    assert need == gd._fused_vmem_bytes(heads, CHUNK, dk, dv)
+    assert all(H % more for more in range(heads + 1, gd._HEADS + 1))
+
+
+@pytest.mark.parametrize("case", ["chunk_512", "budget_of_the_pair"])
+def test_what_the_fused_kernel_does_not_take_runs_todays_path(case,
+                                                              monkeypatch):
+    """Where one head's blocks of the fused kernel exceed the budget (a
+    chunk of 512: eight ``[C, C]`` float32 temporaries; at a chunk of
+    128, a budget that holds the kernel pair's step of one head and no
+    more) the primal pass runs the chunk-local part and the kernel
+    pair."""
+    chunk = 512 if case == "chunk_512" else 2 * CHUNK
+    if case == "budget_of_the_pair":
+        monkeypatch.setattr(gd, "_VMEM_BUDGET",
+                            gd._grad_vmem_bytes(1, chunk, D, D, 2))
+    with gd.force_gdn_scan_impl("pallas_interpret"):
+        p = gd.plan(2, 2, chunk, D, D, BF16)
+    assert (p["impl"], p["heads"]) == ("pallas_interpret", 1)
+    assert p["fused_heads"] == 0 and "exceed" in p["fused_why"]
+    args = delta_inputs(2 * chunk)
+    f = functools.partial(kernels, BF16, chunk=chunk)
+    shapes = [o for _, o in kernel_outputs(jax.make_jaxpr(f)(*args).jaxpr)]
+    assert shapes == [((2, 2, chunk, D),)]          # the stateless kernel
+    assert rel(f(*args), chunked(BF16, *args, chunk=chunk)) < 1e-2
 
 
 # ----------------------------------------------------------------------
@@ -331,13 +505,21 @@ def test_plan_takes_96_wide_keys_and_192_wide_values():
 
 
 def test_plan_at_128_returns_what_it_returned_before():
+    """The kernel pair's answer as before; beside it, the fused primal
+    kernel's (``fused_*``)."""
     with gd.force_gdn_scan_impl("pallas"):
-        assert gd.plan(32, 64, CHUNK, D, D, BF16) == {
-            "impl": "pallas", "heads": 8, "vmem_bytes": 8781824,
-            "vmem_budget": 12582912, "why": "fits"}
-        assert gd.plan(16, 64, CHUNK, D, D, F32) == {
-            "impl": "pallas", "heads": 8, "vmem_bytes": 10616832,
-            "vmem_budget": 12582912, "why": "fits"}
+        bf16, f32 = (gd.plan(32, 64, CHUNK, D, D, BF16),
+                     gd.plan(16, 64, CHUNK, D, D, F32))
+    pair = lambda p: {k: v for k, v in p.items() if not k.startswith("fused")}
+    assert pair(bf16) == {
+        "impl": "pallas", "heads": 8, "vmem_bytes": 8781824,
+        "vmem_budget": 12582912, "why": "fits"}
+    assert pair(f32) == {
+        "impl": "pallas", "heads": 8, "vmem_bytes": 10616832,
+        "vmem_budget": 12582912, "why": "fits"}
+    fused = {"fused_heads": 8, "fused_vmem_bytes": 8519680,
+             "fused_why": "fits"}
+    assert bf16 == {**pair(bf16), **fused} and f32 == {**pair(f32), **fused}
 
 
 @pytest.mark.parametrize("dtype", [F32, BF16], ids=["float32", "bfloat16"])
@@ -380,9 +562,14 @@ def test_kernels_at_96_192_gradient_matches_the_scan(gradients_96_192, i,
 
 #: sha256 of the kernel pair's lowering (StableHLO; the kernels' bodies in
 #: interpret mode) at 128 / 128, as the tree before 96 / 192 was taken
-#: lowered them under this file's pytest set-up: what Qwen3-Next runs
+#: lowered them under this file's pytest set-up: what Qwen3-Next runs.
+#: ``chunked_gradient`` is the whole function's gradient, re-recorded when
+#: the fused primal kernel came: a ``custom_vjp`` now wraps the function
+#: and its forward rule is ``jax.vjp`` of the path as it was, so the same
+#: operations lower in another order; ``forward`` and ``gradient``, the
+#: kernel pair itself, are as recorded before
 RECORDED_128 = {"forward": "8f9017ba6cc7b008", "gradient": "44b79d5daf973730",
-                "chunked_gradient": "11f86c437b4fef29"}
+                "chunked_gradient": "b43bec8b1d1eb428"}
 
 
 def test_the_kernel_pair_lowers_at_128_as_before():
@@ -536,6 +723,26 @@ def test_kernels_compile_for_a_v5e_at_96_192(one_chip, what):
         f = jax.grad(lambda *a, f=f: jnp.sum(f(*a)), argnums=tuple(range(6)))
     text = compiled_for(f, *ops)
     assert text.count("tpu_custom_call") == (1 if what == "forward" else 2)
+
+
+@pytest.mark.parametrize("shape", list(FUSED_SHAPES))
+def test_fused_kernel_compiles_for_a_v5e_at_the_published_shapes(one_chip,
+                                                                 shape):
+    """Mosaic takes the fused primal kernel over one sequence of 4,096
+    tokens at Qwen3-Next's 32 heads of 128 / 128 and Olmo-Hybrid's 30
+    heads of 96 / 192, with the heads a step that ``plan`` picks: ten
+    float32 products at the highest precision a chunk, masks from iotas,
+    ``g``'s decays as a column and a row."""
+    dk, dv, heads = FUSED_SHAPES[shape]
+    H, T = (32 if dk == D else 30), 4096
+    with gd.force_gdn_scan_impl("pallas"):
+        assert gd.plan(H, T // CHUNK, CHUNK, dk, dv, BF16)["fused_heads"] \
+            == heads
+    sh = lambda *s: jax.ShapeDtypeStruct(s, F32, sharding=one_chip)
+    f = functools.partial(gd._fused, heads, False, CHUNK, BF16)
+    text = compiled_for(f, sh(H, T, dk), sh(H, T, dk), sh(H, T, dv),
+                        sh(H, T), sh(H, T))
+    assert text.count("tpu_custom_call") == 1
 
 
 @pytest.mark.parametrize("n_kv,rep", [(2, 8), (20, 1)],

@@ -40,8 +40,10 @@ from federated_pytorch_test_tpu.obs.scopes import (  # noqa: E402
     KERNEL_SCOPES,
     NAMES,
     SCOPES,
+    TPU_ONLY,
     scope,
 )
+from federated_pytorch_test_tpu.ops import gated_delta  # noqa: E402
 
 #: which: (benchmark test with CELL and TINY or None, engine module,
 #: the program tags of ``obs/scopes.py`` it answers to)
@@ -264,11 +266,42 @@ def test_every_name_of_the_program_occurs(lowered, which):
     tags = PROGRAMS[which][2]
     want_epoch = {s.name for s in SCOPES
                   if "epoch" in s.programs or set(tags) & set(s.programs)}
+    want_epoch -= set(TPU_ONLY)         # test_tpu_only_names_nest_...
     want_comm = {s.name for s in SCOPES if "comm" in s.programs}
     assert want_epoch <= seen["epoch"], want_epoch - seen["epoch"]
     assert seen["comm"] == want_comm
     # and no name of another program's
     assert seen["epoch"] <= want_epoch, seen["epoch"] - want_epoch
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["forward", "gradient"])
+def test_tpu_only_names_nest_as_the_table_says(grad):
+    """``gdn_fused`` where the fused kernel runs (in interpret mode here,
+    at head widths the kernels take): under ``gdn_scan`` alone in a plain
+    forward, and in the primal pass of a checkpointed gradient, where JAX
+    traces the kernel's function outside the caller's scopes and the
+    reader's ``canonical`` puts ``gdn`` back (and the frame above
+    ``sublayer_mixer``, which this small function does not open)."""
+    q, k, v = (jnp.ones((2, 64, 128), jnp.float32) / 128 for _ in range(3))
+    g = beta = jnp.full((2, 64), 0.5, jnp.float32)
+
+    def loss(w):
+        @jax.checkpoint
+        def mixer(x):
+            with scope("gdn"), scope("gdn_scan"):
+                return gated_delta.gated_delta_chunked(x * w, k, v, g, beta)
+
+        with scope("sublayer_mixer"):
+            return jnp.sum(mixer(q) ** 2)
+
+    with gated_delta.force_gdn_scan_impl("pallas_interpret"):
+        text = jax.jit(jax.grad(loss) if grad else loss).lower(1.0).as_text(
+            debug_info=True)
+    fused = {scope_tree.canonical(tuple(raw_chain(path)))
+             for _, path, _ in op_paths(text) if "gdn_fused" in path}
+    assert fused == {("client_grad", "model_loss", "sublayer_mixer", "gdn",
+                      "gdn_scan", "gdn_fused")}
+    assert set(TPU_ONLY) <= NAMES
 
 
 def test_the_kernel_readers_names_still_occur(lowered):

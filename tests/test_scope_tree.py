@@ -72,6 +72,17 @@ def op(name, start, dur, category="loop fusion"):
      ("client_grad", "model_loss", "sublayer_mixer", "gdn", "gdn_scan"),
      "forward"),
     (STEP + "opt_update/add:", ("opt_update",), "forward"),
+    # the fused primal kernel: JAX traces it outside the checkpointed
+    # mixer's scopes in a gradient's primal pass, and it opens gdn_scan
+    # again (ops/gated_delta.py); in a plain forward gdn_scan twice
+    (FWD + "sublayer_mixer/while/body/closed_call/closed_call:/gdn_scan/"
+     "gdn_fused/pallas_call:",
+     ("client_grad", "model_loss", "sublayer_mixer", "gdn", "gdn_scan",
+      "gdn_fused"), "forward"),
+    (FWD + "sublayer_mixer/while/body/closed_call/checkpoint/gdn/gdn_scan/"
+     "gdn_scan/gdn_fused/pallas_call:",
+     ("client_grad", "model_loss", "sublayer_mixer", "gdn", "gdn_scan",
+      "gdn_fused"), "forward"),
     # a name is a whole segment: no substring counts
     (STEP + "my_opt_update_2/embedding/add:", (), "forward"),
     (STEP + "while:", (), "forward"),
