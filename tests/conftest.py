@@ -9,6 +9,8 @@ exercised for real (SURVEY.md section 4's distributed-test strategy).
 import gc
 import os
 
+import pytest
+
 # FEDTPU_TEST_TPU=1 keeps the hardware backend so the TPU-gated tests
 # (e.g. test_ops.py::test_compiled_kernels_on_tpu) run compiled on the real
 # chip; everything else in the suite still passes there or skips.  The
@@ -89,6 +91,47 @@ def pytest_runtest_teardown(item, nextitem):
     if nextitem is None or nextitem.module is not item.module:
         jax.clear_caches()
         gc.collect()
+
+
+def deal_long_first(items, workers):
+    """``items`` with the ``long`` ones at the heads of xdist's first chunks.
+
+    ``--dist load`` gives each of its ``workers`` first one contiguous
+    chunk of ``max(2, N // workers // 4)`` items in collection order, then
+    refills from the front of what is left, so a long test runs about when
+    its file sorts.  Here the long items are dealt round-robin over the
+    first chunks, longest first where the marker carries its seconds,
+    and each chunk's go in at its first file boundary (its head if there
+    is none): every worker starts its few long tests at once, and no
+    file's run of items is cut by them.  The other items keep their
+    order; the result depends on nothing but ``items`` and ``workers``,
+    as xdist needs every worker to collect the same order.
+    """
+    long = sorted((it for it in items if it.get_closest_marker("long")),
+                  key=lambda it: -it.get_closest_marker("long").kwargs.get(
+                      "seconds", 0))
+    if not long:
+        return list(items)
+    rest = [it for it in items if not it.get_closest_marker("long")]
+    chunk = max(2, len(items) // workers // 4)
+    out, at = [], 0
+    for k in range(workers):
+        heads = long[k::workers]
+        part = rest[at:at + max(0, chunk - len(heads))]
+        before = [rest[at - 1] if at else None] + part
+        cut = next((j for j, it in enumerate(part)
+                    if before[j] is None or before[j].path != it.path), 0)
+        out += part[:cut] + heads + part[cut:]
+        at += len(part)
+    return out + rest[at:]
+
+
+@pytest.hookimpl(trylast=True)
+def pytest_collection_modifyitems(config, items):
+    # after deselection and pytest's own reordering by parameter scope
+    workerinput = getattr(config, "workerinput", None)
+    if workerinput is not None:
+        items[:] = deal_long_first(items, workerinput["workercount"])
 
 
 def pytest_configure(config):

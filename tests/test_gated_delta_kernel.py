@@ -200,30 +200,44 @@ def test_fused_primal_matches_todays_path(shape, length, dtype):
 
 @pytest.fixture(scope="module")
 def checkpointed_gradients():
-    """Per shape and dtype, gradients of all five inputs through
-    ``jax.checkpoint``, as the models take them: this tree's kernel path
-    and today's, at a length that needs padding (two heads: the heads a
-    step change the grid, not the arithmetic)."""
-    out = {}
-    for shape, (dk, dv, _) in FUSED_SHAPES.items():
-        args = strong_decay_inputs(100, dk, dv, 2)
-        for dtype in (F32, BF16):
+    """Per shape and dtype, built on first use: gradients of all five
+    inputs through ``jax.checkpoint``, as the models take them: this
+    tree's kernel path and today's, at a length that needs padding (two
+    heads: the heads a step change the grid, not the arithmetic)."""
+    made = {}
+
+    def get(shape, dtype):
+        if (shape, dtype) not in made:
+            dk, dv, _ = FUSED_SHAPES[shape]
+            args = strong_decay_inputs(100, dk, dv, 2)
+
             def grad(f):
                 loss = lambda *a: jnp.sum(jax.checkpoint(f)(*a) ** 2)
                 return jax.grad(loss, argnums=(0, 1, 2, 3, 4))(*args)
 
-            out[shape, dtype] = (
+            made[shape, dtype] = (
                 grad(functools.partial(kernels, dtype)),
                 grad(functools.partial(todays_path, dtype)))
-    return out
+        return made[shape, dtype]
+
+    return get
 
 
-@pytest.mark.parametrize("dtype", [F32, BF16], ids=["float32", "bfloat16"])
-@pytest.mark.parametrize("shape", list(FUSED_SHAPES))
-@pytest.mark.parametrize("i", range(5), ids=INPUTS)
+#: the cases key by key, so that a worker that runs a key's five builds
+#: its gradients once (stacked ``parametrize`` would run input by input,
+#: and pytest keys a stacked parameter of module scope per case, so that
+#: scope would not group them)
+CHECKPOINTED = [
+    pytest.param(i, shape, dtype, id=f"{INPUTS[i]}-{shape}-{name}")
+    for shape in FUSED_SHAPES
+    for dtype, name in ((F32, "float32"), (BF16, "bfloat16"))
+    for i in range(5)]
+
+
+@pytest.mark.parametrize("i,shape,dtype", CHECKPOINTED)
 def test_checkpointed_gradient_is_todays(checkpointed_gradients, i, shape,
                                          dtype):
-    got, want = checkpointed_gradients[shape, dtype]
+    got, want = checkpointed_gradients(shape, dtype)
     assert got[i].shape == want[i].shape and got[i].dtype == want[i].dtype
     assert rel(got[i], want[i]) < 1e-5
 

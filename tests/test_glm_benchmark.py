@@ -44,6 +44,7 @@ def reader(name):
 
 
 # ----------------------------------------------------------------------
+@pytest.mark.long(seconds=128)
 def test_rehearsal_of_the_cell():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.run(

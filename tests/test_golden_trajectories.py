@@ -230,6 +230,7 @@ class TestCPCGolden:
         return CPCTrainer(src, latent_dim=8, reduced_dim=4,
                           lbfgs_history=3, lbfgs_max_iter=1, Niter=1)
 
+    @pytest.mark.long(seconds=80)
     def test_default_path(self):
         state, hist = self._make().run(Nloop=1, Nadmm=2,
                                        log=lambda m: None)

@@ -38,6 +38,7 @@ def bench():
 
 
 # ----------------------------------------------------------------------
+@pytest.mark.long(seconds=134)
 def test_rehearsal_of_the_cell():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.run(

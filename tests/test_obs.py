@@ -550,6 +550,7 @@ class TestEngineEmission:
             assert r["quarantined"] >= 0
         assert summary["guard_trips_total"] >= 0
 
+    @pytest.mark.long(seconds=67)
     def test_cpc_records(self, tmp_path):
         from federated_pytorch_test_tpu.data.lofar import CPCDataSource
         from federated_pytorch_test_tpu.train.cpc_engine import CPCTrainer

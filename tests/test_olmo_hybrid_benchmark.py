@@ -114,6 +114,7 @@ def test_the_engine_builds_the_model_from_the_configuration_s_keys():
 # ----------------------------------------------------------------------
 # the cell through the harness
 # ----------------------------------------------------------------------
+@pytest.mark.long(seconds=107)
 def test_rehearsal_of_the_cell():
     """Traced: the check, the window and every reader the cell lists
     (the untraced line is ``run.py``'s own, rehearsed by the sibling
@@ -375,6 +376,7 @@ def test_mfu_reader(cell):
 # ----------------------------------------------------------------------
 # the check against a lower precision
 # ----------------------------------------------------------------------
+@pytest.mark.long(seconds=67)
 def test_the_check_fails_a_float8_probe_at_the_small_size():
     """Every product's operands rounded to float8 e4m3
     (``ops/moe.py:operand``): the nearest precision below the

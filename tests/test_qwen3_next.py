@@ -138,6 +138,7 @@ def test_published_widths_give_the_issue_s_parameter_counts():
 # ----------------------------------------------------------------------
 # the attention core as a kernel pair (interpret mode) against the XLA path
 # ----------------------------------------------------------------------
+@pytest.mark.long(seconds=99)
 @pytest.mark.parametrize("block", [7], ids=["attention_block"])
 def test_model_through_the_attention_kernels_matches_the_xla_path(block):
     """The file's model has heads of 16, which ``plan()`` sends to the XLA

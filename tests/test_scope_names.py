@@ -46,7 +46,10 @@ from federated_pytorch_test_tpu.obs.scopes import (  # noqa: E402
 from federated_pytorch_test_tpu.ops import gated_delta  # noqa: E402
 
 #: which: (benchmark test with CELL and TINY or None, engine module,
-#: the program tags of ``obs/scopes.py`` it answers to)
+#: the program tags of ``obs/scopes.py`` it answers to).  ``which`` is a
+#: parameter of module scope: pytest runs every test of one program
+#: before the next program's, so that a worker lowers each program it
+#: meets once (``lowered``)
 PROGRAMS = {
     "qwen3_next": ("test_lm_benchmark", "lm", ("decoder", "qwen3_next")),
     "glm4_moe_lite": ("test_glm_benchmark", "decoder",
@@ -208,7 +211,24 @@ def plumbing(kind, line):
 
 
 # ----------------------------------------------------------------------
-@pytest.mark.parametrize("which", list(PROGRAMS))
+# first in the file: the four lowerings it makes are found by the tests of
+# those programs that follow it on its worker
+def test_the_kernel_readers_names_still_occur(lowered):
+    seen = set()
+    for which in ("qwen3_next", "glm4_moe_lite", "xing4_0", "zaya"):
+        text = lowered(which)["epoch"].as_text(debug_info=True)
+        for _, path, _ in op_paths(text):
+            seen.update(raw_chain(path))
+    assert set(KERNEL_SCOPES) <= seen
+    assert set(KERNEL_SCOPES) <= NAMES and len(KERNEL_SCOPES) == 15
+    # the fourteen the three substring readers match, and the one core
+    # zaya_work.py reads from the scope tree's whole segments
+    assert set(bench_scopes.SCOPES) | set(glm_work.SCOPES) \
+        | set(xing_work.SCOPES) | {"mtp", "mhc", "cca_core"} \
+        == set(KERNEL_SCOPES)
+
+
+@pytest.mark.parametrize("which", list(PROGRAMS), scope="module")
 def test_every_operation_lies_under_a_name(lowered, which):
     bare = collections.Counter()
     for program in ("epoch", "comm"):
@@ -232,7 +252,7 @@ def test_every_operation_lies_under_a_name(lowered, which):
     assert sum(bare.values()) <= 2
 
 
-@pytest.mark.parametrize("which", list(PROGRAMS))
+@pytest.mark.parametrize("which", list(PROGRAMS), scope="module")
 def test_names_nest_as_the_table_says(lowered, which):
     """Where two names follow one another in a path the first is an
     ancestor of the second (JAX lifts loop-invariant ops out of a loop,
@@ -256,7 +276,7 @@ def test_names_nest_as_the_table_says(lowered, which):
         assert not whole or not PARENTS[whole[0]], (chain, whole)
 
 
-@pytest.mark.parametrize("which", list(PROGRAMS))
+@pytest.mark.parametrize("which", list(PROGRAMS), scope="module")
 def test_every_name_of_the_program_occurs(lowered, which):
     seen = {"epoch": set(), "comm": set()}
     for program in seen:
@@ -304,22 +324,7 @@ def test_tpu_only_names_nest_as_the_table_says(grad):
     assert set(TPU_ONLY) <= NAMES
 
 
-def test_the_kernel_readers_names_still_occur(lowered):
-    seen = set()
-    for which in ("qwen3_next", "glm4_moe_lite", "xing4_0", "zaya"):
-        text = lowered(which)["epoch"].as_text(debug_info=True)
-        for _, path, _ in op_paths(text):
-            seen.update(raw_chain(path))
-    assert set(KERNEL_SCOPES) <= seen
-    assert set(KERNEL_SCOPES) <= NAMES and len(KERNEL_SCOPES) == 15
-    # the fourteen the three substring readers match, and the one core
-    # zaya_work.py reads from the scope tree's whole segments
-    assert set(bench_scopes.SCOPES) | set(glm_work.SCOPES) \
-        | set(xing_work.SCOPES) | {"mtp", "mhc", "cca_core"} \
-        == set(KERNEL_SCOPES)
-
-
-@pytest.mark.parametrize("which", list(PROGRAMS))
+@pytest.mark.parametrize("which", list(PROGRAMS), scope="module")
 def test_the_names_add_no_operation(lowered, which, monkeypatch):
     named = {k: v.as_text() for k, v in lowered(which).items()}
     assert "loc(" not in named["epoch"].split("\n", 1)[1][:2000]

@@ -478,6 +478,7 @@ def test_dispatch_readers_read_nothing_on_a_parent():
 # ----------------------------------------------------------------------
 # through the harness
 # ----------------------------------------------------------------------
+@pytest.mark.long(seconds=94)
 def test_the_readers_in_a_rehearsed_traced_run():
     """The Qwen3-Next cell through ``benchmarks/run.py --trace 1`` at the
     rehearsal's tiny size with the five names listed by this test: the

@@ -357,6 +357,7 @@ def test_the_configuration_file_holds_the_catalog_s_values():
 # ----------------------------------------------------------------------
 # the attention core as a kernel pair (interpret mode) against the XLA path
 # ----------------------------------------------------------------------
+@pytest.mark.long(seconds=79)
 def test_model_through_the_attention_kernels_matches_the_xla_path():
     """Keys of 128 + 64 = 192 beside values of 128 and a sequence of three
     key blocks: what ``plan()`` sends to the kernels with ``q`` and ``k``
@@ -410,6 +411,7 @@ def test_impl_fields_say_what_runs_the_hyper_connections(force, hidden,
             assert ask() == impl == model.mhc_impl(T)
 
 
+@pytest.mark.long(seconds=108)
 def test_model_through_the_stream_kernels_matches_the_jax_numpy_path():
     """Streams of 128: ``plan()`` sends every sub-layer's ``pre`` and
     ``expand`` to the kernels (two sequences of 40 tokens: one ragged

@@ -59,6 +59,7 @@ def reader(name):
 
 
 # ----------------------------------------------------------------------
+@pytest.mark.long(seconds=238)
 def test_rehearsal_of_the_cell():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.run(
@@ -389,6 +390,7 @@ def test_scoped_ops_of_a_recorded_trace():
 # ----------------------------------------------------------------------
 # the check against a lower precision
 # ----------------------------------------------------------------------
+@pytest.mark.long(seconds=125)
 def test_the_check_fails_a_float8_probe_at_the_small_size():
     """Every product's operands rounded to float8 e4m3
     (``ops/moe.py:operand``): the nearest precision below the

@@ -156,6 +156,7 @@ def test_the_engine_balances_the_common_start_for_all_clients_alike():
 # ----------------------------------------------------------------------
 # the cell through the harness
 # ----------------------------------------------------------------------
+@pytest.mark.long(seconds=109)
 @pytest.mark.parametrize("trace", [0, 1])
 def test_rehearsal_of_the_cell(trace):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
@@ -460,6 +461,7 @@ def test_seconds_under_a_parent():
 # ----------------------------------------------------------------------
 # the check against a lower precision
 # ----------------------------------------------------------------------
+@pytest.mark.long(seconds=75)
 def test_the_check_fails_a_float8_probe_at_the_small_size():
     """Every product's operands rounded to float8 e4m3
     (``ops/moe.py:operand``): the nearest precision below the
